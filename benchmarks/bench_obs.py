@@ -23,8 +23,10 @@ separately — that overhead is reported explicitly as ``overhead_frac``
 (``max(0, -hidden) / min(local, exchange)``) instead of being silently
 floored into the 0.000 that used to hide the p8 regression.
 
-Runs in subprocesses (one forced host-device view per shard count, set up
-by ``repro.env``), same harness shape as ``bench_scaling``, and warms the
+Runs in subprocesses on the CPU backend (``JAX_PLATFORMS=cpu``: one
+forced host-device view per shard count, set up by ``repro.env``; on a
+chip host the parent holds the chip, and every row says ``platform=cpu``),
+same harness shape as ``bench_scaling``, and warms the
 kernel-config cache on shard 0's containers first so the phases measure
 the same ``backend="auto"`` schedule the scaling bench's ghost runs. Rows land in
 ``BENCH_obs.json`` via ``python -m benchmarks.run --only obs`` and render
@@ -52,8 +54,9 @@ from repro.core.distributed import (build_dist_matrix, dist_spmv,
 from repro.obs import metrics
 from repro.tuning import kernel_tune
 from repro.tuning.cache import SelectionCache
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((%(ndev)d,), ("rows",))
+mesh = make_mesh((%(ndev)d,), ("rows",))
 prob = hpcg.generate_problem(*%(grid)r)
 x = distribute_vector(np.ones(prob.shape[0], np.float32), mesh, "rows")
 A = build_dist_matrix(prob.row, prob.col, prob.val, prob.shape, mesh,
@@ -117,7 +120,8 @@ def run(shards=(1, 2, 4, 8, 16, 32), grid=(16, 16, 32), iters=20,
         out, last_err = None, ""
         for _ in range(max(1, attempts)):
             res = subprocess.run([sys.executable, "-c", script],
-                                 capture_output=True, text=True, timeout=1800)
+                                 capture_output=True, text=True, timeout=1800,
+                                 env=dict(os.environ, JAX_PLATFORMS="cpu"))
             line = [l for l in res.stdout.splitlines()
                     if l.startswith("RESULT ")]
             if not line:
@@ -133,7 +137,7 @@ def run(shards=(1, 2, 4, 8, 16, 32), grid=(16, 16, 32), iters=20,
         full, loc, exc = ph["full"], ph["local"], ph["exchange"]
         derived = (f"local_us={loc * 1e6:.0f};exch_us={exc * 1e6:.0f};"
                    f"halo_mode={out['halo_mode']};hw={out['hw']};"
-                   f"halo_bytes={out['halo_bytes']:.0f}")
+                   f"halo_bytes={out['halo_bytes']:.0f};platform=cpu")
         if out.get("split") and "interior" in ph:
             derived += (f";interior_us={ph['interior'] * 1e6:.0f};"
                         f"boundary_us={ph['boundary'] * 1e6:.0f}")

@@ -29,7 +29,9 @@ count:
 
 Subprocess environments are set up by ``repro.env.apply`` (backend-gated
 XLA flags, forced host device count) so each shard count gets its own
-device view.
+device view. The children run on the CPU backend (``JAX_PLATFORMS=cpu``):
+the suite rehearses a mesh on forced host devices, and on a chip host the
+parent process already holds the chip. Every row says ``platform=cpu``.
 """
 import json
 import os
@@ -51,8 +53,9 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import Format, hpcg
 from repro.core.distributed import build_dist_matrix, dist_spmv, distribute_vector
 from repro.tuning.cache import SelectionCache
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((%(ndev)d,), ("rows",))
+mesh = make_mesh((%(ndev)d,), ("rows",))
 prob = hpcg.generate_problem(*%(grid)r)
 x = distribute_vector(np.ones(prob.shape[0], np.float32), mesh, "rows")
 out = {"spmv": {}, "build": {}}
@@ -126,8 +129,9 @@ from repro.core import hpcg
 from repro.core.distributed import build_dist_matrix, dist_spmv, distribute_vector
 from repro.obs import metrics
 from repro.tuning.cache import SelectionCache
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((%(ndev)d,), ("rows",))
+mesh = make_mesh((%(ndev)d,), ("rows",))
 prob = hpcg.generate_problem(*%(grid)r)
 x = distribute_vector(np.ones(prob.shape[0], np.float32), mesh, "rows")
 kw = dict(mode="multiformat", tune="cached")
@@ -147,7 +151,7 @@ print("RESULT " + json.dumps({"build": t1 - t0, "spmv": t2 - t1,
 
 
 def _run(script: str, timeout: int = 1800, env_extra=None):
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     if env_extra:
         env.update(env_extra)
     res = subprocess.run([sys.executable, "-c", script],
@@ -187,7 +191,8 @@ def _restart_rows(ndev, grid, src):
             f"plan_cache_hit={cached['plan_cache_hit']};"
             f"replan_total_us={replan['total'] * 1e6:.0f};"
             f"replan_build_us={replan['build'] * 1e6:.0f};"
-            f"speedup_vs_replan={replan['total'] / max(cached['total'], 1e-9):.2f}"))
+            f"speedup_vs_replan={replan['total'] / max(cached['total'], 1e-9):.2f};"
+            "platform=cpu"))
     return rows
 
 
@@ -204,11 +209,11 @@ def run(shards=(1, 2, 4, 8, 16, 32), grid=(16, 16, 32), iters=20,
             continue
         for phase, t in out["build"].items():
             rows.append((f"scaling_build_{phase}_p{ndev}", t * 1e6,
-                         f"per_shard_us={t * 1e6 / ndev:.0f}"))
+                         f"per_shard_us={t * 1e6 / ndev:.0f};platform=cpu"))
         ref = out["spmv"]["reference"]
         for name, t in out["spmv"].items():
             rows.append((f"scaling_spmv_{name}_p{ndev}", t * 1e6,
-                         f"speedup_vs_ref={ref / t:.2f}"))
+                         f"speedup_vs_ref={ref / t:.2f};platform=cpu"))
     for ndev in restart_shards:
         if ndev in shards:
             rows.extend(_restart_rows(ndev, grid, src))

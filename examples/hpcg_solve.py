@@ -14,11 +14,17 @@ Run (8 simulated devices):
   HPCG_DEVICES=8 PYTHONPATH=src python examples/hpcg_solve.py \
       --precond mg --mode multiformat    # full MG-PCG, per-level DistPlans
   PYTHONPATH=src python examples/hpcg_solve.py --local DIA --remote COO
+
+``main(argv)`` returns an :class:`HPCGRun` — the exit code plus what a
+caller in the same process checks (the ``CGResult``, the compiled solve's
+HLO text, phase timings, the operator); the CLI exits with its ``code``.
 """
 import argparse
+import dataclasses
 import os
 import sys
 import time
+from typing import Any, Optional
 
 if __name__ == "__main__":
     # repro.env is jax-free: backend-gated XLA flags land before jax
@@ -37,10 +43,73 @@ from repro.core import Format, hpcg  # noqa: E402
 from repro.core.distributed import (build_dist_matrix,  # noqa: E402
                                     distribute_vector)
 from repro.core.solvers import cg, operator, pcg  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.obs import trace  # noqa: E402
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class HPCGRun:
+    """One HPCG run: ``code`` is the CLI exit code (0 iff validation
+    passed); the rest is for in-process callers."""
+    code: int
+    grid: tuple
+    n: int
+    nnz: int
+    setup_s: float
+    optimize_s: float
+    compile_s: float
+    solve_s: float
+    err: float                 # max|x - 1|
+    result: Any                # CGResult
+    hlo: str                   # compiled solve, as text
+    A: Any                     # DistSparseMatrix (level 0 under --precond mg)
+    hier: Optional[Any] = None  # DistMGHierarchy under --precond mg
+
+
+def _optimize(args, prob, mesh, ndev):
+    """The distributed operator, and the MG hierarchy under --precond mg
+    (its level 0 IS the operator: building it separately would run the
+    partition + per-shard selection twice)."""
+    if args.precond == "mg":
+        from repro.mg import build_dist_hierarchy
+
+        hier = build_dist_hierarchy(
+            prob, mesh, "rows", nlevels=args.mg_levels, mode=args.mode,
+            tune=args.tune, local_format=Format[args.local],
+            remote_format=Format[args.remote], backend=args.backend)
+        return hier.levels[0].A, hier
+    # The z-slab structure of the stencil is known analytically: slab_plan
+    # replaces the partition scan, and being correct by construction it can
+    # also skip the builder's stale-plan validation (check_plan=False) — the
+    # triplets are then touched exactly once, by the device scatter.
+    plan = hpcg.slab_plan(prob, ndev) if prob.nz % ndev == 0 else None
+    A = build_dist_matrix(prob.row, prob.col, prob.val, prob.shape, mesh,
+                          "rows", local_format=Format[args.local],
+                          remote_format=Format[args.remote], mode=args.mode,
+                          tune=args.tune, plan=plan, check_plan=plan is None)
+    return A, None
+
+
+def _print_formats(A, hier):
+    if hier is not None:
+        for rec in hier.formats():
+            bnd = f" boundary={rec['boundary']}" if "boundary" in rec else ""
+            print(f"  level {rec['level']} {rec['dims']}: "
+                  f"local={rec['local']}{bnd} remote={rec['remote']}")
+        return
+    from repro.core import DEFAULT_CANDIDATES
+    names = [f.name for f in DEFAULT_CANDIDATES]
+    label = "interior" if A.split else "local"
+    print(f"  per-shard {label} formats: ",
+          [names[i] for i in np.asarray(A.local.active_id)])
+    if A.split:
+        print("  per-shard boundary formats:",
+              [names[i] for i in np.asarray(A.boundary.active_id)])
+    print("  per-shard remote formats:",
+          [names[i] for i in np.asarray(A.remote.active_id)])
+
+
+def main(argv=None) -> HPCGRun:
     p = argparse.ArgumentParser()
     p.add_argument("--grid", type=int, nargs=3, default=[16, 16, 32])
     p.add_argument("--mode", choices=["uniform", "multiformat"], default="uniform")
@@ -52,8 +121,9 @@ def main(argv=None):
     p.add_argument("--remote", default="COO", choices=[f.name for f in Format])
     p.add_argument("--backend", default="auto",
                    choices=["auto", "ref", "pallas"],
-                   help="SpMV kernel routing: auto = Pallas where it "
-                        "compiles natively, jnp reference otherwise")
+                   help="SpMV kernel routing: auto = Pallas where a tuned "
+                        "kernel config measured faster than the jnp "
+                        "reference, the reference otherwise")
     p.add_argument("--tol", type=float, default=1e-7)
     p.add_argument("--maxiter", type=int, default=500)
     p.add_argument("--precond", nargs="?", const="jacobi", default="none",
@@ -67,93 +137,84 @@ def main(argv=None):
     p.add_argument("--mg-levels", type=int, default=None,
                    help="cap the MG hierarchy depth (default: coarsen while "
                         "dims stay even and slabs divide the mesh)")
+    p.add_argument("--devices", type=int, default=None,
+                   help="solve on the first N of jax.devices() "
+                        "(default: all)")
+    p.add_argument("--no-warmup", action="store_true",
+                   help="skip the untimed warm-up solve: the compiled solve "
+                        "runs once, and its time includes the first run's "
+                        "one-off costs (for smoke runs that time nothing)")
     p.add_argument("--verbose", action="store_true",
                    help="print the per-iteration convergence curve "
                         "(||r_k|| from the solver's residual history)")
     args = p.parse_args(argv)
 
-    ndev = len(jax.devices())
-    mesh = jax.make_mesh((ndev,), ("rows",))
+    devices = jax.devices()[:args.devices]
+    ndev = len(devices)
+    mesh = make_mesh((ndev,), ("rows",), devices=devices)
     print(f"devices: {ndev}, grid: {args.grid}")
 
     # --- 1. problem setup ---------------------------------------------------
     t0 = time.perf_counter()
     with trace.span("build.problem", grid="x".join(map(str, args.grid))):
         prob = hpcg.generate_problem(*args.grid)
-    print(f"setup: n={prob.shape[0]} nnz={len(prob.val)} "
-          f"({time.perf_counter() - t0:.2f}s)")
+    setup_s = time.perf_counter() - t0
+    print(f"setup: n={prob.shape[0]} nnz={len(prob.val)} ({setup_s:.2f}s)")
 
     # --- 2. problem optimization (Morpheus: partition + format selection) ---
-    # The z-slab structure of the stencil is known analytically: slab_plan
-    # replaces the partition scan, and being correct by construction it can
-    # also skip the builder's stale-plan validation (check_plan=False) — the
-    # triplets are then touched exactly once, by the device scatter. In mg
-    # mode the whole hierarchy is the optimization product: its level 0 IS
-    # the distributed operator (building it separately would run the
-    # partition + per-shard selection twice).
+    # Optimization computes on the host's CPU device and places the finished
+    # containers on the mesh: its sort and scatter programs take 17-40 s
+    # each to compile for a TPU at 104^3 (about 20 distinct ones per MG
+    # level), a second or two for the CPU. --tune profile times candidate
+    # kernels, so it stays on the devices that solve, as does everything
+    # when JAX_PLATFORMS leaves the CPU backend out.
+    setup_dev = devices[0]
+    if args.tune != "profile":
+        try:
+            setup_dev = jax.local_devices(backend="cpu")[0]
+        except RuntimeError:
+            pass
     t0 = time.perf_counter()
-    hier = None
-    opt_span = trace.span("build.optimize", mode=args.mode,
-                          precond=args.precond)
-    opt_span.__enter__()
-    if args.precond == "mg":
-        from repro.mg import build_dist_hierarchy
-
-        hier = build_dist_hierarchy(
-            prob, mesh, "rows", nlevels=args.mg_levels, mode=args.mode,
-            tune=args.tune, local_format=Format[args.local],
-            remote_format=Format[args.remote], backend=args.backend)
-        A = hier.levels[0].A
-        print(f"optimization: {hier} ({time.perf_counter() - t0:.2f}s)")
-        if args.mode == "multiformat":
-            for rec in hier.formats():
-                bnd = (f" boundary={rec['boundary']}"
-                       if "boundary" in rec else "")
-                print(f"  level {rec['level']} {rec['dims']}: "
-                      f"local={rec['local']}{bnd} remote={rec['remote']}")
-    else:
-        plan = hpcg.slab_plan(prob, ndev) if prob.nz % ndev == 0 else None
-        A = build_dist_matrix(prob.row, prob.col, prob.val, prob.shape, mesh,
-                              "rows", local_format=Format[args.local],
-                              remote_format=Format[args.remote], mode=args.mode,
-                              tune=args.tune, plan=plan, check_plan=plan is None)
-        print(f"optimization: {A} ({time.perf_counter() - t0:.2f}s)")
-        if args.mode == "multiformat":
-            from repro.core import DEFAULT_CANDIDATES
-            names = [f.name for f in DEFAULT_CANDIDATES]
-            label = "interior" if A.split else "local"
-            print(f"  per-shard {label} formats: ",
-                  [names[i] for i in np.asarray(A.local.active_id)])
-            if A.split:
-                print("  per-shard boundary formats:",
-                      [names[i] for i in np.asarray(A.boundary.active_id)])
-            print("  per-shard remote formats:",
-                  [names[i] for i in np.asarray(A.remote.active_id)])
-
-    opt_span.__exit__(None, None, None)
+    with trace.span("build.optimize", mode=args.mode, precond=args.precond,
+                    device=setup_dev.platform), jax.default_device(setup_dev):
+        A, hier = _optimize(args, prob, mesh, ndev)
+    optimize_s = time.perf_counter() - t0
+    print(f"optimization on {setup_dev.platform}: "
+          f"{A if hier is None else hier} "
+          f"({optimize_s:.2f}s)")
+    if args.mode == "multiformat":
+        _print_formats(A, hier)
     b = distribute_vector(hpcg.rhs_for_ones(prob), mesh, "rows")
 
     # --- 3. optimized timing -------------------------------------------------
+    operands = (A, b)
     if args.precond == "mg":
-        apply_M = hier.apply_M()
-        solve = jax.jit(lambda a, bb: pcg(
+        # the hierarchy is an argument, not a closure: closed-over arrays
+        # would be compiled into the executable as constants
+        operands = (A, b, hier)
+        solve = jax.jit(lambda a, bb, h: pcg(
             operator(a, mesh, backend=args.backend), bb, tol=args.tol,
-            maxiter=args.maxiter, apply_M=apply_M))
+            maxiter=args.maxiter, apply_M=h.apply_M()))
     elif args.precond == "jacobi":
         diag = jnp.asarray(
             np.full(prob.shape[0], 26.0, np.float32))  # HPCG diagonal
-        solve = jax.jit(lambda a, bb: pcg(
-            operator(a, mesh, backend=args.backend), bb, diag, tol=args.tol,
+        operands = (A, b, diag)
+        solve = jax.jit(lambda a, bb, d: pcg(
+            operator(a, mesh, backend=args.backend), bb, d, tol=args.tol,
             maxiter=args.maxiter))
     else:
         solve = jax.jit(lambda a, bb: cg(
             operator(a, mesh, backend=args.backend), bb, tol=args.tol,
             maxiter=args.maxiter))
     with trace.span("solver.compile", precond=args.precond) as sp:
-        sp.sync(solve(A, b))  # compile + warm
+        t0 = time.perf_counter()
+        solve = solve.lower(*operands).compile()
+        compile_s = time.perf_counter() - t0
+        if not args.no_warmup:
+            sp.sync(solve(*operands))
     t0 = time.perf_counter()
     with trace.span("solver.solve", precond=args.precond) as sp:
-        res = solve(A, b)
+        res = solve(*operands)
         sp.sync(res)
     res = jax.block_until_ready(res)
     dt = time.perf_counter() - t0
@@ -181,8 +242,11 @@ def main(argv=None):
             out = os.environ.get("REPRO_TRACE_EXPORT", "trace.json")
             print(f"trace exported: {trace.export_chrome(out)} "
                   f"(render: python -m repro.obs.report {out})")
-    return 0 if err < 1e-3 else 1
+    return HPCGRun(code=0 if err < 1e-3 else 1, grid=tuple(args.grid),
+                   n=prob.shape[0], nnz=len(prob.val), setup_s=setup_s,
+                   optimize_s=optimize_s, compile_s=compile_s, solve_s=dt,
+                   err=err, result=res, hlo=solve.as_text(), A=A, hier=hier)
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main().code)

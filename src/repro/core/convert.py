@@ -290,6 +290,22 @@ def _unique_small(values, sentinel=_SENTINEL) -> np.ndarray:
     return u[u != sentinel]
 
 
+def _dia_offsets(row, col, live, m: int, n: int) -> np.ndarray:
+    """Ascending distinct live diagonals ``col - row`` (any leading batch
+    axes), pulled to host.
+
+    Diagonals of an ``(m, n)`` matrix lie in ``[1 - m, n - 1]``, so one
+    presence scatter over that range finds them: O(nnz) device work and an
+    ``(m + n - 1)``-long mask pulled, where a ``unique`` would sort every
+    entry and pull an nnz-long mask.
+    """
+    span = m + n - 1
+    d = jnp.where(live, col.astype(jnp.int32) - row.astype(jnp.int32)
+                  + (m - 1), span)
+    seen = jnp.zeros((span,), bool).at[d.ravel()].set(True, mode="drop")
+    return np.flatnonzero(_planned_pull(seen)) - (m - 1)
+
+
 def _sell_geometry(c: Optional[int], sigma: Optional[int], m: int):
     """Normalize (C, sigma) hints: C defaults to 32 lanes, sigma to 8*C
     (and is never smaller than C — a sub-slice sort window is meaningless)."""
@@ -390,9 +406,7 @@ def plan_switch(A, fmt: Format, *, k: Optional[int] = None,
 
     if fmt == Format.DIA:
         if offsets is None:
-            diffs = jnp.where(live, C.col.astype(jnp.int32) - C.row.astype(jnp.int32),
-                              _SENTINEL)
-            offs = _unique_small(diffs)
+            offs = _dia_offsets(C.row, C.col, live, m, n)
             offsets = offs if offs.size else np.array([0])
         # the numeric phase routes entries with searchsorted, which needs
         # ascending *unique* offsets: a duplicated offset would leave its
@@ -528,9 +542,7 @@ def plan_switch_batch(A: COO, fmt: Format, *, k: Optional[int] = None,
 
     if fmt == Format.DIA:
         if offsets is None:
-            diffs = jnp.where(live, A.col.astype(jnp.int32) - A.row.astype(jnp.int32),
-                              _SENTINEL)
-            offs = _unique_small(diffs.ravel())  # deduped union over parts
+            offs = _dia_offsets(A.row, A.col, live, m, n)  # union over parts
             offsets = offs if offs.size else np.array([0])
         offsets = tuple(int(o) for o in np.unique(np.asarray(offsets).ravel()))
         return SwitchPlan(fmt, dia_offsets=offsets, capacity=capacity)

@@ -28,8 +28,8 @@ Architecture (the PR-2 plan/execute split, applied end-to-end):
     halo reach — and emits a :class:`DistPlan` of static host metadata
     (slab size, halo width/mode, per-shard capacities, and once computed,
     the per-format :class:`SwitchPlan`\\ s).
-  * ``partition_execute`` (numeric) is jit-able with the plan static: one
-    stable ``argsort`` over the global triplets scatters every entry into
+  * ``partition_execute`` (numeric) is jit-able with the plan static: each
+    entry's rank within its (shard, local/remote) group scatters it into
     its shard-local slot of the stacked, uniform-capacity local/remote COO
     containers. Zero device->host transfers.
   * conversion/selection are batched: ``plan_switch_batch`` produces one
@@ -51,10 +51,8 @@ from typing import Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from repro.core import compat
-from repro.core.compat import leading_axis_spec
 from repro.core.convert import (SwitchPlan, convert_execute_batch,
                                 plan_switch_batch)
 from repro.core import ops as _ops
@@ -69,6 +67,16 @@ AxisNames = Union[str, Tuple[str, ...]]
 # ---------------------------------------------------------------------------
 # Stacking / unstacking shard containers
 # ---------------------------------------------------------------------------
+
+
+def leading_axis_spec(axis, ndim: int) -> PartitionSpec:
+    """``P(axis, None, ...)`` — shard the leading axis, replicate the rest.
+
+    The one spec every stacked shard container and batch tensor uses; shared
+    with ``repro.launch.sharding`` so the distributed layer and the model
+    launcher agree on the convention.
+    """
+    return PartitionSpec(axis, *(None,) * (ndim - 1))
 
 
 def stack_parts(parts: Sequence):
@@ -262,7 +270,7 @@ def dist_spmv(A: DistSparseMatrix, x, mesh: Mesh, backend: str = "auto",
                     leading_axis_spec(axis, 1))
         operands = (A.local, A.remote, x)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
         out_specs=leading_axis_spec(axis, 1))
     if _trace.mode() == "off":
@@ -329,13 +337,13 @@ def dist_spmv_phase(A: DistSparseMatrix, x, mesh: Mesh, phase: str = "full",
             return body(local_s, boundary_s, remote_s, x_blk)
         in_specs = (_part_spec(A.local, axis), _part_spec(A.boundary, axis),
                     _part_spec(A.remote, axis), leading_axis_spec(axis, 1))
-        fn = compat.shard_map(body3, mesh=mesh, in_specs=in_specs,
+        fn = jax.shard_map(body3, mesh=mesh, in_specs=in_specs,
                               out_specs=leading_axis_spec(axis, 1))
         return fn(A.local, A.boundary, A.remote, x)
 
     def body2(local_s, remote_s, x_blk):
         return body(local_s, None, remote_s, x_blk)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body2, mesh=mesh,
         in_specs=(_part_spec(A.local, axis), _part_spec(A.remote, axis),
                   leading_axis_spec(axis, 1)),
@@ -492,42 +500,48 @@ def plan_partition(row, col, val, shape, nshards: int,
                     remote_empty=remote_empty)
 
 
+def group_ranks(key, nkeys: int):
+    """Stable rank of every entry among the entries that share its key
+    (jit-able). Keys outside ``[0, nkeys)`` get rank 0.
+
+    One masked running count per key: O(nkeys * len(key)) elementwise
+    work, where a stable sort by key would pay a comparison sort of every
+    entry — at nnz = 3e7 and a handful of keys, 0.05 s per key against
+    7 s for the sort on a CPU host.
+    """
+    rank = jnp.zeros(key.shape, jnp.int32)
+    for k in range(nkeys):
+        hit = key == k
+        rank = jnp.where(hit, jnp.cumsum(hit, dtype=jnp.int32) - 1, rank)
+    return rank
+
+
 def partition_execute(row, col, val, plan: DistPlan,
                       dtype=jnp.float32) -> Tuple[COO, COO]:
     """Numeric phase of the slab partitioner (jit-able, ``plan`` static).
 
-    One stable ``argsort`` over the global triplets orders entries by
-    (shard, local/remote); a rank-within-group scatter then drops every
-    entry into its slot of the stacked uniform-capacity containers. Local
-    columns are renumbered shard-relative, remote columns halo-relative
-    (neighbor mode) or kept global (gather mode). Zero device->host
-    transfers.
+    Every entry's stable rank within its (shard, local/remote) group
+    (:func:`group_ranks`) drops it into its slot of the stacked
+    uniform-capacity containers in one scatter. Local columns are
+    renumbered shard-relative, remote columns halo-relative (neighbor
+    mode) or kept global (gather mode). Zero device->host transfers.
     """
     nshards, mp, hw = plan.nshards, plan.mp, plan.hw
     row = jnp.asarray(row).astype(jnp.int32)
     col = jnp.asarray(col).astype(jnp.int32)
     val = jnp.asarray(val).astype(dtype)
-    nent = row.shape[0]
 
-    shard = row // mp
-    is_remote = (col // mp) != shard
-    key = shard * 2 + is_remote.astype(jnp.int32)
-    order = jnp.argsort(key, stable=True)
-    k_s, r_s, c_s, v_s = key[order], row[order], col[order], val[order]
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32),
-         jnp.cumsum(jnp.bincount(key, length=2 * nshards)).astype(jnp.int32)])
-    rank = jnp.arange(nent, dtype=jnp.int32) - starts[k_s]
-    p = k_s // 2
-    rem = (k_s % 2) == 1
+    p = row // mp
+    rem = (col // mp) != p
+    rank = group_ranks(p * 2 + rem.astype(jnp.int32), 2 * nshards)
 
-    lrow = r_s - p * mp
-    lcol = c_s - p * mp
+    lrow = row - p * mp
+    lcol = col - p * mp
     if plan.halo_mode == "neighbor" and not plan.remote_empty:
-        below = c_s < p * mp
-        rcol = jnp.where(below, c_s - (p * mp - hw), hw + (c_s - (p + 1) * mp))
+        below = col < p * mp
+        rcol = jnp.where(below, col - (p * mp - hw), hw + (col - (p + 1) * mp))
     else:
-        rcol = c_s
+        rcol = col
 
     def scatter(select, cap, cols, vals):
         # in-capacity entries land at p*cap + rank; everything else (the
@@ -543,8 +557,8 @@ def partition_execute(row, col, val, plan: DistPlan,
             out.append(buf[:nshards * cap].reshape(nshards, cap))
         return out
 
-    lr, lc, lv = scatter(~rem, plan.local_cap, lcol, v_s)
-    rr, rc, rv = scatter(rem, plan.remote_cap, rcol, v_s)
+    lr, lc, lv = scatter(~rem, plan.local_cap, lcol, val)
+    rr, rc, rv = scatter(rem, plan.remote_cap, rcol, val)
     local = COO(lr, lc, lv, plan.local_shape, plan.local_cap)
     remote = COO(rr, rc, rv, plan.remote_shape, plan.remote_cap)
     return local, remote

@@ -38,30 +38,20 @@ class HPCGProblem:
 def generate_problem(nx: int, ny: int, nz: int, dtype=np.float32) -> HPCGProblem:
     """27-point stencil: diag = 26, off-diag = -1 (HPCG's synthetic system)."""
     n = nx * ny * nz
-    x, y, z = np.meshgrid(np.arange(nx), np.arange(ny), np.arange(nz), indexing="ij")
-    # row index, x-fastest ordering
-    idx = (x + nx * (y + ny * z)).ravel()
-    xs, ys, zs = x.ravel(), y.ravel(), z.ravel()
-
-    rows, cols, vals = [], [], []
-    for dz in (-1, 0, 1):
-        for dy in (-1, 0, 1):
-            for dx in (-1, 0, 1):
-                nxp, nyp, nzp = xs + dx, ys + dy, zs + dz
-                ok = ((nxp >= 0) & (nxp < nx) & (nyp >= 0) & (nyp < ny)
-                      & (nzp >= 0) & (nzp < nz))
-                r = idx[ok]
-                c = (nxp + nx * (nyp + ny * nzp))[ok]
-                v = np.where(r == c, 26.0, -1.0).astype(dtype)
-                rows.append(r)
-                cols.append(c)
-                vals.append(v)
-    row = np.concatenate(rows)
-    col = np.concatenate(cols)
-    val = np.concatenate(vals)
-    order = np.lexsort((col, row))
-    return HPCGProblem(nx, ny, nz, row[order].astype(np.int64),
-                       col[order].astype(np.int64), val[order], (n, n))
+    # grid coordinates of rows 0..n-1 (x-fastest), and for every row its 27
+    # neighbours in (dz, dy, dx) order — ascending column within each row,
+    # so the row-major compaction below is already (row, col)-sorted
+    z, y, x = (a.ravel() for a in np.meshgrid(
+        np.arange(nz), np.arange(ny), np.arange(nx), indexing="ij"))
+    d = np.array([-1, 0, 1])
+    dz, dy, dx = (a.ravel() for a in np.meshgrid(d, d, d, indexing="ij"))
+    xp, yp, zp = x[:, None] + dx, y[:, None] + dy, z[:, None] + dz
+    ok = ((xp >= 0) & (xp < nx) & (yp >= 0) & (yp < ny)
+          & (zp >= 0) & (zp < nz))
+    col = (xp + nx * (yp + ny * zp))[ok].astype(np.int64)
+    row = np.repeat(np.arange(n, dtype=np.int64), ok.sum(axis=1))
+    val = np.where(row == col, 26.0, -1.0).astype(dtype)
+    return HPCGProblem(nx, ny, nz, row, col, val, (n, n))
 
 
 def to_coo(prob: HPCGProblem, capacity: Optional[int] = None,

@@ -42,25 +42,19 @@ def csr_row_ids(indptr, capacity: int, m: int):
     return jnp.clip(rows, 0, m - 1)
 
 
-def resolve_backend(backend: str, A=None) -> str:
-    """Resolve the ``"auto"`` backend name to a concrete one.
+def resolve_backend(backend: str, A) -> str:
+    """Resolve the ``"auto"`` backend name to a concrete one for ``A``.
 
-    With a matrix, ``auto`` answers from *measurement*: it routes to the
-    Pallas kernels iff the kernel-config cache
-    (``repro.tuning.kernel_tune``) holds a winner for ``A``'s (format,
-    shape bucket, backend, device) whose measured time beats the reference
-    path — a kernel that merely compiles, or that was measured slower,
-    never takes the hot path. Without a matrix (legacy callers) it falls
-    back to the coarse compile test: ``pallas`` when the kernels lower
-    natively (TPU, or ``REPRO_FORCE_INTERPRET=0``), else ``ref``.
+    ``auto`` answers from *measurement*: it routes to the Pallas kernels
+    iff the kernel-config cache (``repro.tuning.kernel_tune``) holds a
+    winner for ``A``'s (format, shape bucket, backend, device) whose
+    measured time beats the reference path — a kernel that merely
+    compiles, or that was measured slower, never takes the hot path.
     Concrete names pass through unchanged.
     """
     if backend != "auto":
         return backend
-    if A is not None:
-        return kernel_route(A)[0]
-    from repro.kernels import ops as kops  # lazy: keep core import-light
-    return kops.auto_backend()
+    return kernel_route(A)[0]
 
 
 def kernel_route(A, op: str = "spmv", cache=None, ncols=None):
@@ -118,6 +112,19 @@ def kernel_route(A, op: str = "spmv", cache=None, ncols=None):
     return "ref", None
 
 
+def _count_no_kernel(A, op: str) -> None:
+    """``backend="pallas"`` asked for a format with no kernel: the call
+    runs the reference path, and says so in ``kernel.route.ref``."""
+    _metrics.inc("kernel.route.ref")
+    if _trace.mode() != "off":
+        _trace.event("kernel.route", op=op, route="ref",
+                     fmt=type(A).__name__)
+    if _ledger.enabled():
+        _ledger.record("kernel.route", op=op, fmt=type(A).__name__,
+                       route="ref", reason="backend='pallas' but no kernel "
+                       "exists for this format")
+
+
 def _route_kernel_dict(rec) -> dict:
     return {"fmt": rec.fmt, "op": rec.op, "cfg": dict(rec.cfg),
             "kernel_us": float(rec.kernel_us), "ref_us": float(rec.ref_us),
@@ -133,6 +140,21 @@ def _route_bucket(A, op: str, ncols) -> str:
             max(1, int(getattr(A, "nnz", 1))), op=op, ncols=ncols)
     except Exception:
         return "?"
+
+
+def vma(*xs) -> frozenset:
+    """The manual mesh axes any of ``xs`` varies over (empty outside
+    ``shard_map``)."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
+
+
+def varying_like(z, *xs):
+    """``z`` declared varying over every manual axis ``xs`` vary over.
+
+    A value created inside a shard body (a zero loop carry) is invariant
+    across the mesh; ``shard_map``'s type check rejects combining it with
+    per-shard operands in a carry until it is cast to match them."""
+    return jax.lax.pcast(z, tuple(vma(*xs)), to="varying")
 
 
 def _spmv_coo(A: COO, x):
@@ -168,7 +190,8 @@ def _spmv_dia(A: DIA, x):
         w = jax.lax.dynamic_slice(xp, (off + m,), (m,))
         return acc + drow * w, None
 
-    acc0 = jnp.zeros((m,), jnp.result_type(A.dtype, x.dtype))
+    acc0 = varying_like(jnp.zeros((m,), jnp.result_type(A.dtype, x.dtype)),
+                        x, A.data)
     acc, _ = jax.lax.scan(one_diag, acc0,
                           (A.offsets.astype(jnp.int32), A.data),
                           unroll=min(A.ndiag, _DIA_UNROLL_MAX))
@@ -266,6 +289,7 @@ def spmv(A, x, backend: str = "ref", cfg=None):
         fn = kops.SPMV_PALLAS.get(type(A))
         if fn is not None:
             return fn(A, x, cfg=cfg)
+        _count_no_kernel(A, "spmv")
     return _SPMV[type(A)](A, x)
 
 
@@ -355,6 +379,7 @@ def spmm(A, B, backend: str = "ref", cfg=None):
         fn = kops.SPMM_PALLAS.get(type(A))
         if fn is not None:
             return fn(A, B, cfg=cfg)
+        _count_no_kernel(A, "spmm")
     return _SPMM[type(A)](A, B)
 
 
@@ -379,6 +404,7 @@ def spmm_t(A, X, backend: str = "ref", cfg=None):
         fn = kops.SPMM_T_PALLAS.get(type(A))
         if fn is not None:
             return fn(A, X, cfg=cfg)
+        _count_no_kernel(A, "spmm_t")
     return _SPMM[type(A)](A, X.T).T
 
 

@@ -12,7 +12,10 @@ flags can abort process startup. This module therefore
     while the halo ``ppermute`` is in flight, CPU gets only the
     forced-host-device-count flag (the SPMD test/bench harness);
   * merges with any caller-set ``XLA_FLAGS``, replacing only the flags it
-    manages — a user's unrelated flags pass through untouched.
+    manages — a user's unrelated flags pass through untouched;
+  * keeps JAX's persistent compile cache in ``<checkout>/.jax_cache``
+    unless ``JAX_COMPILATION_CACHE_DIR`` already names a directory (then
+    that one is used, and no other is set).
 
 Entry points (``benchmarks/run.py``, the bench subprocess scripts,
 ``examples/hpcg_solve.py``, CI) call :func:`apply` first thing::
@@ -48,6 +51,11 @@ _GPU_FLAGS = (
 
 _applied: Optional[Dict[str, object]] = None
 
+# <checkout>/.jax_cache: this file is <checkout>/src/repro/env.py
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
 
 def resolve_backend(backend: Optional[str] = None) -> str:
     """Resolve the target backend without importing jax.
@@ -81,6 +89,9 @@ def apply(backend: Optional[str] = None,
     they are *not* (unknown or inapplicable flags can abort XLA startup,
     so every flag is backend-gated).
 
+    ``JAX_COMPILATION_CACHE_DIR`` is set to :data:`COMPILE_CACHE_DIR`
+    when unset; a caller's value is left alone.
+
     Idempotent and safe to call multiple times; warns (but still sets the
     environment for child processes) when jax already initialized in this
     process, since the running backend will not see the change.
@@ -104,8 +115,11 @@ def apply(backend: Optional[str] = None,
     flags = _merge_flags(os.environ.get("XLA_FLAGS", ""), managed)
     if flags:
         os.environ["XLA_FLAGS"] = flags
+    cache_dir = os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                                      COMPILE_CACHE_DIR)
     _applied = {"backend": bk, "host_devices": host_devices,
-                "managed_flags": list(managed), "xla_flags": flags}
+                "managed_flags": list(managed), "xla_flags": flags,
+                "compile_cache_dir": cache_dir}
     return dict(_applied)
 
 
@@ -115,4 +129,5 @@ def describe() -> Dict[str, object]:
     if _applied is not None:
         return dict(_applied)
     return {"backend": resolve_backend(), "host_devices": None,
-            "managed_flags": [], "xla_flags": os.environ.get("XLA_FLAGS", "")}
+            "managed_flags": [], "xla_flags": os.environ.get("XLA_FLAGS", ""),
+            "compile_cache_dir": os.environ.get("JAX_COMPILATION_CACHE_DIR")}

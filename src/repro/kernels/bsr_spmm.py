@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.ops import vma
+
 
 def _bsr_kernel(indptr_ref, brow_ref, bcol_ref, blocks_ref, b_ref, y_ref, acc_ref):
     k = pl.program_id(1)
@@ -80,7 +82,8 @@ def bsr_spmm(indptr: jax.Array, brow: jax.Array, bcol: jax.Array,
             out_specs=pl.BlockSpec((bs, tn), lambda j, k, ptr, br, bc: (br[k], j)),
             scratch_shapes=[pltpu.VMEM((bs, tn), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((m, kbp), B.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, kbp), B.dtype,
+                                       vma=vma(blocks, B)),
         interpret=interpret,
     )(indptr, brow, bcol, blocks, B)
     return y[:, :kb]

@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.ops import vma
+
 
 def _segmented_cumsum(v: jax.Array, flags: jax.Array, axis: int = 0) -> jax.Array:
     """Inclusive prefix sum of ``v`` along ``axis`` that restarts wherever
@@ -84,9 +86,9 @@ def _spmm_kernel(indptr_ref, starts_ref, ends_ref, rows_ref, indices_ref,
 
     def window(w, acc):
         base = w0 + w * tk
-        cols = pl.load(indices_ref, (pl.ds(base, tk),))
-        vals = pl.load(data_ref, (pl.ds(base, tk),))
-        rws = pl.load(rows_ref, (pl.ds(base, tk),))
+        cols = indices_ref[pl.ds(base, tk)]
+        vals = data_ref[pl.ds(base, tk)]
+        rws = rows_ref[pl.ds(base, tk)]
         gathered = jnp.take(b, cols, axis=0, mode="clip")      # (tk, tn)
         contrib = vals.astype(jnp.float32)[:, None] * gathered.astype(jnp.float32)
         flags = jnp.concatenate(
@@ -118,9 +120,9 @@ def _spmm_t_kernel(indptr_ref, starts_ref, ends_ref, rows_ref, indices_ref,
 
     def window(w, acc):
         base = w0 + w * tk
-        cols = pl.load(indices_ref, (pl.ds(base, tk),))
-        vals = pl.load(data_ref, (pl.ds(base, tk),))
-        rws = pl.load(rows_ref, (pl.ds(base, tk),))
+        cols = indices_ref[pl.ds(base, tk)]
+        vals = data_ref[pl.ds(base, tk)]
+        rws = rows_ref[pl.ds(base, tk)]
         gathered = jnp.take(x, jnp.clip(cols, 0, x.shape[1] - 1), axis=1)
         contrib = vals.astype(jnp.float32)[None, :] * gathered.astype(jnp.float32)
         flags = jnp.concatenate(
@@ -190,7 +192,8 @@ def csr_spmm(indptr: jax.Array, rows: jax.Array, indices: jax.Array,
             ],
             out_specs=pl.BlockSpec((tm, tn), lambda i, j, *_: (i, j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((mp, kp), B.dtype),
+        out_shape=jax.ShapeDtypeStruct((mp, kp), B.dtype,
+                                       vma=vma(data, B)),
         interpret=interpret,
     )(indptr, starts_of(indptr), ends_of(indptr), rows, indices, data, B)
     return y[:m, :kb]
@@ -237,7 +240,8 @@ def csr_spmm_t(indptr: jax.Array, rows: jax.Array, indices: jax.Array,
             ],
             out_specs=pl.BlockSpec((tn, tm), lambda i, j, *_: (j, i)),
         ),
-        out_shape=jax.ShapeDtypeStruct((tp, mp), X.dtype),
+        out_shape=jax.ShapeDtypeStruct((tp, mp), X.dtype,
+                                       vma=vma(data, X)),
         interpret=interpret,
     )(indptr, starts_of(indptr), ends_of(indptr), rows, indices, data, X)
     return y[:t, :m]

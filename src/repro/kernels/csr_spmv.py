@@ -49,6 +49,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.ops import vma
+
 
 def _segmented_cumsum(v: jax.Array, flags: jax.Array) -> jax.Array:
     """Inclusive prefix sum of ``v`` that restarts wherever ``flags`` is
@@ -78,9 +80,9 @@ def _csr_kernel(indptr_ref, starts_ref, ends_ref, rows_ref, indices_ref,
 
     def window(w, acc):
         base = w0 + w * tk
-        cols = pl.load(indices_ref, (pl.ds(base, tk),))
-        vals = pl.load(data_ref, (pl.ds(base, tk),))
-        rws = pl.load(rows_ref, (pl.ds(base, tk),))
+        cols = indices_ref[pl.ds(base, tk)]
+        vals = data_ref[pl.ds(base, tk)]
+        rws = rows_ref[pl.ds(base, tk)]
         contrib = (vals.astype(jnp.float32)
                    * jnp.take(x, cols, mode="clip").astype(jnp.float32))
         # segment boundaries = row changes; the scan implicitly restarts at
@@ -143,7 +145,7 @@ def csr_spmv(indptr: jax.Array, rows: jax.Array, indices: jax.Array,
             ],
             out_specs=pl.BlockSpec((tm,), lambda i, *_: (i,)),
         ),
-        out_shape=jax.ShapeDtypeStruct((mp,), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((mp,), x.dtype, vma=vma(data, x)),
         interpret=interpret,
     )(indptr, starts, ends, rows, indices, data, x)
     return y[:m]

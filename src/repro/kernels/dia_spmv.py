@@ -2,22 +2,29 @@
 
 The paper's winning format for stencil matrices, re-derived for TPU
 (DESIGN.md §2): every diagonal contributes one *contiguous, shifted*
-multiply-add — pure VPU work, zero gathers, zero index arithmetic per
-element. This is the access pattern vector machines were built for, and the
-reason DIA transfers so well from the paper's GPUs to the TPU's VPU.
+multiply-add — pure VPU work, zero gathers. Vectors live in the lane-dense
+``(rows / 128, 128)`` layout, so a row tile of ``tm`` rows is a ``(T, 128)``
+block with ``T = tm / 128``.
 
 Blocking strategy:
-  * grid over row tiles of size ``tm`` (multiple of 128 lanes);
-  * the diagonal table ``data[ndiag, M]`` streams through VMEM one
-    ``(ndiag, tm)`` tile per grid step;
-  * ``x`` is pre-padded by ``pad`` zeros on both sides so every shifted
-    window load is in-bounds and mask-free (zero padding in the table makes
-    out-of-matrix lanes contribute 0); the padded vector is resident in VMEM;
-  * ``offsets`` ride in SMEM via scalar prefetch and drive dynamic-start
-    (``pl.ds``) window loads — the TPU analogue of the diagonal walk.
+  * grid over row tiles; the diagonal table ``data[ndiag, M]`` streams
+    through VMEM one ``(ndiag, T, 128)`` block per grid step;
+  * ``x`` stays in HBM. Per (tile, diagonal) the kernel DMAs the
+    8-row-aligned ``(T + 16, 128)`` window that covers
+    ``x[row0 + off : row0 + off + tm]`` into a double-buffered VMEM slot —
+    the next diagonal's window is in flight while this one is summed — so
+    VMEM holds two windows, never the whole vector, whatever the reach;
+  * the window is shifted into place in registers: a sublane rotate by
+    the row remainder, a lane rotate by the lane remainder, and one select
+    between the row and the row below it. No unaligned dynamic load;
+  * ``offsets`` ride in SMEM via scalar prefetch and drive the DMA starts.
+    A diagonal that misses the tile entirely is skipped (its window is
+    clamped in bounds and its contribution dropped); partial windows read
+    the zero padding of ``x`` outside ``[0, n)``, so out-of-matrix entries
+    contribute nothing.
 
-VMEM budget per step: ndiag*tm*4 + (N + 2*pad)*4 bytes; the ops wrapper
-falls back to the reference implementation when x would not fit.
+VMEM per step: 2 * ndiag * tm * itemsize (data, double-buffered) +
+2 * (tm + 2048) * 4 (x windows) + 2 * tm * 4 (y).
 """
 from __future__ import annotations
 
@@ -28,58 +35,108 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.ops import vma
 
-def _dia_kernel(offsets_ref, data_ref, x_ref, y_ref, *, pad: int, tm: int):
-    i = pl.program_id(0)
+LANES = 128
+_SUB = 8  # f32 sublanes per vreg: DMA starts are aligned to this many rows
+
+
+def row_unit(dtype) -> int:
+    """Row-tile granule: ``tm`` must be a multiple of this — whole
+    ``(sublane-tile, 128)`` blocks of the diagonal table's dtype."""
+    return LANES * _SUB * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _dia_kernel(offsets_ref, data_ref, x_hbm, y_ref, buf, sem, *,
+                tm: int, n: int, lpad: int, smax: int):
+    t = tm // LANES
+    w = t + 2 * _SUB
     ndiag = data_ref.shape[0]
-    row0 = i * tm
+    row0 = pl.program_id(0) * tm
+
+    def start_of(d):
+        # element start of the diagonal's window in padded coordinates,
+        # clamped so the DMA stays in bounds (a clamped window is dead)
+        return jnp.clip(row0 + offsets_ref[d] + lpad, 0, smax)
+
+    def window_copy(d, slot):
+        a8 = pl.multiple_of(start_of(d) // (LANES * _SUB) * _SUB, _SUB)
+        return pltpu.make_async_copy(x_hbm.at[pl.ds(a8, w)], buf.at[slot],
+                                     sem.at[slot])
+
+    window_copy(0, 0).start()
+    lane = jax.lax.broadcasted_iota(jnp.int32, (t, LANES), 1)
 
     def body(d, acc):
-        off = offsets_ref[d]
-        # contiguous shifted window: x_pad[pad + row0 + off : ... + tm]
-        start = pad + row0 + off
-        window = pl.load(x_ref, (pl.ds(start, tm),))
-        dline = pl.load(data_ref, (pl.ds(d, 1), slice(None)))[0]
-        return acc + dline * window
+        slot = d % 2
 
-    acc = jax.lax.fori_loop(0, ndiag, body, jnp.zeros((tm,), jnp.float32))
-    y_ref[...] = acc.astype(y_ref.dtype)
+        @pl.when(d + 1 < ndiag)
+        def _():
+            window_copy(d + 1, 1 - slot).start()
+
+        window_copy(d, slot).wait()
+        s = start_of(d)
+        sub = s // LANES % _SUB           # row remainder inside the window
+        b = s % LANES                     # lane remainder
+        xw = buf[slot]                                  # (w, 128)
+        xw = pltpu.roll(xw, (w - sub) % w, 0)           # row sub -> row 0
+        xw = pltpu.roll(xw, (LANES - b) % LANES, 1)     # lane b -> lane 0
+        nxt = pltpu.roll(xw, w - 1, 0)                  # the row below
+        win = jnp.where(lane < LANES - b, xw[:t], nxt[:t])
+        off = offsets_ref[d]
+        live = (row0 + off < n) & (row0 + off + tm > 0)
+        contrib = data_ref[d].astype(jnp.float32) * win
+        return jnp.where(live, acc + contrib, acc)
+
+    acc = jax.lax.fori_loop(0, ndiag, body,
+                            jnp.zeros((t, LANES), jnp.float32))
+    y_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("n", "tm", "interpret"))
 def dia_spmv(offsets: jax.Array, data: jax.Array, x: jax.Array, n: int,
-             tm: int = 512, interpret: bool = True) -> jax.Array:
+             tm: int = 8192, interpret: bool = False) -> jax.Array:
     """y = A @ x for DIA A given as (offsets[ndiag], data[ndiag, M]).
 
     ``x`` has length ``n`` (rectangular matrices supported). ``data`` rows
-    follow the cusp convention data[d, i] = A[i, i + offsets[d]] with zeros
-    where the diagonal leaves the matrix.
+    follow the cusp convention data[d, i] = A[i, i + offsets[d]]; entries
+    whose column falls outside ``[0, n)`` contribute nothing. ``tm`` must
+    be a multiple of :func:`row_unit` of the data dtype.
     """
+    unit = row_unit(data.dtype)
+    if tm <= 0 or tm % unit:
+        raise ValueError(f"DIA kernel row tile tm={tm} is not a positive "
+                         f"multiple of {unit} rows for "
+                         f"{jnp.dtype(data.dtype).name} data")
     ndiag, m = data.shape
-    mp = ((m + tm - 1) // tm) * tm
-    if mp != m:
-        data = jnp.pad(data, ((0, 0), (0, mp - m)))
-    # pad so every window load [row0+off, row0+off+tm) lands in-bounds:
-    # row0+off spans [-(pad), mp-tm+pad] => left pad >= max|off|+0, right pad
-    # >= max|off| + (mp - n) + tm slack. Static bound: pad to a safe superset.
-    pad = mp + tm  # static, covers any int32 offset clamped below
-    offsets = jnp.clip(offsets.astype(jnp.int32), -(m + tm), n + tm)
-    x_pad = jnp.pad(x, (pad, pad + (mp - min(n, mp)) + tm))
-
-    grid = (mp // tm,)
-    kernel = functools.partial(_dia_kernel, pad=pad, tm=tm)
+    m128 = -(-m // LANES) * LANES
+    if m128 != m:
+        data = jnp.pad(data, ((0, 0), (0, m128 - m)))
+    t = tm // LANES
+    # x in padded coordinates: tm zeros on the left (a live window starts
+    # above -tm), then x, then zeros so the last live window's
+    # (t + 16)-row DMA ends in bounds; total a whole number of 8-row blocks
+    lpad = tm
+    total = -(-(lpad + n + tm + 2 * _SUB * LANES) // (_SUB * LANES)) * (_SUB * LANES)
+    x_pad = jnp.pad(x.astype(jnp.float32), (lpad, total - lpad - n))
+    smax = total - (t + 2 * _SUB) * LANES
+    kernel = functools.partial(_dia_kernel, tm=tm, n=n, lpad=lpad, smax=smax)
     y = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(pl.cdiv(m128 // LANES, t),),
             in_specs=[
-                pl.BlockSpec((ndiag, tm), lambda i, *_: (0, i)),
-                pl.BlockSpec(x_pad.shape, lambda i, *_: (0,)),
+                pl.BlockSpec((ndiag, t, LANES), lambda i, *_: (0, i, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((tm,), lambda i, *_: (i,)),
+            out_specs=pl.BlockSpec((t, LANES), lambda i, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((2, t + 2 * _SUB, LANES), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))],
         ),
-        out_shape=jax.ShapeDtypeStruct((mp,), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((m128 // LANES, LANES), jnp.float32,
+                                       vma=vma(offsets, data, x)),
         interpret=interpret,
-    )(offsets, data, x_pad)
-    return y[:m]
+    )(offsets.astype(jnp.int32), data.reshape(ndiag, m128 // LANES, LANES),
+      x_pad.reshape(total // LANES, LANES))
+    return y.reshape(m128)[:m].astype(x.dtype)

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.ops import vma
+
 
 def _ell_kernel_row(cols_ref, data_ref, x_ref, y_ref):
     cols = cols_ref[...]                       # (tm, K)
@@ -88,7 +90,7 @@ def ell_spmv(cols: jax.Array, data: jax.Array, x: jax.Array,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tm,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((mp,), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((mp,), x.dtype, vma=vma(data, x)),
         interpret=interpret,
     )(cols, data, x)
     return y[:m]
@@ -184,7 +186,8 @@ def ell_spmm(cols: jax.Array, data: jax.Array, B: jax.Array,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tm, tn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, kp), B.dtype),
+        out_shape=jax.ShapeDtypeStruct((mp, kp), B.dtype,
+                                       vma=vma(data, B)),
         interpret=interpret,
     )(cols, data, B)
     return y[:m, :kb]
@@ -222,7 +225,8 @@ def ell_spmm_t(cols: jax.Array, data: jax.Array, X: jax.Array,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tn, tm), lambda i, j: (j, i)),
-        out_shape=jax.ShapeDtypeStruct((tp, mp), X.dtype),
+        out_shape=jax.ShapeDtypeStruct((tp, mp), X.dtype,
+                                       vma=vma(data, X)),
         interpret=interpret,
     )(cols, data, X)
     return y[:t, :m]

@@ -1,11 +1,9 @@
 """jit'd wrappers binding the Pallas kernels to the core containers.
 
-``INTERPRET`` is True off-TPU: the kernel bodies execute in Python on CPU
-(correctness validation); on TPU the same code lowers through Mosaic. The
-``REPRO_FORCE_INTERPRET=0|1`` environment variable overrides the TPU
-detection in either direction — re-read on every call, so tests/CI can
-exercise the compiled-path plumbing (or pin interpret mode on a TPU host)
-without monkeypatching module state.
+Kernels run in the Pallas interpreter on the CPU backend only (correctness
+checks: :func:`interpret_mode`); on a TPU they always compile through
+Mosaic, and a kernel that Mosaic refuses is an error, never a quiet
+interpreter run.
 
 Every SpMV/SpMM entry point takes ``cfg=`` — a kernel tile-config dict
 (e.g. ``{"tm": 256, "tk": 2048}`` for CSR, ``{"tm": 1024, "layout":
@@ -15,22 +13,23 @@ derived from the matrix's shape and average row nnz). Measured winning
 configs come from ``repro.tuning.kernel_tune`` and are threaded here by
 ``repro.core.ops.spmv(backend="auto")``.
 
-Wrappers enforce each kernel's structural preconditions and fall back to the
-pure-jnp reference path when they do not hold (e.g. x too large for VMEM
-residency, empty BSR block rows) — the dynamic-format machinery guarantees a
-correct answer either way.
+The DIA wrapper (the stencil main path) streams ``x`` from HBM and has no
+size fallback: a shape its kernel cannot take raises a ``ValueError`` that
+names it. The other wrappers still fall back to the pure-jnp reference
+path when their VMEM-resident operands would not fit (e.g. x too large
+for VMEM residency, empty BSR block rows).
 """
 from __future__ import annotations
 
-import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import BSR, CSR, DIA, ELL, HYB, SELL
+from repro.core.ops import vma
 from repro.kernels import bsr_spmm as _bsr
 from repro.kernels import csr_spmm as _csr_mm
 from repro.kernels import csr_spmv as _csr
@@ -39,38 +38,29 @@ from repro.kernels import ell_spmv as _ell
 from repro.kernels import sell_spmv as _sell
 
 
-def _env_interpret():
-    v = os.environ.get("REPRO_FORCE_INTERPRET", "").strip()
-    if v in ("0", "1"):
-        return v == "1"
-    return None
-
-
-_DETECTED = jax.default_backend() != "tpu"
-INTERPRET = _env_interpret() if _env_interpret() is not None else _DETECTED
-
-
 def interpret_mode() -> bool:
-    """Effective interpret flag: ``REPRO_FORCE_INTERPRET`` (if set) wins
-    over the import-time TPU detection baked into ``INTERPRET``."""
-    env = _env_interpret()
-    return INTERPRET if env is None else env
+    """True exactly on the CPU backend, where kernel bodies run in the
+    Pallas interpreter; every other backend compiles them natively."""
+    return jax.default_backend() == "cpu"
 
 
-def auto_backend() -> str:
-    """Backend the kernels would *compile* to right now: ``"pallas"`` when
-    they lower natively (TPU, or the interpret override is forced off),
-    ``"ref"`` when they would run interpreted. NOTE: ``"auto"`` SpMV
-    routing no longer uses this compile test alone — it requires a
-    measured kernel config that beats the reference path (see
-    ``repro.core.ops.resolve_backend``); this predicate remains for
-    callers that only care whether native lowering is available."""
-    return "ref" if interpret_mode() else "pallas"
+def _interpret(*operands):
+    """The ``interpret=`` argument for a kernel call on ``operands``:
+    ``False`` (compile) off the CPU backend. On CPU the HLO interpreter
+    runs the kernel, except inside a ``shard_map`` body, where its grid
+    loop trips the varying-axes check; there the TPU interpreter
+    (``pltpu.InterpretParams``) runs it."""
+    if not interpret_mode():
+        return False
+    return pltpu.InterpretParams() if vma(*jax.tree.leaves(operands)) else True
 
 
 # VMEM residency budget for the x vector (bytes); beyond this the wrappers
 # fall back to the reference path (v5e has ~16 MiB VMEM per core).
 X_VMEM_BUDGET = 6 * 1024 * 1024
+
+# VMEM for the DIA kernel's double-buffered (ndiag, tm) diagonal tiles.
+DIA_VMEM_BUDGET = 8 * 1024 * 1024
 
 
 # ---------------------------------------------------------------------------
@@ -185,7 +175,7 @@ def default_config(A, op: str = "spmv", ncols: Optional[int] = None) -> dict:
             return {"ts": ts, "tn": _rhs_tile(ncols)}
         return {"ts": ts}
     if isinstance(A, DIA):
-        return {"tm": _pow2_clamp(min(m, 512), 8, 2048)}
+        return {"tm": _dia_tm(A)}
     if isinstance(A, BSR):
         return {"tn": 128}
     if isinstance(A, HYB):
@@ -202,16 +192,32 @@ def default_config(A, op: str = "spmv", ncols: Optional[int] = None) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _dia_tm(A: DIA) -> int:
+    """Default DIA row tile: up to 8192 rows, no more than the matrix
+    needs, shrunk until the double-buffered diagonal tiles fit
+    :data:`DIA_VMEM_BUDGET`."""
+    unit = _dia.row_unit(A.dtype)
+    per_row = 2 * max(1, A.ndiag) * A.dtype.itemsize
+    fit = DIA_VMEM_BUDGET // per_row // unit * unit
+    need = -(-A.shape[0] // unit) * unit
+    return max(unit, min(8192, need, fit))
+
+
 def dia_spmv(A: DIA, x: jax.Array, tm: Optional[int] = None,
              cfg: Optional[dict] = None) -> jax.Array:
+    """DIA SpMV via the HBM-streaming Pallas kernel. Raises ``ValueError``
+    when the (ndiag, tm) diagonal tiles exceed :data:`DIA_VMEM_BUDGET`."""
     cfg = resolve_config(A, cfg)
     tm = int(_pick(tm, cfg, "tm", A))
-    n = A.shape[1]
-    if (n + 2 * (A.data.shape[1] + tm)) * x.dtype.itemsize > X_VMEM_BUDGET:
-        from repro.core import ops as core_ops
-        return core_ops._spmv_dia(A, x)
-    return _dia.dia_spmv(A.offsets, A.data, x, n, tm=tm,
-                         interpret=interpret_mode())
+    need = 2 * A.ndiag * tm * A.dtype.itemsize
+    if need > DIA_VMEM_BUDGET:
+        raise ValueError(
+            f"DIA kernel: {A.ndiag} diagonals x tm={tm} rows need {need} "
+            f"bytes of VMEM for the diagonal tiles, over the "
+            f"{DIA_VMEM_BUDGET}-byte budget; use a smaller tm or another "
+            f"format")
+    return _dia.dia_spmv(A.offsets, A.data, x, A.shape[1], tm=tm,
+                         interpret=_interpret(A, x))
 
 
 def ell_spmv(A: ELL, x: jax.Array, tm: Optional[int] = None,
@@ -224,7 +230,7 @@ def ell_spmv(A: ELL, x: jax.Array, tm: Optional[int] = None,
         from repro.core import ops as core_ops
         return core_ops._spmv_ell(A, x)
     return _ell.ell_spmv(A.cols, A.data, x, tm=tm, layout=layout,
-                         interpret=interpret_mode())
+                         interpret=_interpret(A, x))
 
 
 def sell_spmv(A: SELL, x: jax.Array, ts: Optional[int] = None,
@@ -240,7 +246,7 @@ def sell_spmv(A: SELL, x: jax.Array, ts: Optional[int] = None,
         return core_ops._spmv_sell(A, x)
     return _sell.sell_spmv(A.slice_ptrs, A.cols, A.data, A.perm, x,
                            m=A.shape[0], c=A.c, ts=ts,
-                           interpret=interpret_mode())
+                           interpret=_interpret(A, x))
 
 
 def csr_spmv(A: CSR, x: jax.Array, tm: Optional[int] = None,
@@ -256,7 +262,7 @@ def csr_spmv(A: CSR, x: jax.Array, tm: Optional[int] = None,
     tm, tk = _csr_tiles(A.shape[0], A.nnz, resolve_config(A, cfg), tm=tm, tk=tk)
     rows = core_ops.csr_row_ids(A.indptr, A.capacity, A.shape[0])
     return _csr.csr_spmv(A.indptr, rows, A.indices, A.data, x, tm=tm, tk=tk,
-                         interpret=interpret_mode())
+                         interpret=_interpret(A, x))
 
 
 def hyb_spmv(A: HYB, x: jax.Array, cfg: Optional[dict] = None) -> jax.Array:
@@ -279,7 +285,7 @@ def hyb_spmv(A: HYB, x: jax.Array, cfg: Optional[dict] = None) -> jax.Array:
          jnp.cumsum(jnp.bincount(rows, length=A.shape[0])).astype(jnp.int32)])
     tm, tk = _csr_tiles(A.shape[0], c.nnz, cfg.get("csr"))
     tail = _csr.csr_spmv(indptr, rows, c.col[order], c.data[order], x,
-                         tm=tm, tk=tk, interpret=interpret_mode())
+                         tm=tm, tk=tk, interpret=_interpret(A, x))
     return y + tail
 
 
@@ -306,7 +312,7 @@ def bsr_spmm(A: BSR, B: jax.Array, tn: Optional[int] = None,
         return core_ops._spmm_bsr(A, B)
     brow = _bsr_brow(A)
     return _bsr.bsr_spmm(A.indptr, brow, A.indices, A.data, B, A.shape[0],
-                         tn=tn, interpret=interpret_mode())
+                         tn=tn, interpret=_interpret(A, B))
 
 
 def bsr_spmv(A: BSR, x: jax.Array, tn: Optional[int] = None,
@@ -342,7 +348,7 @@ def csr_spmm(A: CSR, B: jax.Array, tm: Optional[int] = None,
         return core_ops._spmm_csr(A, B)
     rows = core_ops.csr_row_ids(A.indptr, A.capacity, A.shape[0])
     return _csr_mm.csr_spmm(A.indptr, rows, A.indices, A.data, B,
-                            tm=tm, tk=tk, tn=tn, interpret=interpret_mode())
+                            tm=tm, tk=tk, tn=tn, interpret=_interpret(A, B))
 
 
 def csr_spmm_t(A: CSR, X: jax.Array, tm: Optional[int] = None,
@@ -356,7 +362,7 @@ def csr_spmm_t(A: CSR, X: jax.Array, tm: Optional[int] = None,
         return core_ops._spmm_csr(A, X.T).T
     rows = core_ops.csr_row_ids(A.indptr, A.capacity, A.shape[0])
     return _csr_mm.csr_spmm_t(A.indptr, rows, A.indices, A.data, X,
-                              tm=tm, tk=tk, tn=tn, interpret=interpret_mode())
+                              tm=tm, tk=tk, tn=tn, interpret=_interpret(A, X))
 
 
 def _ell_spmm_cfg(A, cfg, op, ncols, tm=None, layout=None, tn=None):
@@ -383,7 +389,7 @@ def ell_spmm(A: ELL, B: jax.Array, tm: Optional[int] = None,
     if not _ell_spmm_fits(A, tm, layout, tn, A.shape[1]):
         return core_ops._spmm_ell(A, B)
     return _ell.ell_spmm(A.cols, A.data, B, tm=tm, tn=tn, layout=layout,
-                         interpret=interpret_mode())
+                         interpret=_interpret(A, B))
 
 
 def ell_spmm_t(A: ELL, X: jax.Array, tm: Optional[int] = None,
@@ -395,7 +401,7 @@ def ell_spmm_t(A: ELL, X: jax.Array, tm: Optional[int] = None,
     if not _ell_spmm_fits(A, tm, layout, tn, A.shape[1]):
         return core_ops._spmm_ell(A, X.T).T
     return _ell.ell_spmm_t(A.cols, A.data, X, tm=tm, tn=tn, layout=layout,
-                           interpret=interpret_mode())
+                           interpret=_interpret(A, X))
 
 
 def _hyb_tail_csr(A: HYB):
@@ -422,7 +428,7 @@ def hyb_spmm(A: HYB, B: jax.Array, cfg: Optional[dict] = None) -> jax.Array:
         return y + core_ops._spmm_coo(A.coo, B)
     indptr, rows, col, data = _hyb_tail_csr(A)
     tail = _csr_mm.csr_spmm(indptr, rows, col, data, B, tm=tm, tk=tk, tn=tn,
-                            interpret=interpret_mode())
+                            interpret=_interpret(A, B))
     return y + tail
 
 
@@ -437,7 +443,7 @@ def hyb_spmm_t(A: HYB, X: jax.Array, cfg: Optional[dict] = None) -> jax.Array:
         return y + core_ops._spmm_coo(A.coo, X.T).T
     indptr, rows, col, data = _hyb_tail_csr(A)
     tail = _csr_mm.csr_spmm_t(indptr, rows, col, data, X, tm=tm, tk=tk,
-                              tn=tn, interpret=interpret_mode())
+                              tn=tn, interpret=_interpret(A, X))
     return y + tail
 
 
@@ -457,7 +463,7 @@ def sell_spmm(A: SELL, B: jax.Array, ts: Optional[int] = None,
         return core_ops._spmm_sell(A, B)
     return _sell.sell_spmm(A.slice_ptrs, A.cols, A.data, A.perm, B,
                            m=A.shape[0], c=A.c, ts=ts, tn=tn,
-                           interpret=interpret_mode())
+                           interpret=_interpret(A, B))
 
 
 def sell_spmm_t(A: SELL, X: jax.Array, ts: Optional[int] = None,
@@ -469,7 +475,7 @@ def sell_spmm_t(A: SELL, X: jax.Array, ts: Optional[int] = None,
         return core_ops._spmm_sell(A, X.T).T
     return _sell.sell_spmm_t(A.slice_ptrs, A.cols, A.data, A.perm, X,
                              m=A.shape[0], c=A.c, ts=ts, tn=tn,
-                             interpret=interpret_mode())
+                             interpret=_interpret(A, X))
 
 
 def bsr_spmm_t(A: BSR, X: jax.Array, tn: Optional[int] = None,

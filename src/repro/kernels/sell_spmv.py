@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.ops import vma
+
 
 def _pad_ptrs(slice_ptrs: jax.Array, ts: int):
     """Pad the slice-pointer array so the grid covers whole slice tiles;
@@ -63,14 +65,14 @@ def _sell_kernel(ptrs_ref, cols_ref, data_ref, x_ref, y_ref, *, c: int,
 
         def plane(t, acc, w0=w0):
             base = w0 + t * c
-            cc = pl.load(cols_ref, (pl.ds(base, c),))
-            vv = pl.load(data_ref, (pl.ds(base, c),))
+            cc = cols_ref[pl.ds(base, c)]
+            vv = data_ref[pl.ds(base, c)]
             g = jnp.take(x, cc, mode="clip").astype(jnp.float32)
             return acc + vv.astype(jnp.float32) * g
 
         acc = jax.lax.fori_loop(0, (w1 - w0) // c, plane,
                                 jnp.zeros((c,), jnp.float32))
-        pl.store(y_ref, (pl.ds(j * c, c),), acc.astype(y_ref.dtype))
+        y_ref[pl.ds(j * c, c)] = acc.astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "c", "ts", "interpret"))
@@ -94,7 +96,8 @@ def sell_spmv(slice_ptrs: jax.Array, cols: jax.Array, data: jax.Array,
             ],
             out_specs=pl.BlockSpec((ts * c,), lambda i, *_: (i,)),
         ),
-        out_shape=jax.ShapeDtypeStruct((nsp * ts * c,), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((nsp * ts * c,), x.dtype,
+                                       vma=vma(data, x)),
         interpret=interpret,
     )(ptrs, cols, data, x)
     # back to matrix row order; ghost lanes (perm == m) drop out of bounds
@@ -117,15 +120,14 @@ def _sell_spmm_kernel(ptrs_ref, cols_ref, data_ref, b_ref, y_ref, *, c: int,
 
         def plane(t, acc, w0=w0):
             base = w0 + t * c
-            cc = pl.load(cols_ref, (pl.ds(base, c),))
-            vv = pl.load(data_ref, (pl.ds(base, c),))
+            cc = cols_ref[pl.ds(base, c)]
+            vv = data_ref[pl.ds(base, c)]
             g = jnp.take(b, cc, axis=0, mode="clip").astype(jnp.float32)
             return acc + vv.astype(jnp.float32)[:, None] * g
 
         acc = jax.lax.fori_loop(0, (w1 - w0) // c, plane,
                                 jnp.zeros((c, tn), jnp.float32))
-        pl.store(y_ref, (pl.ds(j * c, c), slice(None)),
-                 acc.astype(y_ref.dtype))
+        y_ref[pl.ds(j * c, c), :] = acc.astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -155,7 +157,8 @@ def sell_spmm(slice_ptrs: jax.Array, cols: jax.Array, data: jax.Array,
             ],
             out_specs=pl.BlockSpec((ts * c, tn), lambda i, j, *_: (i, j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((nsp * ts * c, kp), B.dtype),
+        out_shape=jax.ShapeDtypeStruct((nsp * ts * c, kp), B.dtype,
+                                       vma=vma(data, B)),
         interpret=interpret,
     )(ptrs, cols, data, B)
     return jnp.zeros((m, kb), B.dtype).at[perm].set(
@@ -173,16 +176,15 @@ def _sell_spmm_t_kernel(ptrs_ref, cols_ref, data_ref, x_ref, y_ref, *,
 
         def plane(t, acc, w0=w0):
             base = w0 + t * c
-            cc = pl.load(cols_ref, (pl.ds(base, c),))
-            vv = pl.load(data_ref, (pl.ds(base, c),))
+            cc = cols_ref[pl.ds(base, c)]
+            vv = data_ref[pl.ds(base, c)]
             g = jnp.take(x, jnp.clip(cc, 0, x.shape[1] - 1),
                          axis=1).astype(jnp.float32)  # (tn, c)
             return acc + vv.astype(jnp.float32)[None, :] * g
 
         acc = jax.lax.fori_loop(0, (w1 - w0) // c, plane,
                                 jnp.zeros((tn, c), jnp.float32))
-        pl.store(y_ref, (slice(None), pl.ds(j * c, c)),
-                 acc.astype(y_ref.dtype))
+        y_ref[:, pl.ds(j * c, c)] = acc.astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -212,7 +214,8 @@ def sell_spmm_t(slice_ptrs: jax.Array, cols: jax.Array, data: jax.Array,
             ],
             out_specs=pl.BlockSpec((tn, ts * c), lambda i, j, *_: (j, i)),
         ),
-        out_shape=jax.ShapeDtypeStruct((tp, nsp * ts * c), X.dtype),
+        out_shape=jax.ShapeDtypeStruct((tp, nsp * ts * c), X.dtype,
+                                       vma=vma(data, X)),
         interpret=interpret,
     )(ptrs, cols, data, X)
     return jnp.zeros((t, m), X.dtype).at[:, perm].set(

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import compat
-
 
 def pipeline_apply(stage_fn: Callable, params_staged, x_micro, mesh: Mesh,
                    axis: str = "stage"):
@@ -64,8 +62,8 @@ def pipeline_apply(stage_fn: Callable, params_staged, x_micro, mesh: Mesh,
             return (nxt, out), None
 
         # carries become device-varying after the ppermute: mark them so
-        buf0 = compat.pcast(jnp.zeros_like(xm[0]), (axis,), to="varying")
-        out0 = compat.pcast(jnp.zeros_like(xm), (axis,), to="varying")
+        buf0 = jax.lax.pcast(jnp.zeros_like(xm[0]), (axis,), to="varying")
+        out0 = jax.lax.pcast(jnp.zeros_like(xm), (axis,), to="varying")
         (buf, out), _ = jax.lax.scan(tick, (buf0, out0),
                                      jnp.arange(ticks, dtype=jnp.int32))
         # every stage holds `out`; only the last stage's is real
@@ -73,7 +71,7 @@ def pipeline_apply(stage_fn: Callable, params_staged, x_micro, mesh: Mesh,
                             axis)
 
     spec_p = jax.tree.map(lambda a: P(axis, *(None,) * (a.ndim - 1)), params_staged)
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(spec_p, P()), out_specs=P())
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec_p, P()), out_specs=P())
     return fn(params_staged, x_micro)
 
 

@@ -18,7 +18,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.compat import leading_axis_spec
+from repro.core.distributed import leading_axis_spec
 from repro.models.spec import P as SpecP, is_spec
 
 # logical axis -> mesh axis (axis tuples allowed), per step kind

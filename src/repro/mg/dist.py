@@ -31,21 +31,20 @@ partition scatter already produced — the split never touches it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from repro.core import compat
 from repro.core import ops as _ops
-from repro.core.compat import leading_axis_spec
 from repro.core.convert import (_planned_pull, convert_execute_batch,
                                 plan_switch_batch)
 from repro.core.distributed import (DistSparseMatrix, _exchange_neighbor,
                                     _part_spec, _unstack, build_dist_matrix,
-                                    dist_spmv)
+                                    dist_spmv, leading_axis_spec)
 from repro.core.dynamic import DEFAULT_CANDIDATES, SwitchDynamicMatrix
 from repro.core.formats import COO, Format
 from repro.core.hpcg import HPCGProblem, generate_problem, partition_problem
@@ -55,18 +54,21 @@ from repro.mg.smoothers import (NCOLORS, _split_colors_device, color_grid,
                                 color_ranks, color_rows_padded)
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["blocks", "rows", "diag"], meta_fields=[])
 @dataclasses.dataclass(frozen=True)
 class DistColoredSystem:
     """Stacked per-shard color split of the local blocks.
 
     ``blocks[c]`` is a stacked ``(P, ...)`` container of shape
     ``(rmax, mp)`` (every shard's slab has identical geometry, so the
-    color structure — ``rows``, ranks, counts — is shared host metadata);
-    ``diag`` is the stacked ``(P, mp)`` local diagonal.
+    color structure — ``rows``, ranks, counts — is shared: ``rows[c]`` is
+    replicated on the mesh); ``diag`` is the stacked ``(P, mp)`` local
+    diagonal.
     """
 
     blocks: Tuple
-    rows: Tuple[np.ndarray, ...]
+    rows: Tuple[jax.Array, ...]
     diag: jax.Array
 
     @property
@@ -79,15 +81,25 @@ class DistColoredSystem:
         return out
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["A", "colored", "f2c_local"],
+                   meta_fields=["dims", "slab_dims"])
 @dataclasses.dataclass(frozen=True)
 class DistMGLevel:
     A: DistSparseMatrix
     colored: DistColoredSystem
-    f2c_local: Optional[np.ndarray]     # (mp_coarse,) — None on coarsest
+    f2c_local: Optional[jax.Array]   # (mp_coarse,) replicated; None on coarsest
     dims: Tuple[int, int, int]
     slab_dims: Tuple[int, int, int]
 
 
+# A pytree, so a solve takes the hierarchy as a jit argument: closed over,
+# its arrays would be baked into the executable as constants (gigabytes
+# at HPCG's 104^3 per chip).
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["levels"],
+                   meta_fields=["mesh", "pre", "post", "coarse_sweeps",
+                                "backend"])
 @dataclasses.dataclass(frozen=True)
 class DistMGHierarchy:
     levels: Tuple[DistMGLevel, ...]
@@ -142,6 +154,11 @@ def _shard_put(t, mesh: Mesh, axis):
                 a, NamedSharding(mesh, leading_axis_spec(axis, a.ndim))), t)
 
 
+def _replicate(t, mesh: Mesh):
+    with jax.transfer_guard("allow"):
+        return jax.device_put(t, NamedSharding(mesh, PartitionSpec()))
+
+
 def _diag_batched(local: COO) -> jax.Array:
     """(P, mp) local-block diagonal in one vmapped device pass."""
     mp = local.shape[0]
@@ -155,7 +172,7 @@ def _diag_batched(local: COO) -> jax.Array:
 
 
 def _build_dist_colored(local: COO, slab_dims, mesh: Mesh, axis,
-                        fmt: Format = Format.CSR,
+                        fmt: Format = Format.ELL,
                         policy=None,
                         candidates: Sequence[Format] = DEFAULT_CANDIDATES
                         ) -> DistColoredSystem:
@@ -196,7 +213,7 @@ def _build_dist_colored(local: COO, slab_dims, mesh: Mesh, axis,
             blk = convert_execute_batch(Cc, plan_switch_batch(Cc, Format(fmt)))
         blocks.append(_shard_put(blk, mesh, axis))
     rows_np = color_rows_padded(colors, mp, rmax)
-    rows = tuple(rows_np[c] for c in range(NCOLORS))
+    rows = _replicate(tuple(rows_np[c] for c in range(NCOLORS)), mesh)
     diag = _shard_put(_diag_batched(local), mesh, axis)
     return DistColoredSystem(tuple(blocks), rows, diag)
 
@@ -208,7 +225,7 @@ def build_dist_hierarchy(prob: HPCGProblem, mesh: Mesh, axis,
                          local_format: Format = Format.DIA,
                          remote_format: Format = Format.COO,
                          candidates: Sequence[Format] = DEFAULT_CANDIDATES,
-                         smoother_format: Format = Format.CSR,
+                         smoother_format: Format = Format.ELL,
                          smoother_policy=None,
                          pre: int = 1, post: int = 1, coarse_sweeps: int = 4,
                          backend: str = "auto",
@@ -221,6 +238,10 @@ def build_dist_hierarchy(prob: HPCGProblem, mesh: Mesh, axis,
     ``MIN_COARSE_ROWS`` rows. ``mode``/``tune``/``*_format`` flow into
     every level's ``build_dist_matrix``; ``smoother_policy`` upgrades the
     colored smoother blocks to per-(shard, color) Multi-Format selection.
+    Without one they use ``smoother_format``, ELL by default: a color block
+    of the 27-point stencil holds at most 27 entries per row, and ELL's
+    reference SpMV is one gather and a row sum, where CSR's scatter-adds
+    every entry — the cost that dominated MG-PCG on a TPU.
     """
     sizes = mesh.shape
     names = (axis,) if isinstance(axis, str) else tuple(axis)
@@ -262,7 +283,7 @@ def build_dist_hierarchy(prob: HPCGProblem, mesh: Mesh, axis,
             from repro.mg.coarsen import f2c_map, plan_coarsen
 
             cplan = plan_coarsen(nx, ny, nz // nshards)
-            f2c_local = np.asarray(f2c_map(cplan))
+            f2c_local = _replicate(np.asarray(f2c_map(cplan)), mesh)
         levels.append(DistMGLevel(A, colored, f2c_local, dims, slab_dims))
         if last:
             break
@@ -287,9 +308,8 @@ def _dist_smooth(hier: DistMGHierarchy, lev: DistMGLevel, b, x,
     A, cs = lev.A, lev.colored
     axis = A.axis
     backend = hier.backend
-    rows_np = cs.rows
 
-    def body(blocks_s, diag_s, remote_s, b_blk, x_blk):
+    def body(blocks_s, rows, diag_s, remote_s, b_blk, x_blk):
         blocks = [_unstack(blk) for blk in blocks_s]
         diag_l = diag_s[0]
         remote = _unstack(remote_s)
@@ -306,7 +326,7 @@ def _dist_smooth(hier: DistMGHierarchy, lev: DistMGLevel, b, x,
             for order in (range(NCOLORS), range(NCOLORS - 1, -1, -1)):
                 for c in order:
                     y = _ops.spmv(blocks[c], x, backend=backend)
-                    rws = jnp.asarray(rows_np[c])
+                    rws = rows[c]
                     bc = jnp.take(beff, rws, mode="clip")
                     dc = jnp.take(diag_l, rws, mode="clip")
                     x = x.at[rws].add((bc - y) / jnp.where(dc != 0, dc, 1.0))
@@ -314,35 +334,33 @@ def _dist_smooth(hier: DistMGHierarchy, lev: DistMGLevel, b, x,
 
     if x is None:
         x = jnp.zeros_like(b)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=hier.mesh,
-        in_specs=(_part_spec(cs.blocks, axis), leading_axis_spec(axis, 2),
-                  _part_spec(A.remote, axis), leading_axis_spec(axis, 1),
-                  leading_axis_spec(axis, 1)),
+        in_specs=(_part_spec(cs.blocks, axis), PartitionSpec(),
+                  leading_axis_spec(axis, 2), _part_spec(A.remote, axis),
+                  leading_axis_spec(axis, 1), leading_axis_spec(axis, 1)),
         out_specs=leading_axis_spec(axis, 1))
-    return fn(cs.blocks, cs.diag, A.remote, b, x)
+    return fn(cs.blocks, cs.rows, cs.diag, A.remote, b, x)
 
 
 def _dist_restrict(hier: DistMGHierarchy, lev: DistMGLevel, r):
     axis = lev.A.axis
-    f2c = lev.f2c_local
-    fn = compat.shard_map(
-        lambda rf: jnp.take(rf, jnp.asarray(f2c), mode="clip"),
-        mesh=hier.mesh, in_specs=(leading_axis_spec(axis, 1),),
+    fn = jax.shard_map(
+        lambda rf, f2c: jnp.take(rf, f2c, mode="clip"),
+        mesh=hier.mesh, in_specs=(leading_axis_spec(axis, 1), PartitionSpec()),
         out_specs=leading_axis_spec(axis, 1))
-    return fn(r)
+    return fn(r, lev.f2c_local)
 
 
 def _dist_prolong(hier: DistMGHierarchy, lev: DistMGLevel, xc):
     axis = lev.A.axis
-    f2c = lev.f2c_local
     mp = lev.A.mp
 
-    fn = compat.shard_map(
-        lambda xb: jnp.zeros((mp,), xb.dtype).at[jnp.asarray(f2c)].set(xb),
-        mesh=hier.mesh, in_specs=(leading_axis_spec(axis, 1),),
+    fn = jax.shard_map(
+        lambda xb, f2c: jnp.zeros((mp,), xb.dtype).at[f2c].set(xb),
+        mesh=hier.mesh, in_specs=(leading_axis_spec(axis, 1), PartitionSpec()),
         out_specs=leading_axis_spec(axis, 1))
-    return fn(xc)
+    return fn(xc, lev.f2c_local)
 
 
 def v_cycle_dist(hier: DistMGHierarchy, r: jax.Array,

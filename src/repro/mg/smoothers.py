@@ -37,6 +37,7 @@ import numpy as np
 from repro.core import ops as _ops
 from repro.core.convert import (_planned_pull, convert_execute, plan_switch,
                                 to_coo)
+from repro.core.distributed import group_ranks
 from repro.core.formats import COO, Format
 
 NCOLORS = 8
@@ -102,28 +103,22 @@ def color_ranks(colors: np.ndarray) -> np.ndarray:
 
 
 def _split_colors_device(row, col, data, colors_d, rank_d, cap: int):
-    """Pure device core of the color split: one stable argsort scatters the
-    entries of a (cap0,) COO part into ``(NCOLORS, cap)`` planes. Entry
-    (i, j, v) lands in plane ``colors[i]`` at row ``rank_of_i_within_color``;
-    dead entries and per-color overflow land in a dropped guard slot.
-    jit/vmap-able — the distributed builder vmaps it over the shard axis.
-    The same scatter shape as ``distributed.partition_execute``, with the
-    color id in place of the shard id.
+    """Pure device core of the color split: one scatter drops the entries
+    of a (cap0,) COO part into ``(NCOLORS, cap)`` planes. Entry (i, j, v)
+    lands in plane ``colors[i]`` at row ``rank_of_i_within_color``, in the
+    slot given by its stable rank among that color's entries; dead entries
+    and per-color overflow land in a dropped guard slot. jit/vmap-able —
+    the distributed builder vmaps it over the shard axis. The same scatter
+    as ``distributed.partition_execute``, with the color id in place of
+    the shard id.
     """
-    cap0 = row.shape[0]
     key = jnp.where(data != 0, colors_d[row], NCOLORS)
-    order_e = jnp.argsort(key, stable=True)
-    k_s = key[order_e]
-    r_s, c_s, v_s = row[order_e], col[order_e], data[order_e]
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32),
-         jnp.cumsum(jnp.bincount(key, length=NCOLORS + 1)).astype(jnp.int32)])
-    erank = jnp.arange(cap0, dtype=jnp.int32) - starts[k_s]
-    ok = (k_s < NCOLORS) & (erank < cap)
-    dest = jnp.where(ok, k_s * cap + jnp.minimum(erank, cap - 1), NCOLORS * cap)
-    lrow = rank_d[r_s]
+    erank = group_ranks(key, NCOLORS)
+    ok = (key < NCOLORS) & (erank < cap)
+    dest = jnp.where(ok, key * cap + jnp.minimum(erank, cap - 1), NCOLORS * cap)
+    lrow = rank_d[row]
     out = []
-    for xs in (lrow, c_s, v_s):
+    for xs in (lrow, col, data):
         buf = jnp.zeros((NCOLORS * cap + 1,), xs.dtype).at[dest].set(
             jnp.where(ok, xs, jnp.zeros((), xs.dtype)))
         out.append(buf[:NCOLORS * cap].reshape(NCOLORS, cap))

@@ -56,7 +56,6 @@ def env_info() -> dict:
         "device_kind": device_kind,
         "device_count": device_count,
         "interpret_mode": interp,
-        "force_interpret": os.environ.get("REPRO_FORCE_INTERPRET") or None,
         "trace_mode": os.environ.get("REPRO_TRACE") or "off",
         "xla_flags": os.environ.get("XLA_FLAGS") or None,
         "git_rev": git_rev(),

@@ -28,9 +28,9 @@ Design:
   exists for the bucket and its measured time beats the reference —
   never merely because the kernel compiles.
 
-``REPRO_FORCE_INTERPRET`` interacts through the backend tag: configs
-tuned under interpret mode are keyed ``cpu-interp`` (or ``tpu-interp``)
-and never replayed against natively-compiled kernels, and vice versa.
+Interpret mode (the CPU backend) is part of the backend tag: configs
+tuned against interpreted kernels are keyed ``cpu-interp`` and never
+replayed against natively-compiled kernels, and vice versa.
 
 CLI::
 
@@ -278,8 +278,10 @@ def default_grid(A, smoke: bool = False, op: str = "spmv",
             tn0 = kops._rhs_tile(ncols)
             grid = [dict(g, tn=g.get("tn", tn0)) for g in grid]
     elif isinstance(A, DIA):
-        grid = [base] + ([{"tm": 128}] if smoke else
-                         [{"tm": tm} for tm in (256, 512, 1024)])
+        from repro.kernels.dia_spmv import row_unit
+        unit = row_unit(A.dtype)  # tm granule: whole (sublanes, 128) tiles
+        grid = [base] + ([{"tm": 2 * unit}] if smoke else
+                         [{"tm": k * unit} for k in (1, 4, 16)])
     elif isinstance(A, BSR):
         grid = [base] + ([] if smoke else [{"tn": 256}])
     elif isinstance(A, HYB):

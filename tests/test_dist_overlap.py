@@ -44,6 +44,7 @@ def _run_subprocess(body: str, env=None):
         from repro.core.distributed import (activate_dist, build_dist_matrix,
                                             dist_spmv, dist_spmv_phase,
                                             distribute_vector)
+        from repro.launch.mesh import make_mesh
     """ % os.path.abspath(SRC)) + textwrap.dedent(body)
     full_env = dict(os.environ, **(env or {}))
     res = subprocess.run([sys.executable, "-c", script], capture_output=True,
@@ -277,7 +278,7 @@ def test_plan_cache_restart_skips_planning(tmp_path):
     from repro.tuning.cache import SelectionCache
     from repro.obs import metrics
 
-    mesh = jax.make_mesh((8,), ("rows",))
+    mesh = make_mesh((8,), ("rows",))
     prob = hpcg.generate_problem(4, 4, 8)
     x = distribute_vector(np.ones(prob.shape[0], np.float32), mesh, "rows")
     path = os.environ["PLAN_CACHE_PATH"]
@@ -317,7 +318,7 @@ def test_dist_split_spmv_parity_8shards():
     interior + boundary == local, and the production result is identical
     either way."""
     body = """
-    mesh = jax.make_mesh((8,), ("rows",))
+    mesh = make_mesh((8,), ("rows",))
     prob = hpcg.generate_problem(4, 4, 8)
     n = prob.shape[0]
     D = np.zeros((n, n))
@@ -362,7 +363,7 @@ def test_dist_split_multiformat_and_boundary_activate_8shards():
     body = """
     from repro.core.dynamic import SwitchDynamicMatrix
 
-    mesh = jax.make_mesh((8,), ("rows",))
+    mesh = make_mesh((8,), ("rows",))
     prob = hpcg.generate_problem(4, 4, 8)
     n = prob.shape[0]
     xh = np.ones(n, np.float32)
@@ -399,7 +400,7 @@ def test_dist_split_gather_mode_8shards():
     """Random pattern -> gather halo; the split schedule must agree with
     the dense oracle there too."""
     body = """
-    mesh = jax.make_mesh((8,), ("rows",))
+    mesh = make_mesh((8,), ("rows",))
     rng = np.random.default_rng(7)
     n, m = 128, 2000
     row = rng.integers(0, n, m)
@@ -440,6 +441,7 @@ def test_env_apply_backend_gated(monkeypatch):
     set; a caller's unrelated XLA_FLAGS survive the merge."""
     from repro import env
 
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/cc")
     monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=/tmp/d")
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")  # jax already imported in pytest
@@ -461,6 +463,27 @@ def test_env_apply_backend_gated(monkeypatch):
 def test_env_apply_warns_after_jax_import(monkeypatch):
     from repro import env
 
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/cc")
     monkeypatch.setenv("XLA_FLAGS", "")
     with pytest.warns(RuntimeWarning, match="after jax"):
         env.apply(backend="cpu", host_devices=2)
+
+
+def test_env_apply_compile_cache_dir(monkeypatch):
+    """Unset, the cache dir defaults to <checkout>/.jax_cache; a caller's
+    JAX_COMPILATION_CACHE_DIR is left exactly as it was."""
+    from repro import env
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "x")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        info = env.apply(backend="cpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert info["compile_cache_dir"] == os.path.join(root, ".jax_cache")
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == info["compile_cache_dir"]
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/mine")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        assert env.apply(backend="cpu")["compile_cache_dir"] == "/tmp/mine"
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "/tmp/mine"

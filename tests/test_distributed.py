@@ -19,10 +19,12 @@ from repro.core import Format, hpcg, random_coo, to_dense_np
 from repro.core.convert import (convert_execute_batch, planned_pulls_scope,
                                 plan_switch_batch)
 from repro.core.distributed import (DistPlan, build_dist_matrix, dist_spmv,
-                                    distribute_vector, partition_coo,
-                                    partition_execute_jit, plan_partition)
+                                    distribute_vector, group_ranks,
+                                    partition_coo, partition_execute_jit,
+                                    plan_partition)
 from repro.core.formats import COO
 from repro.core.solvers import cg, cg_fixed_iters
+from repro.launch.mesh import make_mesh
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -39,6 +41,7 @@ def _run_subprocess(body: str, env=None):
         from repro.core.distributed import (activate_dist, build_dist_matrix,
                                             dist_spmv, distribute_vector)
         from repro.core.solvers import cg, operator
+        from repro.launch.mesh import make_mesh
     """ % os.path.abspath(SRC)) + textwrap.dedent(body)
     full_env = dict(os.environ, **(env or {}))
     res = subprocess.run([sys.executable, "-c", script], capture_output=True,
@@ -139,6 +142,19 @@ def test_partition_execute_gather_mode_random():
     np.testing.assert_allclose(got, D, atol=1e-6)
 
 
+@pytest.mark.parametrize("nkeys", [1, 3, 9])
+def test_group_ranks_is_position_within_key(nkeys):
+    """Each entry's rank is its position among the earlier entries with
+    its key, in input order; a key outside [0, nkeys) ranks 0."""
+    key = np.random.default_rng(nkeys).integers(0, nkeys + 1, 500)
+    want = np.zeros_like(key)
+    for k in range(nkeys):
+        idx = np.flatnonzero(key == k)
+        want[idx] = np.arange(len(idx))
+    got = np.asarray(group_ranks(jnp.asarray(key, jnp.int32), nkeys))
+    np.testing.assert_array_equal(got, want)
+
+
 def test_batched_build_constant_planned_pulls():
     """Acceptance: the batched build pipeline performs no per-shard host
     transfers — the planned-pull count is independent of shard count, and
@@ -215,7 +231,7 @@ def test_stale_plan_raises_instead_of_dropping():
     longer fit the triplets must fail loudly, not silently drop entries in
     the guard-slot scatter."""
     prob = hpcg.generate_problem(4, 4, 8)
-    mesh = jax.make_mesh((1,), ("rows",))
+    mesh = make_mesh((1,), ("rows",))
     plan = plan_partition(prob.row, prob.col, prob.val, prob.shape, 1)
     # denser matrix than the plan was made for -> capacity overflow
     import dataclasses
@@ -251,7 +267,7 @@ def test_reused_plan_replans_on_live_pattern_change():
     """Review fix: memoised format plans are fingerprinted against the live
     pattern — a numeric update that turns zeros live must re-plan, not
     silently convert with stale DIA offsets / ELL widths."""
-    mesh = jax.make_mesh((1,), ("rows",))
+    mesh = make_mesh((1,), ("rows",))
     row = np.arange(16).repeat(2)
     col = np.concatenate([np.stack([np.arange(16),
                                     (np.arange(16) + 1) % 16]).T.ravel()])
@@ -358,7 +374,7 @@ def test_batch_features_match_host_featuriser():
 # ---------------------------------------------------------------------------
 
 def test_dist_spmv_single_shard():
-    mesh = jax.make_mesh((1,), ("rows",))
+    mesh = make_mesh((1,), ("rows",))
     prob = hpcg.generate_problem(4, 4, 4)
     A = build_dist_matrix(prob.row, prob.col, prob.val, prob.shape, mesh, "rows",
                           local_format=Format.DIA, remote_format=Format.COO)
@@ -400,7 +416,7 @@ def test_cg_fixed_iters_runs():
 ])
 def test_dist_spmv_8shards(mode, lf, rf):
     out = _run_subprocess(f"""
-        mesh = jax.make_mesh((8,), ("rows",))
+        mesh = make_mesh((8,), ("rows",))
         prob = hpcg.generate_problem(8, 8, 16)
         D = np.zeros(prob.shape); np.add.at(D, (prob.row, prob.col), prob.val)
         x_np = np.random.default_rng(0).standard_normal(prob.shape[0]).astype(np.float32)
@@ -418,7 +434,7 @@ def test_dist_spmv_8shards(mode, lf, rf):
 
 def test_dist_cg_8shards_converges_to_ones():
     out = _run_subprocess("""
-        mesh = jax.make_mesh((8,), ("rows",))
+        mesh = make_mesh((8,), ("rows",))
         prob = hpcg.generate_problem(8, 8, 16)
         A = build_dist_matrix(prob.row, prob.col, prob.val, prob.shape, mesh,
                               "rows", local_format=Format.DIA,
@@ -437,7 +453,7 @@ def test_dist_matches_single_device_result():
     """Invariant: distribution must not change the math."""
     out = _run_subprocess("""
         from repro.core import convert, spmv
-        mesh = jax.make_mesh((8,), ("rows",))
+        mesh = make_mesh((8,), ("rows",))
         prob = hpcg.generate_problem(6, 6, 8)
         x_np = np.random.default_rng(1).standard_normal(prob.shape[0]).astype(np.float32)
         A1 = convert(hpcg.to_coo(prob), Format.CSR)
@@ -458,7 +474,7 @@ def test_dist_multiformat_policy_8shards(tune, tmp_path):
     vs the dense oracle, and the whole build runs with device->host
     transfers disallowed (zero unplanned pulls, full stack)."""
     out = _run_subprocess(f"""
-        mesh = jax.make_mesh((8,), ("rows",))
+        mesh = make_mesh((8,), ("rows",))
         prob = hpcg.generate_problem(8, 8, 16)
         D = np.zeros(prob.shape); np.add.at(D, (prob.row, prob.col), prob.val)
         x_np = np.random.default_rng(2).standard_normal(prob.shape[0]).astype(np.float32)
@@ -476,7 +492,7 @@ def test_dist_multiformat_policy_8shards(tune, tmp_path):
 
 def test_dist_activate_roundtrip_8shards():
     out = _run_subprocess("""
-        mesh = jax.make_mesh((8,), ("rows",))
+        mesh = make_mesh((8,), ("rows",))
         prob = hpcg.generate_problem(8, 8, 16)
         D = np.zeros(prob.shape); np.add.at(D, (prob.row, prob.col), prob.val)
         x_np = np.random.default_rng(3).standard_normal(prob.shape[0]).astype(np.float32)
@@ -504,7 +520,7 @@ def test_dist_overlapped_spmv_random_gather_8shards():
     """Overlap refactor must hold for the all_gather (irregular) path."""
     out = _run_subprocess("""
         from repro.core import random_coo
-        mesh = jax.make_mesh((8,), ("rows",))
+        mesh = make_mesh((8,), ("rows",))
         A0 = random_coo(7, (256, 256), density=0.08)
         r, c, v = np.asarray(A0.row), np.asarray(A0.col), np.asarray(A0.data)
         D = np.zeros((256, 256)); np.add.at(D, (r, c), v)
@@ -522,7 +538,7 @@ def test_dist_overlapped_spmv_random_gather_8shards():
 
 def test_dist_block_diagonal_skips_exchange_8shards():
     out = _run_subprocess("""
-        mesh = jax.make_mesh((8,), ("rows",))
+        mesh = make_mesh((8,), ("rows",))
         row = col = np.arange(64); val = np.arange(1, 65, dtype=np.float32)
         A = build_dist_matrix(row, col, val, (64, 64), mesh, "rows")
         assert A.remote_empty and A.hw == 0, A
@@ -537,7 +553,7 @@ def test_dist_block_diagonal_skips_exchange_8shards():
 def test_dist_cg_slab_plan_auto_backend_8shards():
     """HPCG end-to-end on the slab-aware fast path with operator(auto)."""
     out = _run_subprocess("""
-        mesh = jax.make_mesh((8,), ("rows",))
+        mesh = make_mesh((8,), ("rows",))
         prob = hpcg.generate_problem(8, 8, 16)
         plan = hpcg.slab_plan(prob, 8)
         A = build_dist_matrix(prob.row, prob.col, prob.val, prob.shape, mesh,

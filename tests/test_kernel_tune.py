@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.core import Format, banded_coo, convert, random_coo, spmv
 from repro.core import ops as core_ops
+from repro.kernels import ops as kops
 from repro.tuning import (CACHE_PATH_ENV, FormatPolicy, PatternFeatures,
                           SelectionCache)
 from repro.tuning import kernel_tune as kt
@@ -48,9 +49,8 @@ def test_shape_bucket_quantizes():
 
 
 def test_backend_tag_tracks_interpret_mode(monkeypatch):
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
-    assert kt.backend_tag().endswith("-interp")
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
+    assert kt.backend_tag() == "cpu-interp"  # the CPU backend interprets
+    monkeypatch.setattr(kops, "interpret_mode", lambda: False)
     assert kt.backend_tag().endswith("-native")
 
 
@@ -140,11 +140,10 @@ def test_auto_routing_seeded_cache(tmp_path, monkeypatch):
 def test_auto_routing_interpret_tag_isolation(tmp_path, monkeypatch):
     """A config tuned under interpret mode never routes native kernels."""
     monkeypatch.setenv(CACHE_PATH_ENV, str(tmp_path / "sel.json"))
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
     A = convert(random_coo(12, (256, 256), density=0.05), Format.CSR)
     _seed(A, kernel_us=10.0, ref_us=100.0)
     assert core_ops.kernel_route(A)[0] == "pallas"
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
+    monkeypatch.setattr(kops, "interpret_mode", lambda: False)
     # same cache, native tag: the interp-keyed record must not match
     assert core_ops.kernel_route(A) == ("ref", None)
 
@@ -195,7 +194,6 @@ def test_cached_policy_pin_never_replays_across_modes(tmp_path, monkeypatch):
     native-mode process sharing the cache file: the pin is re-derived from
     the current mode's kernel records instead (here: none -> unpinned)."""
     monkeypatch.setenv(CACHE_PATH_ENV, str(tmp_path / "sel.json"))
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
     A = banded_coo((512, 512), [-1, 0, 1])
     fmt = FormatPolicy("ml").select(A).best
     feats = PatternFeatures.from_coo(A)
@@ -206,7 +204,7 @@ def test_cached_policy_pin_never_replays_across_modes(tmp_path, monkeypatch):
     cold = FormatPolicy("cached", cache=cache).select(A)
     assert cold.backend == "pallas"  # pinned under the interp tag
 
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")  # "native" process
+    monkeypatch.setattr(kops, "interpret_mode", lambda: False)  # "native"
     native = FormatPolicy("cached", cache=SelectionCache(cache.path)).select(A)
     assert native.mode == "cached"
     assert native.best == fmt        # the format pick itself is reused

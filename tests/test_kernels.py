@@ -41,7 +41,7 @@ def test_dia_kernel_sweep(shape, offsets, dtype):
                                np.asarray(y_r, np.float32), **_tol(dtype))
 
 
-@pytest.mark.parametrize("tm", [128, 256, 512])
+@pytest.mark.parametrize("tm", [1024, 2048, 4096])
 def test_dia_kernel_tile_sizes(tm):
     A = convert(banded_coo((700, 700), [-30, 0, 30]), Format.DIA)
     x = jnp.asarray(RNG.standard_normal(700).astype(np.float32))
@@ -185,7 +185,7 @@ def test_ell_kernel_k0(cfg):
     np.testing.assert_array_equal(np.asarray(y), np.zeros(70, np.float32))
 
 
-@pytest.mark.parametrize("cfg", [{"tm": 32}, {"tm": 128}, {"tm": 1024}])
+@pytest.mark.parametrize("cfg", [{"tm": 1024}, {"tm": 2048}, {"tm": 8192}])
 def test_dia_kernel_cfg_sweep_ragged(cfg):
     A = convert(banded_coo((517, 517), [-19, -3, 0, 3, 19]), Format.DIA)
     x = jnp.asarray(RNG.standard_normal(517).astype(np.float32))
@@ -285,31 +285,69 @@ def test_core_pallas_backend(fmt):
     np.testing.assert_allclose(np.asarray(y_p), np.asarray(y_r), rtol=1e-4, atol=1e-4)
 
 
+def test_pallas_backend_without_kernel_counts_ref():
+    """backend="pallas" on a format with no kernel runs the reference and
+    says so in kernel.route.ref, instead of passing it off as a kernel."""
+    from repro.core import spmv
+    from repro.obs import metrics
+    A = convert(banded_coo((256, 256), [-4, 0, 4]), Format.COO)
+    x = jnp.asarray(RNG.standard_normal(256).astype(np.float32))
+    with metrics.scope() as s:
+        y_p = spmv(A, x, backend="pallas")
+    assert s.delta("kernel.route.ref") == 1
+    np.testing.assert_allclose(np.asarray(y_p),
+                               np.asarray(spmv(A, x, backend="ref")),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("fmt", [Format.DIA, Format.CSR])
+def test_pallas_kernel_inside_shard_map(fmt):
+    """A kernel call in a shard_map body (the distributed SpMV's local
+    part) passes the varying-axes check and matches the reference."""
+    from jax.sharding import PartitionSpec as P
+    from repro.core import spmv
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("rows",))
+    A = convert(banded_coo((1024, 1024), [-33, -1, 0, 1, 33]), fmt)
+    x = jnp.asarray(RNG.standard_normal(1024).astype(np.float32))
+    stacked = jax.tree.map(lambda a: a[None], A)
+    f = jax.jit(jax.shard_map(
+        lambda a, v: spmv(jax.tree.map(lambda l: l[0], a), v,
+                          backend="pallas"),
+        mesh=mesh, in_specs=(P("rows"), P("rows")), out_specs=P("rows")))
+    np.testing.assert_allclose(np.asarray(f(stacked, x)),
+                               to_dense_np(A) @ np.asarray(x),
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_force_interpret_env_override(monkeypatch):
-    """REPRO_FORCE_INTERPRET pins the interpret flag in both directions,
-    re-read per call — no TPU-detection heuristic, no module reload."""
-    monkeypatch.delenv("REPRO_FORCE_INTERPRET", raising=False)
-    assert kops.interpret_mode() == kops.INTERPRET
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+    """Interpret mode follows the backend alone: on the CPU backend the
+    kernels run interpreted, and no environment variable (such as the
+    removed REPRO_FORCE_INTERPRET) can switch a backend's mode."""
+    assert jax.default_backend() == "cpu"
     assert kops.interpret_mode() is True
-    # the forced-interpret path must execute end to end
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
+    assert kops.interpret_mode() is True
+    # the interpreted path executes end to end
     A = convert(banded_coo((128, 128), [-1, 0, 1]), Format.CSR)
     x = jnp.ones((128,), jnp.float32)
     np.testing.assert_allclose(np.asarray(kops.csr_spmv(A, x)),
                                to_dense_np(A) @ np.ones(128), rtol=1e-4, atol=1e-4)
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
-    assert kops.interpret_mode() is False
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "")  # unset-equivalent
-    assert kops.interpret_mode() == kops.INTERPRET
 
 
 def test_vmem_budget_fallback():
-    """x too large for VMEM residency -> ref fallback, still correct."""
-    n = 2_000_000  # 8 MB f32 > budget
+    """x far past any VMEM residency budget still runs the DIA kernel
+    (x streams from HBM in per-tile windows) and matches the oracle; a
+    diagonal table whose tiles cannot fit VMEM raises, naming the size."""
+    n = 2_000_000  # 8 MB f32
     A = convert(banded_coo((1024, n), [0, 100]), Format.DIA)
-    x = jnp.ones((n,), jnp.float32)
+    x = jnp.asarray(RNG.standard_normal(n).astype(np.float32))
     y = kops.dia_spmv(A, x)
-    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(dia_spmv_ref(A.offsets, A.data, x, n)),
+                               rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="tm=1048576"):
+        kops.dia_spmv(A, x, tm=1 << 20)
 
 
 # ---------------------------------------------------------------------------

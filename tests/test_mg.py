@@ -254,6 +254,7 @@ def _run_subprocess(body: str):
         from repro.core.distributed import distribute_vector
         from repro.core.solvers import cg, pcg, operator
         from repro.mg import build_dist_hierarchy
+        from repro.launch.mesh import make_mesh
     """ % os.path.abspath(SRC)) + textwrap.dedent(body)
     res = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, timeout=600)
@@ -263,7 +264,7 @@ def _run_subprocess(body: str):
 
 def test_dist_mg_pcg_beats_cg_8shards():
     out = _run_subprocess("""
-        mesh = jax.make_mesh((8,), ("rows",))
+        mesh = make_mesh((8,), ("rows",))
         prob = hpcg.generate_problem(16, 16, 16)
         hier = build_dist_hierarchy(prob, mesh, "rows", mode="multiformat",
                                     tune="analytic")
@@ -285,3 +286,24 @@ def test_dist_mg_pcg_beats_cg_8shards():
         print("DIST_MG_OK", int(r_mg.iters), int(r_cg.iters))
     """)
     assert "DIST_MG_OK" in out
+
+
+def test_dist_hierarchy_is_a_jit_argument():
+    """The distributed hierarchy is a pytree: a solve takes it as a jit
+    argument, so its arrays are inputs of the compiled V-cycle rather
+    than constants compiled into it, and the result is unchanged."""
+    from repro.core.distributed import distribute_vector
+    from repro.launch.mesh import make_mesh
+    from repro.mg import build_dist_hierarchy
+
+    mesh = make_mesh((1,), ("rows",))
+    prob = hpcg.generate_problem(8, 8, 8)
+    hier = build_dist_hierarchy(prob, mesh, "rows", tune="analytic")
+    r = distribute_vector(hpcg.rhs_for_ones(prob), mesh, "rows")
+    leaves = jax.tree.leaves(hier)
+    assert len(leaves) > 3 * hier.nlevels
+    f = jax.jit(lambda h, v: h.apply_M()(v))
+    assert len(jax.tree.leaves(f.lower(hier, r).args_info)) == len(leaves) + 1
+    closed = jax.jit(hier.apply_M())
+    np.testing.assert_allclose(np.asarray(f(hier, r)), np.asarray(closed(r)),
+                               rtol=1e-6, atol=1e-6)

@@ -15,6 +15,7 @@ from repro.core import Format, hpcg
 from repro.core.convert import convert, planned_pulls_scope
 from repro.core.ops import spmv
 from repro.core.solvers import cg, cg_fixed_iters, pcg
+from repro.launch.mesh import make_mesh
 from repro.obs import explain, ledger, metrics, trace
 from repro.obs import report
 from repro.obs.provenance import env_info
@@ -251,7 +252,7 @@ def test_hpcg_trace_contains_phases_with_sane_parentage():
     with trace.tracing("full"):
         trace.clear()
         prob = hpcg.generate_problem(4, 4, 4)
-        mesh = jax.make_mesh((1,), ("rows",))
+        mesh = make_mesh((1,), ("rows",))
         A = build_dist_matrix(prob.row, prob.col, prob.val, prob.shape,
                               mesh, "rows", mode="multiformat",
                               tune="analytic")

@@ -13,6 +13,7 @@ SCRIPT = textwrap.dedent("""
     sys.path.insert(0, %r)
     import jax, jax.numpy as jnp, numpy as np
     from repro.launch.pipeline import pipeline_apply, stage_params
+    from repro.launch.mesh import make_mesh
 
     S, L, M, MB, D = 4, 8, 6, 2, 16
     rng = np.random.default_rng(0)
@@ -33,7 +34,7 @@ SCRIPT = textwrap.dedent("""
             h = layer(Ws[i], h)
         return h
 
-    mesh = jax.make_mesh((S,), ("stage",))
+    mesh = make_mesh((S,), ("stage",))
     staged = stage_params({"w": Ws}, S)["w"]
     y_pipe = pipeline_apply(stage_fn, staged, x, mesh)
     y_ser = jax.vmap(lambda xi: serial(Ws, xi))(x)
