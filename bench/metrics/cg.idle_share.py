@@ -1,0 +1,6 @@
+"""cg.idle_share: 1 - device busy union / traced window (%)."""
+
+
+def read(ctx):
+    share = ctx.trace.idle_share if ctx.trace is not None else None
+    return None if share is None else 100.0 * share
