@@ -14,12 +14,15 @@ Run (8 simulated devices):
   HPCG_DEVICES=8 PYTHONPATH=src python examples/hpcg_solve.py \
       --precond mg --mode multiformat    # full MG-PCG, per-level DistPlans
   PYTHONPATH=src python examples/hpcg_solve.py --local DIA --remote COO
+  REPRO_TRACE=summary PYTHONPATH=src python examples/hpcg_solve.py \
+      --profile /tmp/hpcg-profile   # device time by layer (README)
 
 ``main(argv)`` returns an :class:`HPCGRun` — the exit code plus what a
 caller in the same process checks (the ``CGResult``, the compiled solve's
 HLO text, phase timings, the operator); the CLI exits with its ``code``.
 """
 import argparse
+import contextlib
 import dataclasses
 import os
 import sys
@@ -90,6 +93,17 @@ def _optimize(args, prob, mesh, ndev):
     return A, None
 
 
+def _profile(log_dir: Optional[str]):
+    """A JAX profile into ``log_dir`` while the block runs (a no-op for
+    None). Python's call tracer stays off: it would slow the host and
+    bury the program's spans."""
+    if log_dir is None:
+        return contextlib.nullcontext()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(log_dir, profiler_options=opts)
+
+
 def _print_formats(A, hier):
     if hier is not None:
         for rec in hier.formats():
@@ -144,6 +158,13 @@ def main(argv=None) -> HPCGRun:
                    help="skip the untimed warm-up solve: the compiled solve "
                         "runs once, and its time includes the first run's "
                         "one-off costs (for smoke runs that time nothing)")
+    p.add_argument("--profile", metavar="DIR", default=None,
+                   help="write a JAX profile of the timed solve to DIR, "
+                        "with the program's spans on the device's clock "
+                        "when REPRO_TRACE is on, and the compiled solve's "
+                        "text (DIR/solve.hlo.txt), whose op_name metadata "
+                        "names each op's layer (solver.*, mg.l<k>.*, "
+                        "dist.*)")
     p.add_argument("--verbose", action="store_true",
                    help="print the per-iteration convergence curve "
                         "(||r_k|| from the solver's residual history)")
@@ -206,18 +227,26 @@ def main(argv=None) -> HPCGRun:
         solve = jax.jit(lambda a, bb: cg(
             operator(a, mesh, backend=args.backend), bb, tol=args.tol,
             maxiter=args.maxiter))
-    with trace.span("solver.compile", precond=args.precond) as sp:
+    with trace.span("solver.compile", precond=args.precond):
         t0 = time.perf_counter()
         solve = solve.lower(*operands).compile()
         compile_s = time.perf_counter() - t0
         if not args.no_warmup:
-            sp.sync(solve(*operands))
-    t0 = time.perf_counter()
-    with trace.span("solver.solve", precond=args.precond) as sp:
-        res = solve(*operands)
-        sp.sync(res)
-    res = jax.block_until_ready(res)
-    dt = time.perf_counter() - t0
+            # waited for in every trace mode: a warm-up still running
+            # would be timed with the solve
+            jax.block_until_ready(solve(*operands))
+    if args.profile:
+        # the compiled text holds each op's op_name, and with it its layer
+        os.makedirs(args.profile, exist_ok=True)
+        with open(os.path.join(args.profile, "solve.hlo.txt"), "w") as f:
+            f.write(solve.as_text())
+    with _profile(args.profile):
+        t0 = time.perf_counter()
+        with trace.span("solver.solve", precond=args.precond) as sp:
+            res = solve(*operands)
+            sp.sync(res)
+        res = jax.block_until_ready(res)
+        dt = time.perf_counter() - t0
     iters = int(res.iters)
     # HPCG's figure of merit: ~ (2 * nnz) flops per SpMV, 1 SpMV per iter
     gflops = 2 * len(prob.val) * iters / dt / 1e9

@@ -184,6 +184,19 @@ def _exchange_neighbor(x_blk, hw: int, axis: AxisNames, nshards: int):
     return jnp.concatenate([prev_tail, next_head])
 
 
+def _exchange_halo(x_blk, hw: int, axis: AxisNames, nshards: int,
+                   halo_mode: str):
+    """The halo a shard's remote part reads, under the ``dist.halo`` scope:
+    the neighbours' boundary rows (``neighbor``) or the whole vector
+    (``gather``)."""
+    with jax.named_scope("dist.halo"):
+        if halo_mode == "neighbor":
+            return _exchange_neighbor(x_blk, hw, axis, nshards)
+        if halo_mode == "gather":
+            return jax.lax.all_gather(x_blk, axis, tiled=True)
+    raise ValueError(halo_mode)
+
+
 def _shard_spmv(local, remote, x_blk, hw: int, axis: AxisNames, nshards: int,
                 halo_mode: str, backend: str, remote_empty: bool, cfg=None,
                 boundary=None):
@@ -199,23 +212,22 @@ def _shard_spmv(local, remote, x_blk, hw: int, axis: AxisNames, nshards: int,
     independent of the collective, so the scheduler has a dependency-free
     region exactly as wide as the interior work to hide the exchange in.
     The boundary and remote terms, whose result rows genuinely wait on the
-    halo, are summed last.
+    halo, are summed last. The exchange runs in the ``dist.halo`` scope and
+    the remote part's SpMV in ``dist.remote``; the local work keeps the
+    caller's scope.
     """
     if remote_empty:
         y = _ops.spmv(local, x_blk, backend=backend, cfg=cfg)
         if boundary is not None:
             y = y + _ops.spmv(boundary, x_blk, backend=backend, cfg=cfg)
         return y
-    if halo_mode == "neighbor":
-        halo = _exchange_neighbor(x_blk, hw, axis, nshards)
-    elif halo_mode == "gather":
-        halo = jax.lax.all_gather(x_blk, axis, tiled=True)
-    else:
-        raise ValueError(halo_mode)
+    halo = _exchange_halo(x_blk, hw, axis, nshards, halo_mode)
     y = _ops.spmv(local, x_blk, backend=backend, cfg=cfg)
     if boundary is not None:
         y = y + _ops.spmv(boundary, x_blk, backend=backend, cfg=cfg)
-    return y + _ops.spmv(remote, halo, backend=backend, cfg=cfg)
+    with jax.named_scope("dist.remote"):
+        y_remote = _ops.spmv(remote, halo, backend=backend, cfg=cfg)
+    return y + y_remote
 
 
 def dist_spmv(A: DistSparseMatrix, x, mesh: Mesh, backend: str = "auto",
@@ -247,10 +259,6 @@ def dist_spmv(A: DistSparseMatrix, x, mesh: Mesh, backend: str = "auto",
         halo_elems = (2 * A.hw if A.halo_mode == "neighbor"
                       else A.shape[1])
         _metrics.inc("halo.bytes", A.nshards * halo_elems * itemsize)
-        if _trace.mode() != "off":
-            _trace.event("exchange.issue", mode=A.halo_mode, p=A.nshards,
-                         bytes=A.nshards * halo_elems * itemsize,
-                         split=A.split)
 
     if A.split:
         def body(local_s, boundary_s, remote_s, x_blk):
@@ -273,13 +281,7 @@ def dist_spmv(A: DistSparseMatrix, x, mesh: Mesh, backend: str = "auto",
     fn = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
         out_specs=leading_axis_spec(axis, 1))
-    if _trace.mode() == "off":
-        return fn(*operands)
-    with _trace.span("exchange.dist_spmv", p=A.nshards,
-                     halo="empty" if A.remote_empty else A.halo_mode) as sp:
-        y = fn(*operands)
-        sp.sync(y)
-    return y
+    return fn(*operands)
 
 
 def dist_spmv_phase(A: DistSparseMatrix, x, mesh: Mesh, phase: str = "full",
@@ -326,11 +328,9 @@ def dist_spmv_phase(A: DistSparseMatrix, x, mesh: Mesh, phase: str = "full",
             return y
         if A.remote_empty:
             return jnp.zeros_like(x_blk)
-        if A.halo_mode == "neighbor":
-            halo = _exchange_neighbor(x_blk, A.hw, axis, A.nshards)
-        else:
-            halo = jax.lax.all_gather(x_blk, axis, tiled=True)
-        return _ops.spmv(remote, halo, backend=backend, cfg=cfg)
+        halo = _exchange_halo(x_blk, A.hw, axis, A.nshards, A.halo_mode)
+        with jax.named_scope("dist.remote"):
+            return _ops.spmv(remote, halo, backend=backend, cfg=cfg)
 
     if A.split:
         def body3(local_s, boundary_s, remote_s, x_blk):

@@ -8,6 +8,14 @@ solvers are generic over an ``apply_A`` closure so the same loop runs:
   * distributed local/remote split across a mesh     (paper Fig. 5)
 Vector algebra goes through repro.core.ops (dot/waxpby/axpy/norm2), the
 algorithms the paper exposes for DenseVector.
+
+Every solve names its layers in the compiled program: ``solver.spmv``
+around each ``apply_A`` (the initial residual's included),
+``solver.precond`` around ``apply_M`` and ``solver.vector`` around the
+dots and vector updates. A ``jax.named_scope`` costs nothing at run time:
+XLA keeps it as the ``op_name`` metadata of every op it lowers to, fusions
+and ops hoisted out of the loop included, so a device profile attributes
+each op to its layer (``repro.obs.trace``).
 """
 from __future__ import annotations
 
@@ -62,14 +70,26 @@ def _cg_step(apply_A: Callable, state):
     (x, r, p, rs) -> (x, r, p, rs). All reductions are global (XLA emits
     the cross-shard all-reduce when the vectors are sharded)."""
     x, r, p, rs = state
-    Ap = apply_A(p)
-    alpha = rs / jnp.maximum(_ops.dot(p, Ap), 1e-30)
-    x = _ops.axpy(alpha, p, x)
-    r = _ops.axpy(-alpha, Ap, r)
-    rs_new = _ops.dot(r, r)
-    beta = rs_new / jnp.maximum(rs, 1e-30)
-    p = _ops.waxpby(1.0, r, beta, p)
+    with jax.named_scope("solver.spmv"):
+        Ap = apply_A(p)
+    with jax.named_scope("solver.vector"):
+        alpha = rs / jnp.maximum(_ops.dot(p, Ap), 1e-30)
+        x = _ops.axpy(alpha, p, x)
+        r = _ops.axpy(-alpha, Ap, r)
+        rs_new = _ops.dot(r, r)
+        beta = rs_new / jnp.maximum(rs, 1e-30)
+        p = _ops.waxpby(1.0, r, beta, p)
     return x, r, p, rs_new
+
+
+def _residual0(apply_A: Callable, b, x0):
+    """``(x0, r0 = b - A x0)``, ``x0`` defaulting to zeros."""
+    with jax.named_scope("solver.vector"):
+        x0 = jnp.zeros_like(b) if x0 is None else x0
+    with jax.named_scope("solver.spmv"):
+        Ax0 = apply_A(x0)
+    with jax.named_scope("solver.vector"):
+        return x0, b - Ax0
 
 
 def cg(apply_A: Callable, b: jax.Array, x0: Optional[jax.Array] = None,
@@ -78,11 +98,12 @@ def cg(apply_A: Callable, b: jax.Array, x0: Optional[jax.Array] = None,
 
     Runs a fixed-shape lax.while_loop over the shared :func:`_cg_step`.
     """
-    x0 = jnp.zeros_like(b) if x0 is None else x0
-    r0 = b - apply_A(x0)
-    rs0 = _ops.dot(r0, r0)
-    tol2 = jnp.asarray(tol, b.dtype) ** 2 * jnp.maximum(rs0, 1e-30)
-    hist0 = jnp.full((maxiter + 1,), jnp.nan, b.dtype).at[0].set(jnp.sqrt(rs0))
+    x0, r0 = _residual0(apply_A, b, x0)
+    with jax.named_scope("solver.vector"):
+        rs0 = _ops.dot(r0, r0)
+        tol2 = jnp.asarray(tol, b.dtype) ** 2 * jnp.maximum(rs0, 1e-30)
+        hist0 = jnp.full((maxiter + 1,), jnp.nan,
+                         b.dtype).at[0].set(jnp.sqrt(rs0))
 
     def cond(state):
         (_, _, _, rs), k, _ = state
@@ -91,7 +112,8 @@ def cg(apply_A: Callable, b: jax.Array, x0: Optional[jax.Array] = None,
     def body(state):
         s, k, hist = state
         s = _cg_step(apply_A, s)
-        return s, k + 1, hist.at[k + 1].set(jnp.sqrt(s[3]))
+        with jax.named_scope("solver.vector"):
+            return s, k + 1, hist.at[k + 1].set(jnp.sqrt(s[3]))
 
     (x, r, p, rs), k, hist = jax.lax.while_loop(cond, body,
                                                 ((x0, r0, r0, rs0), 0, hist0))
@@ -103,17 +125,19 @@ def cg_fixed_iters(apply_A: Callable, b: jax.Array,
     """Fixed-iteration CG (benchmark timing variant: no early exit, the
     HPCG 'optimized problem timing' loop shape). Same :func:`_cg_step`
     body as :func:`cg`, under ``lax.scan``."""
-    x0 = jnp.zeros_like(b) if x0 is None else x0
-    r0 = b - apply_A(x0)
-    rs0 = _ops.dot(r0, r0)
+    x0, r0 = _residual0(apply_A, b, x0)
+    with jax.named_scope("solver.vector"):
+        rs0 = _ops.dot(r0, r0)
 
     def body(state, _):
         state = _cg_step(apply_A, state)
-        return state, jnp.sqrt(state[3])
+        with jax.named_scope("solver.vector"):
+            return state, jnp.sqrt(state[3])
 
     (x, r, _, rs), norms = jax.lax.scan(body, (x0, r0, r0, rs0), None,
                                         length=iters)
-    hist = jnp.concatenate([jnp.sqrt(rs0)[None], norms])
+    with jax.named_scope("solver.vector"):
+        hist = jnp.concatenate([jnp.sqrt(rs0)[None], norms])
     return CGResult(x, jnp.asarray(iters), jnp.sqrt(rs), hist)
 
 
@@ -143,14 +167,15 @@ def pcg(apply_A: Callable, b: jax.Array,
                              "diag_A= (Jacobi)")
         minv = jnp.where(jnp.abs(diag_A) > 1e-30, 1.0 / diag_A, 0.0)
         apply_M = lambda r: minv * r  # noqa: E731
-    x0 = jnp.zeros_like(b) if x0 is None else x0
-    r0 = b - apply_A(x0)
-    z0 = apply_M(r0)
-    p0 = z0
-    rz0 = _ops.dot(r0, z0)
-    rr0 = _ops.dot(r0, r0)
-    tol2 = jnp.asarray(tol, b.dtype) ** 2 * jnp.maximum(rr0, 1e-30)
-    hist0 = jnp.full((maxiter + 1,), jnp.nan, b.dtype).at[0].set(jnp.sqrt(rr0))
+    x0, r0 = _residual0(apply_A, b, x0)
+    with jax.named_scope("solver.precond"):
+        z0 = apply_M(r0)
+    with jax.named_scope("solver.vector"):
+        rz0 = _ops.dot(r0, z0)
+        rr0 = _ops.dot(r0, r0)
+        tol2 = jnp.asarray(tol, b.dtype) ** 2 * jnp.maximum(rr0, 1e-30)
+        hist0 = jnp.full((maxiter + 1,), jnp.nan,
+                         b.dtype).at[0].set(jnp.sqrt(rr0))
 
     # ||r||^2 is carried in the loop state: the convergence test reads it
     # instead of re-reducing r every cond evaluation, and computing it next
@@ -162,18 +187,22 @@ def pcg(apply_A: Callable, b: jax.Array,
 
     def body(state):
         x, r, p, rz, _, k, hist = state
-        Ap = apply_A(p)
-        alpha = rz / jnp.maximum(_ops.dot(p, Ap), 1e-30)
-        x = _ops.axpy(alpha, p, x)
-        r = _ops.axpy(-alpha, Ap, r)
-        z = apply_M(r)
-        rz_new = _ops.dot(r, z)
-        rr_new = _ops.dot(r, r)
-        beta = rz_new / jnp.maximum(rz, 1e-30)
-        p = _ops.waxpby(1.0, z, beta, p)
-        return (x, r, p, rz_new, rr_new, k + 1,
-                hist.at[k + 1].set(jnp.sqrt(rr_new)))
+        with jax.named_scope("solver.spmv"):
+            Ap = apply_A(p)
+        with jax.named_scope("solver.vector"):
+            alpha = rz / jnp.maximum(_ops.dot(p, Ap), 1e-30)
+            x = _ops.axpy(alpha, p, x)
+            r = _ops.axpy(-alpha, Ap, r)
+        with jax.named_scope("solver.precond"):
+            z = apply_M(r)
+        with jax.named_scope("solver.vector"):
+            rz_new = _ops.dot(r, z)
+            rr_new = _ops.dot(r, r)
+            beta = rz_new / jnp.maximum(rz, 1e-30)
+            p = _ops.waxpby(1.0, z, beta, p)
+            return (x, r, p, rz_new, rr_new, k + 1,
+                    hist.at[k + 1].set(jnp.sqrt(rr_new)))
 
     x, r, p, rz, rr, k, hist = jax.lax.while_loop(
-        cond, body, (x0, r0, p0, rz0, rr0, 0, hist0))
+        cond, body, (x0, r0, z0, rz0, rr0, 0, hist0))
     return CGResult(x, k, jnp.sqrt(rr), hist)

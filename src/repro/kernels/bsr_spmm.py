@@ -70,6 +70,7 @@ def bsr_spmm(indptr: jax.Array, brow: jax.Array, bcol: jax.Array,
     grid = (kbp // tn, nblk)  # j outer, k inner => consecutive accumulation
     y = pl.pallas_call(
         _bsr_kernel,
+        name="bsr_spmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,

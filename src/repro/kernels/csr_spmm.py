@@ -179,6 +179,7 @@ def csr_spmm(indptr: jax.Array, rows: jax.Array, indices: jax.Array,
     kernel = functools.partial(_spmm_kernel, tm=tm, tk=tk, tn=tn)
     y = pl.pallas_call(
         kernel,
+        name="csr_spmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -227,6 +228,7 @@ def csr_spmm_t(indptr: jax.Array, rows: jax.Array, indices: jax.Array,
     kernel = functools.partial(_spmm_t_kernel, tm=tm, tk=tk, tn=tn)
     y = pl.pallas_call(
         kernel,
+        name="csr_spmm_t",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

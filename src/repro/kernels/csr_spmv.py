@@ -132,6 +132,7 @@ def csr_spmv(indptr: jax.Array, rows: jax.Array, indices: jax.Array,
     kernel = functools.partial(_csr_kernel, tm=tm, tk=tk)
     y = pl.pallas_call(
         kernel,
+        name="csr_spmv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

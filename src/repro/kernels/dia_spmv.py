@@ -123,6 +123,7 @@ def dia_spmv(offsets: jax.Array, data: jax.Array, x: jax.Array, n: int,
     kernel = functools.partial(_dia_kernel, tm=tm, n=n, lpad=lpad, smax=smax)
     y = pl.pallas_call(
         kernel,
+        name="dia_spmv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(pl.cdiv(m128 // LANES, t),),

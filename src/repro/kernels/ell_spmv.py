@@ -87,6 +87,7 @@ def ell_spmv(cols: jax.Array, data: jax.Array, x: jax.Array,
     kernel = _ell_kernel_col if layout == "col" else _ell_kernel_row
     y = pl.pallas_call(
         kernel,
+        name="ell_spmv",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tm,), lambda i: (i,)),
@@ -183,6 +184,7 @@ def ell_spmm(cols: jax.Array, data: jax.Array, B: jax.Array,
               if layout == "col" else _ell_spmm_kernel_row)
     y = pl.pallas_call(
         kernel,
+        name="ell_spmm",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tm, tn), lambda i, j: (i, j)),
@@ -222,6 +224,7 @@ def ell_spmm_t(cols: jax.Array, data: jax.Array, X: jax.Array,
               if layout == "col" else _ell_spmm_t_kernel_row)
     y = pl.pallas_call(
         kernel,
+        name="ell_spmm_t",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tn, tm), lambda i, j: (j, i)),

@@ -86,6 +86,7 @@ def sell_spmv(slice_ptrs: jax.Array, cols: jax.Array, data: jax.Array,
     kernel = functools.partial(_sell_kernel, c=c, ts=ts)
     y_sorted = pl.pallas_call(
         kernel,
+        name="sell_spmv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -147,6 +148,7 @@ def sell_spmm(slice_ptrs: jax.Array, cols: jax.Array, data: jax.Array,
     kernel = functools.partial(_sell_spmm_kernel, c=c, ts=ts, tn=tn)
     y_sorted = pl.pallas_call(
         kernel,
+        name="sell_spmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -204,6 +206,7 @@ def sell_spmm_t(slice_ptrs: jax.Array, cols: jax.Array, data: jax.Array,
     kernel = functools.partial(_sell_spmm_t_kernel, c=c, ts=ts, tn=tn)
     y_sorted = pl.pallas_call(
         kernel,
+        name="sell_spmm_t",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

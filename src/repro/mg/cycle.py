@@ -107,20 +107,26 @@ def _smooth(hier: MGHierarchy, lev: MGLevel, b, x, sweeps: int):
 def v_cycle(hier: MGHierarchy, r: jax.Array, level: int = 0) -> jax.Array:
     """One V-cycle on ``A_level z = r`` from a zero initial guess.
 
-    The ``mg.vcycle`` span fires per *trace* of the level recursion (the
-    cycle is usually jitted inside pcg's while_loop), so it attributes
-    trace/compile structure, not per-iteration device time — the
-    per-iteration cost shows up in the enclosing ``solver.*`` span.
+    Each step is a named scope of its level in the compiled program:
+    ``mg.l<k>.smooth`` (the coarsest level's sweeps too),
+    ``mg.l<k>.residual``, ``mg.l<k>.restrict`` and ``mg.l<k>.prolong``.
+    Level ``k + 1`` runs outside level ``k``'s scopes, so no level's scope
+    holds another's ops.
     """
-    with _trace.span("mg.vcycle", level=level):
-        lev = hier.levels[level]
-        if level == hier.nlevels - 1:
+    lev = hier.levels[level]
+    if level == hier.nlevels - 1:
+        with jax.named_scope(f"mg.l{level}.smooth"):
             return _smooth(hier, lev, r, None, hier.coarse_sweeps)
+    with jax.named_scope(f"mg.l{level}.smooth"):
         x = _smooth(hier, lev, r, None, hier.pre)
+    with jax.named_scope(f"mg.l{level}.residual"):
         res = r - _ops.spmv(lev.A, x, backend=hier.backend)
+    with jax.named_scope(f"mg.l{level}.restrict"):
         rc = restrict(lev.coarsen, res)
-        xc = v_cycle(hier, rc, level + 1)
+    xc = v_cycle(hier, rc, level + 1)
+    with jax.named_scope(f"mg.l{level}.prolong"):
         x = x + prolong(lev.coarsen, xc)
+    with jax.named_scope(f"mg.l{level}.smooth"):
         return _smooth(hier, lev, r, x, hier.post)
 
 

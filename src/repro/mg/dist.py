@@ -42,7 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from repro.core import ops as _ops
 from repro.core.convert import (_planned_pull, convert_execute_batch,
                                 plan_switch_batch)
-from repro.core.distributed import (DistSparseMatrix, _exchange_neighbor,
+from repro.core.distributed import (DistSparseMatrix, _exchange_halo,
                                     _part_spec, _unstack, build_dist_matrix,
                                     dist_spmv, leading_axis_spec)
 from repro.core.dynamic import DEFAULT_CANDIDATES, SwitchDynamicMatrix
@@ -302,7 +302,9 @@ def _dist_smooth(hier: DistMGHierarchy, lev: DistMGLevel, b, x,
                  sweeps: int, x_is_zero: bool):
     """``sweeps`` distributed SymGS sweeps: per sweep, one halo exchange
     (skipped when ``x`` is statically zero — the halo term vanishes) then
-    the frozen-halo colored forward+backward sweep on the local block."""
+    the frozen-halo colored forward+backward sweep on the local block.
+    The exchange and the frozen-halo term run in the ``dist.halo`` and
+    ``dist.remote`` scopes, as in the distributed SpMV."""
     if sweeps <= 0:
         return x if x is not None else jnp.zeros_like(b)
     A, cs = lev.A, lev.colored
@@ -318,11 +320,10 @@ def _dist_smooth(hier: DistMGHierarchy, lev: DistMGLevel, b, x,
             if A.remote_empty or (x_is_zero and s == 0):
                 beff = b_blk
             else:
-                if A.halo_mode == "neighbor":
-                    halo = _exchange_neighbor(x, A.hw, axis, A.nshards)
-                else:
-                    halo = jax.lax.all_gather(x, axis, tiled=True)
-                beff = b_blk - _ops.spmv(remote, halo, backend=backend)
+                halo = _exchange_halo(x, A.hw, axis, A.nshards, A.halo_mode)
+                with jax.named_scope("dist.remote"):
+                    y_remote = _ops.spmv(remote, halo, backend=backend)
+                beff = b_blk - y_remote
             for order in (range(NCOLORS), range(NCOLORS - 1, -1, -1)):
                 for c in order:
                     y = _ops.spmv(blocks[c], x, backend=backend)
@@ -366,14 +367,20 @@ def _dist_prolong(hier: DistMGHierarchy, lev: DistMGLevel, xc):
 def v_cycle_dist(hier: DistMGHierarchy, r: jax.Array,
                  level: int = 0) -> jax.Array:
     """One distributed V-cycle from a zero guess (jit-able; collectives:
-    halo exchanges in the smoother + the overlapped residual SpMV)."""
-    with _trace.span("mg.vcycle_dist", level=level):
-        lev = hier.levels[level]
-        if level == hier.nlevels - 1:
+    halo exchanges in the smoother + the overlapped residual SpMV). Its
+    steps carry the named scopes of :func:`repro.mg.cycle.v_cycle`."""
+    lev = hier.levels[level]
+    if level == hier.nlevels - 1:
+        with jax.named_scope(f"mg.l{level}.smooth"):
             return _dist_smooth(hier, lev, r, None, hier.coarse_sweeps, True)
+    with jax.named_scope(f"mg.l{level}.smooth"):
         x = _dist_smooth(hier, lev, r, None, hier.pre, True)
+    with jax.named_scope(f"mg.l{level}.residual"):
         res = r - dist_spmv(lev.A, x, hier.mesh, backend=hier.backend)
+    with jax.named_scope(f"mg.l{level}.restrict"):
         rc = _dist_restrict(hier, lev, res)
-        xc = v_cycle_dist(hier, rc, level + 1)
+    xc = v_cycle_dist(hier, rc, level + 1)
+    with jax.named_scope(f"mg.l{level}.prolong"):
         x = x + _dist_prolong(hier, lev, xc)
+    with jax.named_scope(f"mg.l{level}.smooth"):
         return _dist_smooth(hier, lev, r, x, hier.post, False)

@@ -3,7 +3,12 @@
 Small, zero-heavy-dep pieces:
 
 * :mod:`repro.obs.trace`   — ``span()``/``event()`` tracer gated by
-  ``REPRO_TRACE=off|summary|full``, Chrome/Perfetto export, ``summary()``.
+  ``REPRO_TRACE=off|summary|full``, Chrome/Perfetto export, ``summary()``;
+  active spans also annotate a JAX profile. Its docstring holds the one
+  taxonomy of names: host spans (``select``, ``plan``, ``convert``,
+  ``build``, ``solver.compile``/``solver.solve``, ``kernel.route``
+  events) and the device scopes inside the jitted solves
+  (``solver.*``, ``mg.l<k>.*``, ``dist.*``).
 * :mod:`repro.obs.metrics` — named monotonic counters, gauges, and
   fixed-bucket histograms (p50/p95/p99 via :func:`metrics.quantile`) with
   ``snapshot()``/``reset()`` and order-independent ``scope()`` deltas.
@@ -15,7 +20,7 @@ Small, zero-heavy-dep pieces:
 * :mod:`repro.obs.regress` — bench-trajectory store + noise-aware
   baseline regression gate. CLI: ``python -m repro.obs.regress``.
 * :mod:`repro.obs.report`  — per-phase attribution tables
-  (select/plan/convert/kernel/exchange/solver) from a live or exported
+  (select/plan/convert/kernel/solver/build) from a live or exported
   trace, plus the distributed exchange-overlap table from
   ``BENCH_obs.json``. CLI: ``python -m repro.obs.report``.
 

@@ -1,11 +1,13 @@
 """Render traces into per-phase attribution tables.
 
-The span taxonomy (``repro.obs.trace``) prefixes every span with its
-phase: ``select.*``, ``plan.*``, ``convert.*``, ``kernel.*``,
-``exchange.*``, ``solver.*``, ``build.*``, ``mg.*``. This module folds a
-trace (live buffers or an exported ``trace.json``) into the question the
-ROADMAP actually asks: *where does the wall time go* — selection,
-planning, conversion, kernel routing, exchange, or the solve itself?
+The span taxonomy (``repro.obs.trace``) prefixes every host span with
+its phase: ``select.*``, ``plan.*``, ``convert.*``, ``kernel.*``,
+``solver.*``, ``build.*``. This module folds a trace (live buffers or an
+exported ``trace.json``) into the question the ROADMAP actually asks:
+*where does the host's wall time go* — selection, planning, conversion,
+kernel routing, building, or the solve itself? Device time by layer
+comes from the named scopes in a JAX profile instead (README,
+"Observability").
 
 Attribution uses **self time**: a span's duration minus its children's,
 so ``build.dist`` does not double-count the ``plan.*``/``convert.*``
@@ -33,8 +35,7 @@ import re
 import sys
 from typing import Dict, List, Optional
 
-PHASES = ("select", "plan", "convert", "kernel", "exchange", "solver",
-          "build", "mg")
+PHASES = ("select", "plan", "convert", "kernel", "solver", "build")
 
 
 def phase_of(name: str) -> str:

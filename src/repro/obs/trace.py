@@ -23,21 +23,35 @@ cost:
   Register the result with ``sp.sync(y)`` and the span calls
   ``jax.block_until_ready`` *once, at span close* — never on the
   untraced path, and never anywhere else in the span body.
+* An active span also holds a ``jax.profiler.TraceAnnotation`` of its
+  name and attributes open, so a JAX profile taken while the program runs
+  (``examples/hpcg_solve.py --profile DIR``) shows the program's spans on
+  the ``/host:CPU`` plane, on the same clock as the device's ops.
 
 The tracer is importable with zero heavy dependencies: ``jax`` is only
-imported lazily inside the sync handling of an *active* span.
+imported lazily, inside an *active* span. Off mode imports nothing and
+annotates nothing.
 
-Span-name taxonomy (the first dotted component is the phase the report
-attributes time to — see ``repro.obs.report``):
+Two kinds of names, one taxonomy. Host spans (the first dotted
+component is the phase ``repro.obs.report`` attributes time to):
 
     select.*    FormatPolicy decisions (``select.policy``, ``select.batch``)
     plan.*      symbolic phases (``plan.switch``, ``plan.partition``, ...)
     convert.*   numeric conversion phases
-    kernel.*    kernel routing / tile-config decisions
-    exchange.*  halo-exchange issue points (trace-time markers)
-    solver.*    solve wall time (``solver.solve``, ``solver.cg`` traces)
-    build.*     composite build phases (``build.dist``, ``build.mg_level``)
-    mg.*        V-cycle structure (``mg.vcycle`` per level)
+    build.*     composite build phases (``build.dist``,
+                ``build.mg_dist_level``, ``build.optimize``)
+    solver.*    ``solver.compile`` and ``solver.solve`` wall time
+    kernel.*    ``kernel.route`` events: kernel routing decisions
+    serve.*     serving-engine steps (``serve.refill``)
+
+Device scopes are ``jax.named_scope``s inside the jitted solves. They
+cost nothing at run time: XLA keeps each as the ``op_name`` metadata of
+the ops it lowers to, so a device profile names the layer of every op
+(the innermost scope wins):
+
+    solver.spmv, solver.vector, solver.precond   (repro.core.solvers)
+    mg.l<k>.smooth, .residual, .restrict, .prolong   (repro.mg, level k)
+    dist.halo, dist.remote   (repro.core.distributed, meshes only)
 """
 from __future__ import annotations
 
@@ -150,7 +164,8 @@ def _record(ev: dict) -> None:
 class _Span:
     """An active span. Use via :func:`span`; not constructed directly."""
 
-    __slots__ = ("name", "attrs", "id", "parent", "tid", "_t0", "_sync")
+    __slots__ = ("name", "attrs", "id", "parent", "tid", "_t0", "_sync",
+                 "_annotation")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -160,6 +175,7 @@ class _Span:
         self._sync: list = []
         self.parent = None
         self._t0 = 0
+        self._annotation = None
 
     def sync(self, *values) -> "_Span":
         """Register values to ``jax.block_until_ready`` at span close, so
@@ -170,12 +186,18 @@ class _Span:
     def set(self, **attrs) -> "_Span":
         """Attach/overwrite span attributes (e.g. the decision made)."""
         self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
         return self
 
     def __enter__(self):
         st = _stack()
         self.parent = st[-1].id if st else None
         st.append(self)
+        from jax.profiler import TraceAnnotation  # lazy: see the docstring
+
+        self._annotation = TraceAnnotation(self.name, **self.attrs)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -185,6 +207,7 @@ class _Span:
 
             jax.block_until_ready(self._sync)
         t1 = time.perf_counter_ns()
+        self._annotation.__exit__(None, None, None)
         st = _stack()
         if st and st[-1] is self:
             st.pop()

@@ -420,3 +420,58 @@ def test_core_spmm_t_backends_agree():
     np.testing.assert_allclose(np.asarray(y_ref),
                                np.asarray(spmm(A, X.T, backend="ref").T),
                                rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Kernel names: each pallas_call is named after the function it serves, so
+# a device profile's op paths say which kernel ran (``.../<name>/pallas_call``
+# in every op's ``op_name``; on a TPU also the Mosaic ``kernel_name``).
+# ---------------------------------------------------------------------------
+
+# (kernel name, format, entry point, right-hand side: x, B (n, b) or X (b, n))
+KERNEL_ENTRIES = [
+    ("dia_spmv", Format.DIA, kops.dia_spmv, "x"),
+    ("csr_spmv", Format.CSR, kops.csr_spmv, "x"),
+    ("ell_spmv", Format.ELL, kops.ell_spmv, "x"),
+    ("sell_spmv", Format.SELL, kops.sell_spmv, "x"),
+    ("csr_spmm", Format.CSR, kops.csr_spmm, "B"),
+    ("csr_spmm_t", Format.CSR, kops.csr_spmm_t, "X"),
+    ("ell_spmm", Format.ELL, kops.ell_spmm, "B"),
+    ("ell_spmm_t", Format.ELL, kops.ell_spmm_t, "X"),
+    ("sell_spmm", Format.SELL, kops.sell_spmm, "B"),
+    ("sell_spmm_t", Format.SELL, kops.sell_spmm_t, "X"),
+    ("bsr_spmm", Format.BSR, kops.bsr_spmm, "B"),
+]
+
+
+@pytest.mark.parametrize("name,fmt,entry,rhs", KERNEL_ENTRIES,
+                         ids=[e[0] for e in KERNEL_ENTRIES])
+def test_pallas_call_is_named_after_its_function(name, fmt, entry, rhs):
+    import re
+
+    n, b = 256, 8
+    kwargs = {"block_size": 64} if fmt == Format.BSR else {}
+    # a band for DIA: a random pattern would hold hundreds of diagonals
+    C = (banded_coo((n, n), [-1, 0, 1]) if fmt == Format.DIA
+         else random_coo(12, (n, n), 0.05))
+    A = convert(C, fmt, **kwargs)
+    shape = {"x": (n,), "B": (n, b), "X": (b, n)}[rhs]
+    v = jnp.ones(shape, jnp.float32)
+    # A closed over: the wrappers read its structure on the host
+    text = jax.jit(lambda v: entry(A, v)).lower(v).as_text(debug_info=True)
+    assert set(re.findall(r"(?<![\w.])([\w.]+)/pallas_call", text)) == {name}
+    # spmv_roofline counts the custom calls whose name holds "spmv"
+    assert ("spmv" in name) == name.endswith("spmv")
+
+
+def test_dia_kernel_name_reaches_mosaic(monkeypatch):
+    """Lowered for a TPU from this host (nothing compiles): the Mosaic
+    custom call carries the kernel's name."""
+    import re
+
+    monkeypatch.setattr(kops, "interpret_mode", lambda: False)
+    A = convert(banded_coo((1024, 1024), [-1, 0, 1]), Format.DIA)
+    text = jax.jit(lambda v: kops.dia_spmv(A, v)).trace(
+        jnp.ones(1024, jnp.float32)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert re.findall(r'kernel_name = "([^"]*)"', text) == ["dia_spmv"]

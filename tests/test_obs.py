@@ -82,6 +82,36 @@ def test_off_mode_emits_nothing_and_never_touches_jax(monkeypatch):
     assert trace.span("a") is trace.span("b")
 
 
+def test_summary_span_shows_on_the_profilers_host_plane(tmp_path):
+    """An active span is a JAX profiler annotation too: a profile taken on
+    the CPU holds it on the ``/host:CPU`` plane, with its attributes, on
+    the clock of the ops it covers."""
+    import glob
+
+    f = jax.jit(lambda v: (v * 2.0).sum())
+    x = jnp.ones(256)
+    f(x).block_until_ready()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with trace.tracing("summary"):
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            with trace.span("solver.solve", precond="mg") as sp:
+                sp.set(iters=5)
+                f(x).block_until_ready()
+    assert trace.aggregate()["solver.solve"]["count"] == 1
+    [pb] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    planes = {p.name: p for p in
+              jax.profiler.ProfileData.from_file(pb).planes}
+    spans = [ev for line in planes["/host:CPU"].lines
+             for ev in line.events if ev.name == "solver.solve"]
+    assert len(spans) == 1
+    assert dict(spans[0].stats) == {"precond": "mg", "iters": 5}
+    dispatch = [ev for line in planes["/host:CPU"].lines
+                for ev in line.events if ev.name.startswith("PjitFunction")]
+    assert any(spans[0].start_ns <= ev.start_ns <= spans[0].end_ns
+               for ev in dispatch)
+
+
 def test_tracing_scope_restores_mode():
     trace.set_mode("off")
     with trace.tracing("full"):
@@ -95,15 +125,15 @@ def test_tracing_scope_restores_mode():
 def test_export_chrome_roundtrip(tmp_path):
     with trace.tracing("full"):
         with trace.span("solver.solve", precond="mg") as sp:
-            with trace.span("exchange.dist_spmv"):
+            with trace.span("plan.partition"):
                 pass
     path = trace.export_chrome(str(tmp_path / "trace.json"))
     with open(path) as f:
         doc = json.load(f)
     assert all(e["ph"] == "X" for e in doc["traceEvents"])
     evs = report.load_trace(path)
-    assert {e["name"] for e in evs} == {"solver.solve", "exchange.dist_spmv"}
-    child = next(e for e in evs if e["name"] == "exchange.dist_spmv")
+    assert {e["name"] for e in evs} == {"solver.solve", "plan.partition"}
+    child = next(e for e in evs if e["name"] == "plan.partition")
     parent = next(e for e in evs if e["name"] == "solver.solve")
     assert child["parent"] == parent["id"]
     assert parent["args"]["precond"] == "mg"  # ids popped out of args

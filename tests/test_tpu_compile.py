@@ -12,6 +12,7 @@ imports this file. Code that asks ``jax.default_backend()`` still sees the
 CPU here, so the tests switch the kernels' interpret mode off themselves.
 """
 import os
+import sys
 
 import pytest
 
@@ -25,6 +26,9 @@ from repro.core.solvers import cg, operator
 from repro.kernels import dia_spmv as dia_kernel
 from repro.kernels import ops as kops
 from repro.launch.mesh import make_mesh
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from bench import scopes  # noqa: E402
 
 G = 104                  # HPCG reference local grid edge
 M = G ** 3               # rows per chip: 1,124,864
@@ -68,6 +72,17 @@ def _custom_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _unscoped_loop_ops(compiled):
+    """The fusions, custom calls and dots of the solve's loop that name no
+    program layer (``bench.scopes``): a device profile could not place
+    them. The TPU compiler's own ``ConcatBitcast`` calls, which join the
+    slices of a vector it moves between memories, carry no metadata and
+    are left out."""
+    return [line for line in scopes.loop_ops(compiled.as_text())
+            if scopes.scope_of(line) is None
+            and 'custom_call_target="ConcatBitcast"' not in line]
+
+
 def _dia(sds, lead=()):
     return DIA(sds(*lead, NDIAG, dtype=jnp.int32), sds(*lead, NDIAG, M),
                (M, M), NDIAG * M)
@@ -98,6 +113,7 @@ def test_cg_pallas_compiles_at_104cubed(topo, native):
     compiled = solve.lower(_dia(sds), sds(M)).compile()
     assert _custom_calls(compiled) >= 1
     assert _device_bytes(compiled) < HBM_BYTES
+    assert _unscoped_loop_ops(compiled) == []
 
 
 def test_dist_cg_pallas_compiles_on_4_chips(topo, native):
@@ -124,3 +140,4 @@ def test_dist_cg_pallas_compiles_on_4_chips(topo, native):
     assert _custom_calls(compiled) >= 1
     assert "collective-permute" in text
     assert _device_bytes(compiled) < HBM_BYTES
+    assert _unscoped_loop_ops(compiled) == []
