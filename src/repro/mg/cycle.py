@@ -178,7 +178,7 @@ def build_hierarchy(prob: HPCGProblem, nlevels: Optional[int] = None,
             A = _pick_format(C, policy, fmt)
             cs = (build_colored(C, dims=dims, fmt=fmt, policy=policy)
                   if smoother == "symgs" else None)
-            diag = cs.diag if cs is not None else _ops.extract_diagonal(C)
+            diag = _ops.extract_diagonal(C)
             sp.set(fmt=Format(A.format).name).sync(diag)
         levels.append(MGLevel(A, diag, cs, cz, dims))
         if last:

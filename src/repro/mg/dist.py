@@ -14,11 +14,13 @@ halo values are exchanged once per sweep and *frozen* during it (hybrid
 block-Jacobi across shards, colored symmetric Gauss-Seidel within each
 shard's local block). Folding the frozen halo term into the right-hand
 side (``b_eff = b - A_remote x_halo``) reduces the per-shard work to the
-single-device colored sweep over the local block — the same
-``(NCOLORS, cap)`` stacked split, built here with one vmapped device
-scatter over the shard axis. Grid transfers are injection and z-slabs
-align across levels (fine z = 2 * coarse z lands in the same shard), so
-restriction/prolongation are shard-local gathers/scatters — no collective.
+single-device colored sweep over the local block, in the same layout
+(color-major where the shard slab's dims are all even, see
+``repro.mg.smoothers``) — the same ``(NCOLORS, cap)`` stacked split,
+built here with one vmapped device scatter over the shard axis. Grid
+transfers are injection and z-slabs align across levels (fine z = 2 *
+coarse z lands in the same shard), so restriction/prolongation are
+shard-local gathers/scatters — no collective.
 
 A V-cycle therefore issues collectives only where the operator itself
 does: the per-sweep halo exchange and the residual's overlapped
@@ -40,8 +42,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core import ops as _ops
-from repro.core.convert import (_planned_pull, convert_execute_batch,
-                                plan_switch_batch)
+from repro.core.convert import _planned_pull, convert_execute_batch
 from repro.core.distributed import (DistSparseMatrix, _exchange_halo,
                                     _part_spec, _unstack, build_dist_matrix,
                                     dist_spmv, leading_axis_spec)
@@ -51,7 +52,10 @@ from repro.core.hpcg import HPCGProblem, generate_problem, partition_problem
 from repro.mg.cycle import MIN_COARSE_ROWS
 from repro.obs import trace as _trace
 from repro.mg.smoothers import (NCOLORS, _split_colors_device, color_grid,
-                                color_ranks, color_rows_padded)
+                                color_major, color_major_index, color_ranks,
+                                color_rows_padded, count_layout,
+                                plan_color_block, symgs_sweeps,
+                                to_color_major)
 
 
 @functools.partial(jax.tree_util.register_dataclass,
@@ -61,14 +65,19 @@ class DistColoredSystem:
     """Stacked per-shard color split of the local blocks.
 
     ``blocks[c]`` is a stacked ``(P, ...)`` container of shape
-    ``(rmax, mp)`` (every shard's slab has identical geometry, so the
-    color structure — ``rows``, ranks, counts — is shared: ``rows[c]`` is
-    replicated on the mesh); ``diag`` is the stacked ``(P, mp)`` local
-    diagonal.
+    ``(rmax, mp)``; ``diag`` is the stacked ``(P, mp)`` local diagonal.
+    Every shard's slab has identical geometry, so the color structure is
+    shared. Where the slab dims are all even (``smoothers.color_major``
+    of the level's ``slab_dims``) the layout is color-major: the blocks'
+    columns and ``diag`` are in color-major order, a DIA block is the 27
+    diagonals of ``smoothers.color_block_offsets``, and ``rows`` is None.
+    Otherwise the blocks keep natural columns and ``rows[c]``, replicated
+    on the mesh, holds color ``c``'s local row ids for the sweep's gathers
+    and scatter.
     """
 
     blocks: Tuple
-    rows: Tuple[jax.Array, ...]
+    rows: Optional[Tuple[jax.Array, ...]]
     diag: jax.Array
 
     @property
@@ -172,23 +181,29 @@ def _diag_batched(local: COO) -> jax.Array:
 
 
 def _build_dist_colored(local: COO, slab_dims, mesh: Mesh, axis,
-                        fmt: Format = Format.ELL,
+                        fmt: Optional[Format] = None,
                         policy=None,
                         candidates: Sequence[Format] = DEFAULT_CANDIDATES
                         ) -> DistColoredSystem:
     """Color-split every shard's local block in one vmapped device scatter.
 
+    Slabs with every dim even take the color-major layout, where ``fmt``
+    defaults to DIA; others the natural one, where it defaults to ELL.
     With a ``FormatPolicy``, each color's stacked shard batch goes through
     ``select_batch`` and becomes a stacked ``SwitchDynamicMatrix`` with
     per-shard active ids (the Multi-Format smoother); otherwise every
     block converts uniformly to ``fmt`` via the batched plan/execute.
     """
     mp = local.shape[0]
+    cm = color_major(slab_dims)
+    if fmt is None:
+        fmt = Format.DIA if cm else Format.ELL
     colors = color_grid(*slab_dims)
     counts = np.bincount(colors, minlength=NCOLORS)
     rmax = max(1, int(counts.max()))
     colors_d = jnp.asarray(colors)
     rank_d = jnp.asarray(color_ranks(colors))
+    colpos_d = jnp.asarray(color_major_index(slab_dims)) if cm else None
 
     # shared per-color capacity: one vmapped count + one planned pull
     def _counts(row, data):
@@ -198,8 +213,8 @@ def _build_dist_colored(local: COO, slab_dims, mesh: Mesh, axis,
     cap = max(1, int(_planned_pull(jnp.max(jax.vmap(_counts)(
         local.row, local.data)))))
 
-    split = jax.vmap(
-        lambda r, c, v: _split_colors_device(r, c, v, colors_d, rank_d, cap))
+    split = jax.vmap(lambda r, c, v: _split_colors_device(
+        r, c, v, colors_d, rank_d, cap, colpos_d))
     rr, cc, vv = split(local.row, local.col, local.data)  # (P, NCOLORS, cap)
 
     blocks = []
@@ -210,12 +225,18 @@ def _build_dist_colored(local: COO, slab_dims, mesh: Mesh, axis,
             blk = SwitchDynamicMatrix.build_batched(
                 Cc, candidates=tuple(policy.candidates), active_ids=ids)
         else:
-            blk = convert_execute_batch(Cc, plan_switch_batch(Cc, Format(fmt)))
+            blk = convert_execute_batch(Cc, plan_color_block(
+                Cc, Format(fmt), slab_dims, c, batch=True))
         blocks.append(_shard_put(blk, mesh, axis))
-    rows_np = color_rows_padded(colors, mp, rmax)
-    rows = _replicate(tuple(rows_np[c] for c in range(NCOLORS)), mesh)
-    diag = _shard_put(_diag_batched(local), mesh, axis)
-    return DistColoredSystem(tuple(blocks), rows, diag)
+    diag = _diag_batched(local)
+    if cm:
+        diag = jax.vmap(lambda d: to_color_major(d, slab_dims))(diag)
+        rows = None
+    else:
+        rows_np = color_rows_padded(colors, mp, rmax)
+        rows = _replicate(tuple(rows_np[c] for c in range(NCOLORS)), mesh)
+    return DistColoredSystem(tuple(blocks), rows,
+                             _shard_put(diag, mesh, axis))
 
 
 def build_dist_hierarchy(prob: HPCGProblem, mesh: Mesh, axis,
@@ -225,7 +246,7 @@ def build_dist_hierarchy(prob: HPCGProblem, mesh: Mesh, axis,
                          local_format: Format = Format.DIA,
                          remote_format: Format = Format.COO,
                          candidates: Sequence[Format] = DEFAULT_CANDIDATES,
-                         smoother_format: Format = Format.ELL,
+                         smoother_format: Optional[Format] = None,
                          smoother_policy=None,
                          pre: int = 1, post: int = 1, coarse_sweeps: int = 4,
                          backend: str = "auto",
@@ -238,10 +259,18 @@ def build_dist_hierarchy(prob: HPCGProblem, mesh: Mesh, axis,
     ``MIN_COARSE_ROWS`` rows. ``mode``/``tune``/``*_format`` flow into
     every level's ``build_dist_matrix``; ``smoother_policy`` upgrades the
     colored smoother blocks to per-(shard, color) Multi-Format selection.
-    Without one they use ``smoother_format``, ELL by default: a color block
-    of the 27-point stencil holds at most 27 entries per row, and ELL's
-    reference SpMV is one gather and a row sum, where CSR's scatter-adds
-    every entry — the cost that dominated MG-PCG on a TPU.
+
+    A level whose shard slab has every dim even smooths in the color-major
+    layout (``repro.mg.smoothers``): each color block is then the 27
+    diagonals of the stencil, and without a policy it is stored as DIA, a
+    ``(27, mp/8)`` table per shard with no index arrays, which the sweep
+    reads as static shifted slices of contiguous color-major vectors.
+    A level with an odd slab dim (at HPCG's 104^3, the 13^3 coarsest)
+    keeps the natural layout and its per-color gathers and scatter, with
+    ELL blocks: at most 27 entries a row, one gather and a row sum. An
+    explicit ``smoother_format`` applies to every level, in its layout.
+    The counters ``mg.smoother.color_major`` and ``mg.smoother.gather``
+    count the levels built each way.
     """
     sizes = mesh.shape
     names = (axis,) if isinstance(axis, str) else tuple(axis)
@@ -260,8 +289,10 @@ def build_dist_hierarchy(prob: HPCGProblem, mesh: Mesh, axis,
                 or (nx * ny * nz) // 8 < MIN_COARSE_ROWS)
         # one device scatter per level: the stacked (local, remote) parts
         # feed both the matrix builder (parts=) and the colored smoother
+        slab_dims = (nx, ny, nz // nshards)
         with _trace.span("build.mg_dist_level", level=len(levels),
-                         dims="x".join(map(str, dims)), p=nshards):
+                         dims="x".join(map(str, dims)), p=nshards,
+                         layout=count_layout(slab_dims)):
             local, remote, plan = partition_problem(prob_l, nshards,
                                                     dtype=dtype)
             A = build_dist_matrix(prob_l.row, prob_l.col, prob_l.val,
@@ -271,7 +302,6 @@ def build_dist_hierarchy(prob: HPCGProblem, mesh: Mesh, axis,
                                   tune=tune, candidates=candidates,
                                   plan=plan, check_plan=False, dtype=dtype,
                                   parts=(local, remote))
-            slab_dims = (nx, ny, nz // nshards)
             colored = _build_dist_colored(local, slab_dims, mesh, axis,
                                           fmt=smoother_format,
                                           policy=smoother_policy,
@@ -302,36 +332,31 @@ def _dist_smooth(hier: DistMGHierarchy, lev: DistMGLevel, b, x,
                  sweeps: int, x_is_zero: bool):
     """``sweeps`` distributed SymGS sweeps: per sweep, one halo exchange
     (skipped when ``x`` is statically zero — the halo term vanishes) then
-    the frozen-halo colored forward+backward sweep on the local block.
-    The exchange and the frozen-halo term run in the ``dist.halo`` and
-    ``dist.remote`` scopes, as in the distributed SpMV."""
+    the frozen-halo colored forward+backward sweep on the local block, in
+    the level's layout (color-major where its slab dims are all even).
+    The exchange and the frozen-halo term run in natural order in the
+    ``dist.halo`` and ``dist.remote`` scopes, as in the distributed SpMV."""
     if sweeps <= 0:
         return x if x is not None else jnp.zeros_like(b)
     A, cs = lev.A, lev.colored
     axis = A.axis
     backend = hier.backend
+    dims = lev.slab_dims if color_major(lev.slab_dims) else None
 
     def body(blocks_s, rows, diag_s, remote_s, b_blk, x_blk):
         blocks = [_unstack(blk) for blk in blocks_s]
-        diag_l = diag_s[0]
         remote = _unstack(remote_s)
-        x = x_blk
-        for s in range(int(sweeps)):
+
+        def rhs(s, x_of):
             if A.remote_empty or (x_is_zero and s == 0):
-                beff = b_blk
-            else:
-                halo = _exchange_halo(x, A.hw, axis, A.nshards, A.halo_mode)
-                with jax.named_scope("dist.remote"):
-                    y_remote = _ops.spmv(remote, halo, backend=backend)
-                beff = b_blk - y_remote
-            for order in (range(NCOLORS), range(NCOLORS - 1, -1, -1)):
-                for c in order:
-                    y = _ops.spmv(blocks[c], x, backend=backend)
-                    rws = rows[c]
-                    bc = jnp.take(beff, rws, mode="clip")
-                    dc = jnp.take(diag_l, rws, mode="clip")
-                    x = x.at[rws].add((bc - y) / jnp.where(dc != 0, dc, 1.0))
-        return x
+                return b_blk
+            halo = _exchange_halo(x_of(), A.hw, axis, A.nshards, A.halo_mode)
+            with jax.named_scope("dist.remote"):
+                y_remote = _ops.spmv(remote, halo, backend=backend)
+            return b_blk - y_remote
+
+        return symgs_sweeps(blocks, rows, diag_s[0], rhs, x_blk, sweeps,
+                            dims, backend)
 
     if x is None:
         x = jnp.zeros_like(b)

@@ -13,11 +13,26 @@ a 3D grid, same-color points are at distance >= 2 along every axis, so the
 
 Each per-color partial ``(A x)[c]`` is one SpMV of the color's **row
 block** — an ordinary (rows_c, n) sparse matrix stored in any of the
-library's formats, so the sweep runs on the existing CSR/ELL Pallas
-kernels through ``repro.core.ops.spmv`` and the measured ``backend="auto"``
-routing. The sweep is *exactly* sequential Gauss-Seidel over the
-color-permuted row ordering (the permutation is applied implicitly: blocks
-carry their global row ids and updates scatter back through them).
+library's formats. The sweep is *exactly* sequential Gauss-Seidel over the
+color-permuted row ordering.
+
+Two layouts of the blocks' columns and of the vectors:
+
+- **color-major**, wherever the grid's dims are all even. Every color then
+  holds m = n/8 points on an (nx/2, ny/2, nz/2) sub-grid, and the points
+  are numbered color first, then x-fastest within the color. A stencil
+  neighbour (dx, dy, dz) of a point of color c has color
+  c' = c xor parity(dx, dy, dz) and sits at a constant shift within c''s
+  sub-grid, so each row block is exactly 27 diagonals, at offsets
+  ``c' * m + shift`` (:func:`color_block_offsets`). A sweep moves b and x
+  into color-major order once (a reshape and transpose of the grid, no
+  gather), updates color c's contiguous slice ``[c*m, (c+1)*m)`` in place,
+  and moves x back once. A DIA block there is summed as 27 static shifted
+  slices: no index array, no gather, no scatter.
+- **natural**, the fallback for a grid with an odd dim (colors of unequal
+  size) or a coloring given as ``colors=``: blocks carry natural column
+  ids and their global row ids, and each color's update gathers b and the
+  diagonal and scatters into x through them.
 
 Build path mirrors the distributed multiformat pipeline: the 8 row blocks
 are extracted as ONE stacked ``(ncolors, cap)`` COO batch (a single device
@@ -28,7 +43,8 @@ phase — so every color block can live in its own format.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+import functools
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,9 +52,10 @@ import numpy as np
 
 from repro.core import ops as _ops
 from repro.core.convert import (_planned_pull, convert_execute, plan_switch,
-                                to_coo)
+                                plan_switch_batch, to_coo)
 from repro.core.distributed import group_ranks
-from repro.core.formats import COO, Format
+from repro.core.formats import COO, DIA, Format
+from repro.obs import metrics as _metrics
 
 NCOLORS = 8
 
@@ -73,16 +90,21 @@ def check_coloring(C: COO, colors: np.ndarray) -> None:
 class ColoredSystem:
     """Color-permuted view of a square system for parallel Gauss-Seidel.
 
-    ``blocks[c]`` is the (rmax, n) row block of color ``c`` (any format;
-    inert padding rows when colors are unevenly sized); ``rows[c]`` holds
-    the blocks' global row ids, padded with ``n`` so padded lanes clip on
-    gather and drop on scatter; ``diag`` is the full diagonal of A.
+    ``blocks[c]`` is the (rmax, n) row block of color ``c`` (any format).
+    With ``dims`` set the layout is color-major (module docstring): the
+    blocks' columns, and ``diag``, are in color-major order, every color
+    has ``rmax = n/8`` rows and ``rows`` is None. With ``dims`` None the
+    layout is natural: ``rows[c]`` holds the block's global row ids, padded
+    with ``n`` so padded lanes clip on gather and drop on scatter (inert
+    padding rows when colors are unevenly sized), and ``diag`` is the
+    diagonal of A in natural order.
     """
 
     blocks: Tuple
-    rows: Tuple[jax.Array, ...]
+    rows: Optional[Tuple[jax.Array, ...]]
     diag: jax.Array
     shape: Tuple[int, int]
+    dims: Optional[Tuple[int, int, int]] = None
 
     @property
     def ncolors(self) -> int:
@@ -102,12 +124,107 @@ def color_ranks(colors: np.ndarray) -> np.ndarray:
     return rank.astype(np.int32)
 
 
-def _split_colors_device(row, col, data, colors_d, rank_d, cap: int):
+# ---------------------------------------------------------------------------
+# The color-major layout
+# ---------------------------------------------------------------------------
+
+
+def color_major(dims) -> bool:
+    """Whether a grid (or shard slab) of ``dims`` = (nx, ny, nz) takes the
+    color-major layout: every dim even, so all 8 colors hold n/8 points."""
+    return dims is not None and all(int(d) % 2 == 0 for d in dims)
+
+
+def to_color_major(v: jax.Array, dims) -> jax.Array:
+    """Natural (x-fastest) grid vector -> color-major order: reshapes and
+    transposes of the grid, no gather. The x parity is split off while x
+    is a major dimension, between two 2-D transposes: split off as the
+    minor dimension, in one 6-D transpose, every pair of values would take
+    a whole row of vector lanes on a TPU (310 MB for a 104^3 grid)."""
+    nx, ny, nz = dims
+    hx, hy, hz = nx // 2, ny // 2, nz // 2
+    t = v.reshape(nz * ny, nx).T                       # (x, zy)
+    t = t.reshape(hx, 2, nz * ny).transpose(1, 2, 0)   # (px, zy, qx)
+    t = t.reshape(2, hz, 2, hy, 2, hx).transpose(2, 4, 0, 1, 3, 5)
+    return t.reshape(v.shape)
+
+
+def from_color_major(v: jax.Array, dims) -> jax.Array:
+    """Inverse of :func:`to_color_major`, by the same steps backwards."""
+    nx, ny, nz = dims
+    hx, hy, hz = nx // 2, ny // 2, nz // 2
+    t = v.reshape(2, 2, 2, hz, hy, hx).transpose(2, 3, 0, 4, 1, 5)
+    t = t.reshape(2, nz * ny, hx).transpose(2, 0, 1)   # (qx, px, zy)
+    return t.reshape(nx, nz * ny).T.reshape(v.shape)
+
+
+def color_major_index(dims) -> np.ndarray:
+    """(n,) color-major position of every natural grid point (host): its
+    color times n/8 plus its rank within the color."""
+    colors = color_grid(*dims)
+    return (colors * (len(colors) // NCOLORS)
+            + color_ranks(colors)).astype(np.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def color_block_offsets(dims: Tuple[int, int, int], c: int) -> Tuple[int, ...]:
+    """Ascending DIA offsets of color ``c``'s row block in color-major
+    columns: one per stencil neighbour (dx, dy, dz) that lies in the grid
+    for some point of the color, at ``c' * m + shift``. 27 where every dim
+    is at least 4; a dim of 2 leaves out the shifts that would leave it."""
+    h = tuple(int(d) // 2 for d in dims)
+    m = h[0] * h[1] * h[2]
+    stride = (1, h[0], h[0] * h[1])
+    axes = []
+    for a in range(3):
+        p = (c >> a) & 1
+        opts = []
+        for d in (-1, 0, 1):
+            shift, parity = divmod(p + d, 2)
+            if shift == 0 or h[a] > 1:
+                opts.append((parity << a, shift * stride[a]))
+        axes.append(opts)
+    return tuple(sorted({(cx + cy + cz) * m + sx + sy + sz
+                         for cx, sx in axes[0] for cy, sy in axes[1]
+                         for cz, sz in axes[2]}))
+
+
+def count_layout(dims) -> str:
+    """The layout a level built on ``dims`` takes, counted in the
+    always-on ``mg.smoother.color_major`` / ``mg.smoother.gather``
+    counters (once per level built)."""
+    layout = "color_major" if color_major(dims) else "gather"
+    _metrics.inc(f"mg.smoother.{layout}")
+    return layout
+
+
+def plan_color_block(C: COO, fmt: Format, dims, c: int,
+                     batch: bool = False):
+    """The conversion plan of color ``c``'s block ``C`` (a stacked batch of
+    shard blocks when ``batch``). A DIA block in the color-major layout
+    gets the offsets :func:`color_block_offsets` gives, which the sweep
+    takes as static; an entry off them would be dropped, so the live
+    diagonals are checked to lie among them."""
+    plan = (plan_switch_batch if batch else plan_switch)(C, fmt)
+    if Format(fmt) == Format.DIA and color_major(dims):
+        offs = color_block_offsets(tuple(dims), c)
+        if not set(plan.dia_offsets) <= set(offs):
+            raise ValueError(
+                f"color {c} block has diagonals off the 27-point stencil's "
+                f"color-major offsets: "
+                f"{sorted(set(plan.dia_offsets) - set(offs))[:4]}")
+        plan = dataclasses.replace(plan, dia_offsets=offs)
+    return plan
+
+
+def _split_colors_device(row, col, data, colors_d, rank_d, cap: int,
+                         colpos_d=None):
     """Pure device core of the color split: one scatter drops the entries
     of a (cap0,) COO part into ``(NCOLORS, cap)`` planes. Entry (i, j, v)
     lands in plane ``colors[i]`` at row ``rank_of_i_within_color``, in the
     slot given by its stable rank among that color's entries; dead entries
-    and per-color overflow land in a dropped guard slot. jit/vmap-able —
+    and per-color overflow land in a dropped guard slot. ``colpos_d``
+    renumbers the columns (the color-major layout). jit/vmap-able —
     the distributed builder vmaps it over the shard axis. The same scatter
     as ``distributed.partition_execute``, with the color id in place of
     the shard id.
@@ -117,6 +234,8 @@ def _split_colors_device(row, col, data, colors_d, rank_d, cap: int):
     ok = (key < NCOLORS) & (erank < cap)
     dest = jnp.where(ok, key * cap + jnp.minimum(erank, cap - 1), NCOLORS * cap)
     lrow = rank_d[row]
+    if colpos_d is not None:
+        col = colpos_d[col]
     out = []
     for xs in (lrow, col, data):
         buf = jnp.zeros((NCOLORS * cap + 1,), xs.dtype).at[dest].set(
@@ -126,12 +245,14 @@ def _split_colors_device(row, col, data, colors_d, rank_d, cap: int):
 
 
 def split_colors_stacked(C: COO, colors: np.ndarray,
-                         rmax: int, cap: int) -> COO:
+                         rmax: int, cap: int, colpos=None) -> COO:
     """One device scatter: (cap0,) COO -> stacked (ncolors, cap) row blocks
     (``cap`` must come from a prior count — see :func:`build_colored`)."""
     colors_d = jnp.asarray(colors)
     rank_d = jnp.asarray(color_ranks(colors))
-    r, c, v = _split_colors_device(C.row, C.col, C.data, colors_d, rank_d, cap)
+    colpos_d = None if colpos is None else jnp.asarray(colpos)
+    r, c, v = _split_colors_device(C.row, C.col, C.data, colors_d, rank_d,
+                                   cap, colpos_d)
     return COO(r, c, v, (rmax, C.shape[1]), cap)
 
 
@@ -151,17 +272,22 @@ def build_colored(A, colors: Optional[np.ndarray] = None,
     """Build the per-color row blocks of a square operator ``A``.
 
     ``colors`` (or ``dims``, from which the 2x2x2 grid coloring is
-    derived) assigns every row a color. With a ``FormatPolicy`` each color
-    block picks its own format from ONE batched ``select_batch`` pass over
-    the stacked blocks; otherwise all blocks use ``fmt``. ``check=True``
-    verifies the coloring is proper (host scan).
+    derived) assigns every row a color. Given ``dims`` with every dim
+    even, the blocks take the color-major layout; otherwise the natural
+    one. With a ``FormatPolicy`` each color block picks its own format
+    from ONE batched ``select_batch`` pass over the stacked blocks;
+    otherwise all blocks use ``fmt``. ``check=True`` verifies the coloring
+    is proper (host scan).
     """
     C = to_coo(A.concrete if hasattr(A, "concrete") else A)
     n = C.shape[0]
+    cm_dims = None
     if colors is None:
         if dims is None:
             raise ValueError("build_colored needs colors= or dims=")
         colors = color_grid(*dims)
+        if count_layout(dims) == "color_major":
+            cm_dims = tuple(int(d) for d in dims)
     colors = np.asarray(colors, np.int32)
     if len(colors) != n:
         raise ValueError(f"{len(colors)} colors for {n} rows")
@@ -176,7 +302,8 @@ def build_colored(A, colors: Optional[np.ndarray] = None,
                         length=NCOLORS + 1)[:NCOLORS]
     cap = max(1, int(_planned_pull(jnp.max(ecnt))))
 
-    stacked = split_colors_stacked(C, colors, rmax, cap)
+    colpos = None if cm_dims is None else color_major_index(cm_dims)
+    stacked = split_colors_stacked(C, colors, rmax, cap, colpos)
     if policy is not None:
         ids = policy.select_batch(stacked)
         fmts = [policy.candidates[i] for i in ids]
@@ -186,10 +313,14 @@ def build_colored(A, colors: Optional[np.ndarray] = None,
     for c in range(NCOLORS):
         blk = jax.tree.map(lambda a, c=c: a[c], stacked)
         blk = COO(blk.row, blk.col, blk.data, (rmax, n), cap)
-        blocks.append(convert_execute(blk, plan_switch(blk, fmts[c])))
+        blocks.append(convert_execute(
+            blk, plan_color_block(blk, fmts[c], cm_dims, c)))
+    diag = _ops.extract_diagonal(C)
+    if cm_dims is not None:
+        return ColoredSystem(tuple(blocks), None,
+                             to_color_major(diag, cm_dims), (n, n), cm_dims)
     rows_np = color_rows_padded(colors, n, rmax)
     rows = tuple(jnp.asarray(rows_np[c]) for c in range(NCOLORS))
-    diag = _ops.extract_diagonal(C)
     return ColoredSystem(tuple(blocks), rows, diag, (n, n))
 
 
@@ -198,20 +329,124 @@ def build_colored(A, colors: Optional[np.ndarray] = None,
 # ---------------------------------------------------------------------------
 
 
+def _dia_static_matvec(data: jax.Array, offsets: Tuple[int, ...]):
+    """``x -> A x`` for a DIA table whose offsets are known at trace time:
+    one static shifted slice of zero-padded ``x`` per diagonal. Written in
+    ``lax`` ops, and with the table's rows split once, since a V-cycle
+    unrolls it for every color, direction, sweep and level."""
+    m = data.shape[-1]
+    rows = [jax.lax.index_in_dim(data, d, keepdims=False)
+            for d in range(len(offsets))]
+
+    def matvec(x):
+        lo, hi = max(0, -offsets[0]), max(0, offsets[-1] + m - x.shape[0])
+        xp = jax.lax.pad(x.astype(data.dtype), jnp.zeros((), data.dtype),
+                         [(lo, hi, 0)])
+        y = None
+        for row, off in zip(rows, offsets):
+            t = jax.lax.mul(row, jax.lax.slice_in_dim(xp, off + lo,
+                                                      off + lo + m))
+            y = t if y is None else jax.lax.add(y, t)
+        return y
+
+    return matvec
+
+
+def _block_matvec(blk, dims, c: int, backend: str, cfg):
+    """``x -> blk x`` for color ``c``'s block. A DIA block of the
+    color-major layout on the reference path takes its offsets as static
+    from ``dims``; every other block goes through ``repro.core.ops.spmv``."""
+    if isinstance(blk, DIA) and dims is not None:
+        route, auto_cfg = ((backend, None) if backend != "auto"
+                           else _ops.kernel_route(blk))
+        if route == "ref":
+            return _dia_static_matvec(blk.data, color_block_offsets(dims, c))
+        backend, cfg = route, cfg if cfg is not None else auto_cfg
+    return lambda x: _ops.spmv(blk, x, backend=backend, cfg=cfg)
+
+
+def _color_order(forward: bool):
+    return range(NCOLORS) if forward else range(NCOLORS - 1, -1, -1)
+
+
+def _sweep_natural(blocks, rows, diag, b, x, forward: bool, backend, cfg):
+    for c in _color_order(forward):
+        y = _ops.spmv(blocks[c], x, backend=backend, cfg=cfg)
+        bc = jnp.take(b, rows[c], mode="clip")
+        dc = jnp.take(diag, rows[c], mode="clip")
+        delta = (bc - y) / jnp.where(dc != 0, dc, 1.0)
+        x = x.at[rows[c]].add(delta)  # padded lanes (id n) drop
+    return x
+
+
+def _color_slices(v: jax.Array):
+    m = v.shape[0] // NCOLORS
+    return [jax.lax.slice_in_dim(v, c * m, (c + 1) * m)
+            for c in range(NCOLORS)]
+
+
+def _sweep_color_major(matvecs, dslices, bslices, x, forward: bool):
+    """One color-order sweep on color-major ``x``: color c's rows are the
+    static slice ``[c*m, (c+1)*m)``; ``dslices``/``bslices`` are the
+    diagonal's and the right-hand side's slices."""
+    m = x.shape[0] // NCOLORS
+    for c in _color_order(forward):
+        y = matvecs[c](x)
+        xc = jax.lax.slice_in_dim(x, c * m, (c + 1) * m)
+        xc = xc + (bslices[c] - y) / dslices[c]
+        x = jax.lax.dynamic_update_slice_in_dim(x, xc, c * m, 0)
+    return x
+
+
+def _color_major_sweeps(blocks, diag, rhs, x, sweeps: int, dims, backend,
+                        cfg, directions=(True, False)):
+    """The color-major path of :func:`symgs_sweeps` (``directions`` per
+    sweep): ``x`` moves to color-major order once and back once, a
+    right-hand side once per distinct array."""
+    matvecs = [_block_matvec(blk, dims, c, backend, cfg)
+               for c, blk in enumerate(blocks)]
+    dslices = _color_slices(jnp.where(diag != 0, diag, 1.0))
+    x_nat, x = x, to_color_major(x, dims)
+    b_nat = bslices = None
+    for s in range(int(sweeps)):
+        b = rhs(s, lambda: x_nat if s == 0 else from_color_major(x, dims))
+        if b is not b_nat:
+            b_nat, bslices = b, _color_slices(to_color_major(b, dims))
+        for forward in directions:
+            x = _sweep_color_major(matvecs, dslices, bslices, x, forward)
+    return from_color_major(x, dims)
+
+
 def gs_sweep(cs: ColoredSystem, b: jax.Array, x: jax.Array,
              forward: bool = True, backend: str = "auto",
              cfg=None) -> jax.Array:
     """One Gauss-Seidel sweep in color order (exact GS over the color
-    permutation). Each color is one row-block SpMV + a masked scatter."""
-    n = cs.shape[0]
-    order = range(cs.ncolors) if forward else range(cs.ncolors - 1, -1, -1)
-    for c in order:
-        y = _ops.spmv(cs.blocks[c], x, backend=backend, cfg=cfg)
-        rows = cs.rows[c]
-        bc = jnp.take(b, rows, mode="clip")
-        dc = jnp.take(cs.diag, rows, mode="clip")
-        delta = (bc - y) / jnp.where(dc != 0, dc, 1.0)
-        x = x.at[rows].add(delta)  # padded lanes (id n) drop
+    permutation): per color one row-block SpMV and the update of the
+    color's rows. ``b`` and ``x`` are in natural order."""
+    if cs.dims is None:
+        return _sweep_natural(cs.blocks, cs.rows, cs.diag, b, x, forward,
+                              backend, cfg)
+    return _color_major_sweeps(cs.blocks, cs.diag, lambda s, x_of: b, x, 1,
+                               cs.dims, backend, cfg, directions=(forward,))
+
+
+def symgs_sweeps(blocks, rows, diag, rhs: Callable, x: jax.Array,
+                 sweeps: int, dims=None, backend: str = "auto",
+                 cfg=None) -> jax.Array:
+    """``sweeps`` symmetric (forward then backward) color sweeps over the
+    blocks of one layout: color-major when ``dims`` is given, else natural
+    with ``rows``. ``rhs(s, x_of)`` returns sweep ``s``'s right-hand side
+    in natural order; ``x_of()`` gives the current iterate in natural order
+    where the right-hand side depends on it (the distributed smoother's
+    frozen halo). ``x`` comes and goes in natural order."""
+    if dims is not None:
+        return _color_major_sweeps(blocks, diag, rhs, x, sweeps, dims,
+                                   backend, cfg)
+    for s in range(int(sweeps)):
+        b = rhs(s, lambda: x)
+        for forward in (True, False):
+            x = _sweep_natural(blocks, rows, diag, b, x, forward, backend,
+                               cfg)
     return x
 
 
@@ -222,10 +457,8 @@ def symgs(cs: ColoredSystem, b: jax.Array, x: Optional[jax.Array] = None,
     smoother that keeps ``apply_M`` a symmetric preconditioner."""
     if x is None:
         x = jnp.zeros_like(b)
-    for _ in range(int(sweeps)):
-        x = gs_sweep(cs, b, x, forward=True, backend=backend, cfg=cfg)
-        x = gs_sweep(cs, b, x, forward=False, backend=backend, cfg=cfg)
-    return x
+    return symgs_sweeps(cs.blocks, cs.rows, cs.diag, lambda s, x_of: b, x,
+                        sweeps, cs.dims, backend, cfg)
 
 
 def jacobi(diag: jax.Array, apply_A, b: jax.Array,

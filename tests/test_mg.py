@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.core import Format, convert, hpcg, spmv, to_dense_np
 from repro.core.solvers import cg, pcg
+from repro.obs import metrics
 from repro.mg import (build_colored, build_hierarchy, check_coloring,
                       coarsen_execute, color_grid, galerkin_coarse,
                       plan_coarsen, prolong, restrict, stencil27_coo,
@@ -121,12 +122,23 @@ def test_plan_coarsen_validation():
 # ---------------------------------------------------------------------------
 
 
+# Grids with every dim even take the color-major layout; an odd dim the
+# natural one, with its gathers and scatter.
+LAYOUT = {(4, 4, 4): "color_major", (5, 3, 4): "gather",
+          (8, 8, 2): "color_major"}
+
+
 @pytest.mark.parametrize("dims", [(4, 4, 4), (5, 3, 4), (8, 8, 2)])
 def test_colored_symgs_matches_sequential_gs(dims):
     prob = hpcg.generate_problem(*dims)
     C = hpcg.to_coo(prob)
     colors = color_grid(*dims)
-    cs = build_colored(C, dims=dims, fmt=Format.CSR, check=True)
+    with metrics.scope() as m:
+        cs = build_colored(C, dims=dims, fmt=Format.CSR, check=True)
+    assert m.delta(f"mg.smoother.{LAYOUT[dims]}") == 1
+    assert (m.delta("mg.smoother.color_major")
+            + m.delta("mg.smoother.gather")) == 1
+    assert (cs.dims is not None) == (LAYOUT[dims] == "color_major")
     rng = np.random.default_rng(0)
     n = prob.shape[0]
     b = rng.standard_normal(n).astype(np.float32)
@@ -143,12 +155,45 @@ def test_colored_blocks_any_format_agree():
     C = hpcg.to_coo(prob)
     b = jnp.asarray(hpcg.rhs_for_ones(prob))
     base = symgs(build_colored(C, dims=dims, fmt=Format.CSR), b, backend="ref")
-    for fmt in (Format.ELL, Format.DIA, Format.COO):
+    for fmt in (Format.ELL, Format.DIA, Format.COO, Format.SELL, Format.HYB):
         cs = build_colored(C, dims=dims, fmt=fmt)
         assert set(cs.formats) == {fmt}
+        assert cs.dims == dims  # the color-major layout, in every format
         got = symgs(cs, b, backend="ref")
         np.testing.assert_allclose(np.asarray(got), np.asarray(base),
                                    rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dims", [(4, 4, 4), (8, 8, 2), (6, 4, 8), (2, 2, 2)])
+def test_color_major_moves_and_block_offsets(dims):
+    """The moves are the color-major permutation (color, then x-fastest
+    rank within the color), and each color block's diagonals lie on the
+    offsets the sweep takes as static: all 27 where every dim is at
+    least 4."""
+    from repro.core.convert import plan_switch
+    from repro.mg.smoothers import (color_block_offsets, color_major_index,
+                                    from_color_major, to_color_major)
+
+    n = int(np.prod(dims))
+    v = np.arange(n, dtype=np.float32)
+    cm = np.asarray(to_color_major(jnp.asarray(v), dims))
+    pos = color_major_index(dims)
+    np.testing.assert_array_equal(cm[pos], v)
+    np.testing.assert_array_equal(
+        np.asarray(from_color_major(jnp.asarray(cm), dims)), v)
+    colors = color_grid(*dims)
+    np.testing.assert_array_equal(np.sort(pos[colors == 3]),
+                                  3 * (n // 8) + np.arange(n // 8))
+
+    cs = build_colored(hpcg.to_coo(hpcg.generate_problem(*dims)), dims=dims,
+                       fmt=Format.COO)
+    for c, blk in enumerate(cs.blocks):
+        live = plan_switch(blk, Format.DIA).dia_offsets
+        offs = color_block_offsets(dims, c)
+        assert live == offs
+        # three shifts per axis, two along a dim of 2 (the sub-grid is 1
+        # wide there, so a shift off it leaves the grid)
+        assert len(offs) == np.prod([3 if d >= 4 else 2 for d in dims])
 
 
 def test_check_coloring_rejects_improper():
@@ -307,3 +352,91 @@ def test_dist_hierarchy_is_a_jit_argument():
     closed = jax.jit(hier.apply_M())
     np.testing.assert_allclose(np.asarray(f(hier, r)), np.asarray(closed(r)),
                                rtol=1e-6, atol=1e-6)
+
+
+# (dims, smoother_format) -> layout of each of the two levels built
+DIST_SMOOTHERS = {((8, 8, 8), None): ("color_major", "color_major"),
+                  ((8, 8, 8), Format.ELL): ("color_major", "color_major"),
+                  ((6, 6, 6), None): ("color_major", "gather")}
+
+
+@pytest.mark.parametrize("dims, smoother_format", list(DIST_SMOOTHERS),
+                         ids=["8-default", "8-ell", "6-default"])
+def test_dist_smoother_matches_sequential_gs_one_shard(dims, smoother_format):
+    """Each level's distributed smoother on one shard is the sequential
+    color-ordered Gauss-Seidel, in the color-major layout (default DIA
+    blocks, or the format asked for) and in the natural fallback."""
+    from repro.core.distributed import distribute_vector
+    from repro.launch.mesh import make_mesh
+    from repro.mg import build_dist_hierarchy
+    from repro.mg.dist import _dist_smooth
+
+    layouts = DIST_SMOOTHERS[(dims, smoother_format)]
+    mesh = make_mesh((1,), ("rows",))
+    with metrics.scope() as m:
+        hier = build_dist_hierarchy(hpcg.generate_problem(*dims), mesh,
+                                    "rows", nlevels=2,
+                                    smoother_format=smoother_format)
+    for layout in ("color_major", "gather"):
+        assert m.delta(f"mg.smoother.{layout}") == layouts.count(layout)
+    smooth = jax.jit(lambda h, k, bb, xx: _dist_smooth(
+        h, h.levels[k], bb, xx, 2, False), static_argnums=1)
+    rng = np.random.default_rng(1)
+    for k, (lev, layout) in enumerate(zip(hier.levels, layouts)):
+        fmt = (smoother_format or
+               (Format.DIA if layout == "color_major" else Format.ELL))
+        assert lev.colored.formats == [fmt.name] * 8
+        assert (lev.colored.rows is None) == (layout == "color_major")
+        prob = hpcg.generate_problem(*lev.dims)
+        n = prob.shape[0]
+        b = rng.standard_normal(n).astype(np.float32)
+        x0 = rng.standard_normal(n).astype(np.float32)
+        got = smooth(hier, k, distribute_vector(b, mesh, "rows"),
+                     distribute_vector(x0, mesh, "rows"))
+        want = symgs_reference_np(prob.row, prob.col, prob.val,
+                                  color_grid(*lev.dims), b, x0, sweeps=2)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5,
+                                   atol=2e-5)
+
+
+def test_dist_mg_smoothing_is_gather_free():
+    """The mechanism: in the compiled MG-PCG solve, the even levels'
+    smoothing holds no gather and no scatter (the natural path's per-color
+    takes and scatter-add are gone, and the DIA blocks are read as static
+    slices), and level 0's color blocks are DIA tables of 27 diagonals."""
+    import re
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from bench import scopes
+    from repro.core.distributed import distribute_vector
+    from repro.core.formats import DIA
+    from repro.core.solvers import operator
+    from repro.launch.mesh import make_mesh
+    from repro.mg import build_dist_hierarchy
+    from repro.mg.smoothers import color_block_offsets
+
+    grid = (16, 16, 16)
+    mesh = make_mesh((1,), ("rows",))
+    prob = hpcg.generate_problem(*grid)
+    hier = build_dist_hierarchy(prob, mesh, "rows", nlevels=4)
+    for c, blk in enumerate(hier.levels[0].colored.blocks):
+        assert isinstance(blk, DIA)
+        offs = np.asarray(blk.offsets)
+        assert offs.shape == (1, 27)
+        assert tuple(offs[0]) == color_block_offsets(grid, c)
+    b = distribute_vector(hpcg.rhs_for_ones(prob), mesh, "rows")
+    fn = jax.jit(lambda a, bb, h: pcg(operator(a, mesh), bb, tol=0.0,
+                                      maxiter=2, apply_M=h.apply_M()))
+    text = fn.lower(hier.levels[0].A, b, hier).compile().as_text()
+    table = scopes.instruction_scopes(text)
+    found = {}
+    for line in text.split("\n"):
+        if not line.startswith("  ") or " = " not in line:
+            continue
+        op = re.search(r"\s(gather|scatter)\(", line.split(" = ", 1)[1])
+        if op:
+            scope = table[scopes.instruction(line)]
+            found.setdefault(scope, []).append(op.group(1))
+    assert {"mg.l0.smooth", "mg.l1.smooth"} <= set(table.values())
+    assert "mg.l0.smooth" not in found and "mg.l1.smooth" not in found
+    assert "gather" in found["mg.l0.restrict"]  # the search finds them
