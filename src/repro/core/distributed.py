@@ -53,8 +53,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from repro.core.convert import (SwitchPlan, convert_execute_batch,
-                                plan_switch_batch)
+from repro.core.convert import (SwitchPlan, _planned_pull,
+                                convert_execute_batch, plan_switch_batch)
 from repro.core import ops as _ops
 from repro.core.dynamic import SwitchDynamicMatrix
 from repro.core.formats import COO, Format
@@ -212,9 +212,10 @@ def _shard_spmv(local, remote, x_blk, hw: int, axis: AxisNames, nshards: int,
     independent of the collective, so the scheduler has a dependency-free
     region exactly as wide as the interior work to hide the exchange in.
     The boundary and remote terms, whose result rows genuinely wait on the
-    halo, are summed last. The exchange runs in the ``dist.halo`` scope and
-    the remote part's SpMV in ``dist.remote``; the local work keeps the
-    caller's scope.
+    halo, are summed last. Each part runs in a scope of its own:
+    ``dist.halo`` (the exchange), ``dist.interior`` (``dist.local`` when
+    unsplit), ``dist.boundary`` and ``dist.remote``. A statically-empty
+    remote part leaves the local work in the caller's scope.
     """
     if remote_empty:
         y = _ops.spmv(local, x_blk, backend=backend, cfg=cfg)
@@ -222,12 +223,24 @@ def _shard_spmv(local, remote, x_blk, hw: int, axis: AxisNames, nshards: int,
             y = y + _ops.spmv(boundary, x_blk, backend=backend, cfg=cfg)
         return y
     halo = _exchange_halo(x_blk, hw, axis, nshards, halo_mode)
-    y = _ops.spmv(local, x_blk, backend=backend, cfg=cfg)
-    if boundary is not None:
-        y = y + _ops.spmv(boundary, x_blk, backend=backend, cfg=cfg)
-    with jax.named_scope("dist.remote"):
-        y_remote = _ops.spmv(remote, halo, backend=backend, cfg=cfg)
-    return y + y_remote
+    y = _local_spmv(local, boundary, x_blk, backend, cfg)
+    return y + _scoped_spmv("dist.remote", remote, halo, backend, cfg)
+
+
+def _local_spmv(local, boundary, x_blk, backend: str, cfg):
+    """A shard's local block times its own slab of x: the interior part
+    under ``dist.interior`` and the boundary part under ``dist.boundary``,
+    or an unsplit local block under ``dist.local``."""
+    if boundary is None:
+        return _scoped_spmv("dist.local", local, x_blk, backend, cfg)
+    y = _scoped_spmv("dist.interior", local, x_blk, backend, cfg)
+    return y + _scoped_spmv("dist.boundary", boundary, x_blk, backend, cfg)
+
+
+def _scoped_spmv(scope: str, A, x, backend: str, cfg):
+    """One part's SpMV under its ``dist.*`` scope."""
+    with jax.named_scope(scope):
+        return _ops.spmv(A, x, backend=backend, cfg=cfg)
 
 
 def dist_spmv(A: DistSparseMatrix, x, mesh: Mesh, backend: str = "auto",
@@ -318,19 +331,16 @@ def dist_spmv_phase(A: DistSparseMatrix, x, mesh: Mesh, phase: str = "full",
         local, remote = _unstack(local_s), _unstack(remote_s)
         boundary = _unstack(boundary_s) if boundary_s is not None else None
         if phase == "interior":
-            return _ops.spmv(local, x_blk, backend=backend, cfg=cfg)
+            return _scoped_spmv("dist.interior", local, x_blk, backend, cfg)
         if phase == "boundary":
-            return _ops.spmv(boundary, x_blk, backend=backend, cfg=cfg)
+            return _scoped_spmv("dist.boundary", boundary, x_blk, backend,
+                                cfg)
         if phase == "local":
-            y = _ops.spmv(local, x_blk, backend=backend, cfg=cfg)
-            if boundary is not None:
-                y = y + _ops.spmv(boundary, x_blk, backend=backend, cfg=cfg)
-            return y
+            return _local_spmv(local, boundary, x_blk, backend, cfg)
         if A.remote_empty:
             return jnp.zeros_like(x_blk)
         halo = _exchange_halo(x_blk, A.hw, axis, A.nshards, A.halo_mode)
-        with jax.named_scope("dist.remote"):
-            return _ops.spmv(remote, halo, backend=backend, cfg=cfg)
+        return _scoped_spmv("dist.remote", remote, halo, backend, cfg)
 
     if A.split:
         def body3(local_s, boundary_s, remote_s, x_blk):
@@ -973,20 +983,47 @@ def build_dist_matrix(row, col, val, shape, mesh: Mesh, axis: AxisNames,
     else:
         raise ValueError(mode)
 
+    if split:
+        parts = {"interior": (local, lcoos), "boundary": (boundary, bcoos)}
+    else:
+        parts = {"local": (local, lcoos)}
+    parts["remote"] = (remote, rcoos)
+    counts = _count_parts(parts)
     A = DistSparseMatrix(local, remote, boundary=boundary, nshards=nshards,
                          mp=plan.mp, shape=shape, halo_mode=plan.halo_mode,
                          axis=axis, hw=plan.hw, remote_empty=plan.remote_empty)
     A = _shard_containers(A, mesh)
-    # Build artifact (not pytree state): pass back via build(plan=...) and a
+    # Build artifacts (not pytree state): pass back via build(plan=...) and a
     # rebuild performs zero symbolic pulls — partition caps, split caps and
     # per-format SwitchPlans are all memoised.
     A.plan = plan
+    A.counts = counts
     if cache_key is not None and plan_cache is not None:
         if plan.pattern_sig is None:
             plan = dataclasses.replace(plan, pattern_sig=sig)
             A.plan = plan
         plan_cache.put_raw(cache_key, plan.to_json())
     return A
+
+
+def _count_parts(parts: dict) -> dict:
+    """``dist.stored.<part>``, the values a part's container stores over
+    all shards, padding included, and ``dist.entries.<part>``, the live
+    entries of its COO source: their gap is what the part's format pays in
+    padding. ``parts`` maps each part's name to ``(container, coo)``. The
+    live counts of all parts come in one planned pull; each count is also
+    added to the always-on counter of its name."""
+    live = _planned_pull(jnp.stack([jnp.count_nonzero(coo.data)
+                                    for _, coo in parts.values()]))
+    counts = {}
+    for (name, (container, _)), n in zip(parts.items(), live):
+        counts[f"dist.stored.{name}"] = sum(
+            int(a.size) for a in jax.tree.leaves(container)
+            if jnp.issubdtype(a.dtype, jnp.floating))
+        counts[f"dist.entries.{name}"] = int(n)
+    for name, n in counts.items():
+        _metrics.inc(name, n)
+    return counts
 
 
 def _shard_containers(A: DistSparseMatrix, mesh: Mesh) -> DistSparseMatrix:
@@ -1062,7 +1099,7 @@ def _traced_build_dist(fn):
         with _trace.span("build.dist",
                          mode=kwargs.get("mode", "uniform")) as sp:
             A = fn(*args, **kwargs)
-            sp.set(p=A.nshards, halo=A.halo_mode, hw=A.hw)
+            sp.set(p=A.nshards, halo=A.halo_mode, hw=A.hw, **A.counts)
         return A
     return wrapper
 

@@ -51,7 +51,8 @@ the ops it lowers to, so a device profile names the layer of every op
 
     solver.spmv, solver.vector, solver.precond   (repro.core.solvers)
     mg.l<k>.smooth, .residual, .restrict, .prolong   (repro.mg, level k)
-    dist.halo, dist.remote   (repro.core.distributed, meshes only)
+    dist.halo, dist.interior, dist.boundary, dist.local, dist.remote
+                             (repro.core.distributed, meshes only)
 """
 from __future__ import annotations
 

@@ -419,6 +419,53 @@ def test_dist_split_gather_mode_8shards():
     assert "OK" in _run_subprocess(body)
 
 
+def test_dist_parts_scoped_and_counted_4shards():
+    """Each part of a split distributed SpMV runs under a scope of its own
+    in the compiled text (``bench.scopes`` reads them), and the build
+    counts what each part stores against its live entries: a DIA
+    boundary part stores 27 full diagonals over every row of each slab."""
+    body = """
+    sys.path.insert(0, %r)
+    from collections import Counter
+    from bench import scopes
+    from repro.obs import metrics
+
+    mesh = make_mesh((4,), ("rows",), devices=jax.devices()[:4])
+    prob = hpcg.generate_problem(8, 8, 32)
+    A = build_dist_matrix(prob.row, prob.col, prob.val, prob.shape, mesh,
+                          "rows", local_format=Format.DIA,
+                          remote_format=Format.COO,
+                          plan=hpcg.slab_plan(prob, 4), check_plan=False)
+    assert A.split and A.mp == 8 * 8 * 8, A
+    parts = ("interior", "boundary", "remote")
+    assert set(A.counts) == {f"dist.{k}.{p}" for k in ("stored", "entries")
+                             for p in parts}
+    assert A.counts["dist.stored.boundary"] == 27 * A.mp * 4
+    assert A.counts["dist.stored.interior"] == 27 * A.mp * 4
+    assert sum(A.counts[f"dist.entries.{p}"] for p in parts) == len(prob.val)
+    # the two end slabs exchange one plane, the others two: 9 remote
+    # entries per row of a plane, fewer at its edges
+    assert A.counts["dist.entries.remote"] == 6 * (3 * 8 - 2) ** 2
+    assert all(metrics.value(k) == v for k, v in A.counts.items())
+
+    x = distribute_vector(np.ones(prob.shape[0], np.float32), mesh, "rows")
+
+    def placed(fn):
+        text = jax.jit(fn).lower(A, x).compile().as_text()
+        return Counter(scopes.instruction_scopes(text).values())
+
+    full = placed(lambda a, v: dist_spmv(a, v, mesh, backend="ref"))
+    assert all(full[s] > 0 for s in ("dist.interior", "dist.boundary",
+                                     "dist.halo", "dist.remote")), full
+    local = placed(lambda a, v: dist_spmv_phase(a, v, mesh, phase="local",
+                                                backend="ref"))
+    assert local["dist.halo"] == local["dist.remote"] == 0, local
+    assert local["dist.interior"] > 0 and local["dist.boundary"] > 0, local
+    print("OK")
+    """ % os.path.dirname(os.path.abspath(SRC))
+    assert "OK" in _run_subprocess(body)
+
+
 # ---------------------------------------------------------------------------
 # repro.env (no jax involvement by construction)
 # ---------------------------------------------------------------------------
