@@ -9,22 +9,29 @@ block with ``T = tm / 128``.
 Blocking strategy:
   * grid over row tiles; the diagonal table ``data[ndiag, M]`` streams
     through VMEM one ``(ndiag, T, 128)`` block per grid step;
-  * ``x`` stays in HBM. Per (tile, diagonal) the kernel DMAs the
-    8-row-aligned ``(T + 16, 128)`` window that covers
-    ``x[row0 + off : row0 + off + tm]`` into a double-buffered VMEM slot —
-    the next diagonal's window is in flight while this one is summed — so
-    VMEM holds two windows, never the whole vector, whatever the reach;
+  * each (tile, diagonal) reads the 8-row-aligned ``(T + 16, 128)`` window
+    that covers ``x[row0 + off : row0 + off + tm]`` from one of two homes,
+    chosen by the caller from the padded ``x``'s size:
+      - resident: the whole padded ``x`` is one VMEM operand, copied in
+        once per call, and the window is an aligned dynamic slice of it;
+      - streamed: ``x`` stays in HBM and the window is a DMA into a
+        double-buffered VMEM slot (the next diagonal's window is in flight
+        while this one is summed), so VMEM holds two windows, never the
+        whole vector, whatever the reach;
   * the window is shifted into place in registers: a sublane rotate by
     the row remainder, a lane rotate by the lane remainder, and one select
-    between the row and the row below it. No unaligned dynamic load;
-  * ``offsets`` ride in SMEM via scalar prefetch and drive the DMA starts.
+    between the row and the row below it. No unaligned dynamic load. Both
+    homes share this step and the order of accumulation, so they give
+    bitwise the same ``y``;
+  * ``offsets`` ride in SMEM via scalar prefetch and place the windows.
     A diagonal that misses the tile entirely is skipped (its window is
     clamped in bounds and its contribution dropped); partial windows read
     the zero padding of ``x`` outside ``[0, n)``, so out-of-matrix entries
     contribute nothing.
 
 VMEM per step: 2 * ndiag * tm * itemsize (data, double-buffered) +
-2 * (tm + 2048) * 4 (x windows) + 2 * tm * 4 (y).
+2 * tm * 4 (y) + either 4 * x_pad_len(n, tm) (resident ``x``) or
+2 * (tm + 2048) * 4 (streamed windows).
 """
 from __future__ import annotations
 
@@ -38,7 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.ops import vma
 
 LANES = 128
-_SUB = 8  # f32 sublanes per vreg: DMA starts are aligned to this many rows
+_SUB = 8  # f32 sublanes per vreg: window starts are aligned to this many rows
 
 
 def row_unit(dtype) -> int:
@@ -47,8 +54,20 @@ def row_unit(dtype) -> int:
     return LANES * _SUB * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
-def _dia_kernel(offsets_ref, data_ref, x_hbm, y_ref, buf, sem, *,
+def x_pad_len(n: int, tm: int) -> int:
+    """Length of ``x`` in the kernel's padded coordinates: ``tm`` zeros on
+    the left (a live window starts above ``-tm``), then ``x``, then zeros
+    so the last live window's ``(tm / 128 + 16)``-row read ends in bounds;
+    a whole number of 8-row blocks."""
+    block = _SUB * LANES
+    return -(-(tm + n + tm + 2 * block) // block) * block
+
+
+def _dia_kernel(offsets_ref, data_ref, x_ref, y_ref, *stream,
                 tm: int, n: int, lpad: int, smax: int):
+    """One row tile. ``x_ref`` is the whole padded ``x`` in VMEM, or, with
+    ``stream`` = (window buffers, DMA semaphores), in HBM; the two differ
+    only in where each diagonal's window comes from."""
     t = tm // LANES
     w = t + 2 * _SUB
     ndiag = data_ref.shape[0]
@@ -56,29 +75,41 @@ def _dia_kernel(offsets_ref, data_ref, x_hbm, y_ref, buf, sem, *,
 
     def start_of(d):
         # element start of the diagonal's window in padded coordinates,
-        # clamped so the DMA stays in bounds (a clamped window is dead)
+        # clamped so the read stays in bounds (a clamped window is dead)
         return jnp.clip(row0 + offsets_ref[d] + lpad, 0, smax)
 
-    def window_copy(d, slot):
-        a8 = pl.multiple_of(start_of(d) // (LANES * _SUB) * _SUB, _SUB)
-        return pltpu.make_async_copy(x_hbm.at[pl.ds(a8, w)], buf.at[slot],
-                                     sem.at[slot])
+    def aligned_row(d):
+        return pl.multiple_of(start_of(d) // (LANES * _SUB) * _SUB, _SUB)
 
-    window_copy(0, 0).start()
+    if stream:
+        buf, sem = stream
+
+        def window_copy(d, slot):
+            return pltpu.make_async_copy(x_ref.at[pl.ds(aligned_row(d), w)],
+                                         buf.at[slot], sem.at[slot])
+
+        window_copy(0, 0).start()
+
+        def window(d):
+            slot = d % 2
+
+            @pl.when(d + 1 < ndiag)
+            def _():
+                window_copy(d + 1, 1 - slot).start()
+
+            window_copy(d, slot).wait()
+            return buf[slot]
+    else:
+        def window(d):
+            return x_ref[pl.ds(aligned_row(d), w), :]
+
     lane = jax.lax.broadcasted_iota(jnp.int32, (t, LANES), 1)
 
     def body(d, acc):
-        slot = d % 2
-
-        @pl.when(d + 1 < ndiag)
-        def _():
-            window_copy(d + 1, 1 - slot).start()
-
-        window_copy(d, slot).wait()
+        xw = window(d)                                  # (w, 128)
         s = start_of(d)
         sub = s // LANES % _SUB           # row remainder inside the window
         b = s % LANES                     # lane remainder
-        xw = buf[slot]                                  # (w, 128)
         xw = pltpu.roll(xw, (w - sub) % w, 0)           # row sub -> row 0
         xw = pltpu.roll(xw, (LANES - b) % LANES, 1)     # lane b -> lane 0
         nxt = pltpu.roll(xw, w - 1, 0)                  # the row below
@@ -93,15 +124,19 @@ def _dia_kernel(offsets_ref, data_ref, x_hbm, y_ref, buf, sem, *,
     y_ref[...] = acc
 
 
-@functools.partial(jax.jit, static_argnames=("n", "tm", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("n", "tm", "x_resident", "interpret"))
 def dia_spmv(offsets: jax.Array, data: jax.Array, x: jax.Array, n: int,
-             tm: int = 8192, interpret: bool = False) -> jax.Array:
+             tm: int = 8192, x_resident: bool = False,
+             interpret: bool = False) -> jax.Array:
     """y = A @ x for DIA A given as (offsets[ndiag], data[ndiag, M]).
 
     ``x`` has length ``n`` (rectangular matrices supported). ``data`` rows
     follow the cusp convention data[d, i] = A[i, i + offsets[d]]; entries
     whose column falls outside ``[0, n)`` contribute nothing. ``tm`` must
-    be a multiple of :func:`row_unit` of the data dtype.
+    be a multiple of :func:`row_unit` of the data dtype. ``x_resident``
+    keeps the whole padded ``x`` (``4 * x_pad_len(n, tm)`` bytes) in VMEM;
+    otherwise each window is a DMA from HBM.
     """
     unit = row_unit(data.dtype)
     if tm <= 0 or tm % unit:
@@ -113,14 +148,17 @@ def dia_spmv(offsets: jax.Array, data: jax.Array, x: jax.Array, n: int,
     if m128 != m:
         data = jnp.pad(data, ((0, 0), (0, m128 - m)))
     t = tm // LANES
-    # x in padded coordinates: tm zeros on the left (a live window starts
-    # above -tm), then x, then zeros so the last live window's
-    # (t + 16)-row DMA ends in bounds; total a whole number of 8-row blocks
     lpad = tm
-    total = -(-(lpad + n + tm + 2 * _SUB * LANES) // (_SUB * LANES)) * (_SUB * LANES)
+    total = x_pad_len(n, tm)
     x_pad = jnp.pad(x.astype(jnp.float32), (lpad, total - lpad - n))
     smax = total - (t + 2 * _SUB) * LANES
     kernel = functools.partial(_dia_kernel, tm=tm, n=n, lpad=lpad, smax=smax)
+    if x_resident:
+        x_spec, scratch = pl.BlockSpec(memory_space=pltpu.VMEM), []
+    else:
+        x_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = [pltpu.VMEM((2, t + 2 * _SUB, LANES), jnp.float32),
+                   pltpu.SemaphoreType.DMA((2,))]
     y = pl.pallas_call(
         kernel,
         name="dia_spmv",
@@ -129,11 +167,10 @@ def dia_spmv(offsets: jax.Array, data: jax.Array, x: jax.Array, n: int,
             grid=(pl.cdiv(m128 // LANES, t),),
             in_specs=[
                 pl.BlockSpec((ndiag, t, LANES), lambda i, *_: (0, i, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
+                x_spec,
             ],
             out_specs=pl.BlockSpec((t, LANES), lambda i, *_: (i, 0)),
-            scratch_shapes=[pltpu.VMEM((2, t + 2 * _SUB, LANES), jnp.float32),
-                            pltpu.SemaphoreType.DMA((2,))],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((m128 // LANES, LANES), jnp.float32,
                                        vma=vma(offsets, data, x)),

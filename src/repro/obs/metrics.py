@@ -14,6 +14,10 @@ Standard counter names (incremented by the instrumented layers):
     kernel.route.pallas/.ref/.veto
                              ``kernel_route`` outcomes (veto = a record
                              exists but measured slower than ref)
+    kernel.dia.x_resident/.x_streamed
+                             DIA kernel calls traced with the whole padded
+                             ``x`` in VMEM / with ``x`` windows streamed
+                             from HBM (``repro.kernels.ops.dia_spmv``)
     replan.pattern_sig       memoised DistPlan format plans dropped
                              because the live pattern changed
     halo.bytes               bytes a traced ``dist_spmv`` exchanges per

@@ -10,6 +10,7 @@ from repro.core import (Format, banded_coo, coo_from_dense_np, convert,
 from repro.kernels import ops as kops
 from repro.kernels.ref import (bsr_spmm_ref, csr_spmv_ref, dia_spmv_ref,
                                ell_spmv_ref)
+from repro.obs import metrics
 
 RNG = np.random.default_rng(0)
 
@@ -22,6 +23,10 @@ def _tol(dtype):
 # DIA SpMV
 # ---------------------------------------------------------------------------
 
+# x's home in the DIA kernel -> an X_VMEM_BUDGET that forces it
+DIA_X_PATHS = {"resident": 1 << 40, "streamed": 0}
+
+
 @pytest.mark.parametrize("shape,offsets", [
     ((64, 64), [0]),
     ((128, 128), [-1, 0, 1]),
@@ -32,13 +37,38 @@ def _tol(dtype):
     ((513, 513), [-5, 0, 5]),            # non-tile-aligned rows
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_dia_kernel_sweep(shape, offsets, dtype):
+@pytest.mark.parametrize("path", DIA_X_PATHS)
+def test_dia_kernel_sweep(shape, offsets, dtype, path, monkeypatch):
+    """Both homes of x, forced by the VMEM budget: the whole padded x in
+    VMEM, or per-tile windows streamed from HBM."""
+    monkeypatch.setattr(kops, "X_VMEM_BUDGET", DIA_X_PATHS[path])
     A = convert(banded_coo(shape, offsets, dtype=dtype), Format.DIA)
     x = jnp.asarray(RNG.standard_normal(shape[1]), dtype=dtype)
-    y_k = kops.dia_spmv(A, x)
+    with metrics.scope() as s:
+        y_k = kops.dia_spmv(A, x)
+    assert s.delta(f"kernel.dia.x_{path}") == 1
+    assert sum(s.delta(f"kernel.dia.x_{p}") for p in DIA_X_PATHS) == 1
     y_r = dia_spmv_ref(A.offsets, A.data, x, shape[1])
     np.testing.assert_allclose(np.asarray(y_k, np.float32),
                                np.asarray(y_r, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("shape,offsets,tm", [
+    ((1000, 1000), [-96, -32, -1, 0, 1, 32, 96], 1024),
+    ((3000, 2500), [-2100, -700, 0, 5, 1300], 1024),
+    ((517, 517), [-19, -3, 0, 3, 19], 2048),
+])
+def test_dia_kernel_x_paths_agree_bitwise(shape, offsets, tm, monkeypatch):
+    """The resident and streamed kernels differ only in where each window
+    comes from: the same shifts and order of accumulation give the same
+    bits."""
+    A = convert(banded_coo(shape, offsets), Format.DIA)
+    x = jnp.asarray(RNG.standard_normal(shape[1]).astype(np.float32))
+    ys = {}
+    for path, budget in DIA_X_PATHS.items():
+        monkeypatch.setattr(kops, "X_VMEM_BUDGET", budget)
+        ys[path] = np.asarray(kops.dia_spmv(A, x, tm=tm))
+    np.testing.assert_array_equal(ys["resident"], ys["streamed"])
 
 
 @pytest.mark.parametrize("tm", [1024, 2048, 4096])
@@ -342,7 +372,10 @@ def test_vmem_budget_fallback():
     n = 2_000_000  # 8 MB f32
     A = convert(banded_coo((1024, n), [0, 100]), Format.DIA)
     x = jnp.asarray(RNG.standard_normal(n).astype(np.float32))
-    y = kops.dia_spmv(A, x)
+    with metrics.scope() as s:
+        y = kops.dia_spmv(A, x)
+    assert s.delta("kernel.dia.x_streamed") == 1
+    assert s.delta("kernel.dia.x_resident") == 0
     np.testing.assert_allclose(np.asarray(y),
                                np.asarray(dia_spmv_ref(A.offsets, A.data, x, n)),
                                rtol=1e-5, atol=1e-5)

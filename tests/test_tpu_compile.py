@@ -26,6 +26,7 @@ from repro.core.solvers import cg, operator
 from repro.kernels import dia_spmv as dia_kernel
 from repro.kernels import ops as kops
 from repro.launch.mesh import make_mesh
+from repro.obs import metrics
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from bench import scopes  # noqa: E402
@@ -83,6 +84,12 @@ def _unscoped_loop_ops(compiled):
             and 'custom_call_target="ConcatBitcast"' not in line]
 
 
+def _dia_paths(scope):
+    """DIA kernel calls traced in ``scope``, by the home of ``x``."""
+    return {p: scope.delta(f"kernel.dia.x_{p}")
+            for p in ("resident", "streamed")}
+
+
 def _dia(sds, lead=()):
     return DIA(sds(*lead, NDIAG, dtype=jnp.int32), sds(*lead, NDIAG, M),
                (M, M), NDIAG * M)
@@ -96,7 +103,10 @@ def test_dia_kernel_compiles_at_104cubed(topo, native):
 
     A = _dia(sds)
     assert kops.default_config(A)["tm"] % dia_kernel.row_unit(jnp.float32) == 0
-    compiled = jax.jit(kops.dia_spmv).lower(A, sds(M)).compile()
+    with metrics.scope() as s:
+        compiled = jax.jit(kops.dia_spmv).lower(A, sds(M)).compile()
+    # the padded x (4.8 MB) fits VMEM: no per-diagonal window DMAs
+    assert _dia_paths(s) == {"resident": 1, "streamed": 0}
     assert _custom_calls(compiled) == 1
     assert _device_bytes(compiled) < HBM_BYTES
 
@@ -110,7 +120,9 @@ def test_cg_pallas_compiles_at_104cubed(topo, native):
 
     solve = jax.jit(lambda a, b: cg(operator(a, backend="pallas"), b,
                                     tol=1e-7, maxiter=500))
-    compiled = solve.lower(_dia(sds), sds(M)).compile()
+    with metrics.scope() as s:
+        compiled = solve.lower(_dia(sds), sds(M)).compile()
+    assert _dia_paths(s)["streamed"] == 0 < _dia_paths(s)["resident"]
     assert _custom_calls(compiled) >= 1
     assert _device_bytes(compiled) < HBM_BYTES
     assert _unscoped_loop_ops(compiled) == []
@@ -135,7 +147,10 @@ def test_dist_cg_pallas_compiles_on_4_chips(topo, native):
                          axis="rows", halo_mode="neighbor", hw=hw)
     solve = jax.jit(lambda a, b: cg(operator(a, mesh, backend="pallas"), b,
                                     tol=1e-7, maxiter=500))
-    compiled = solve.lower(A, sds(nd * M)).compile()
+    with metrics.scope() as s:
+        compiled = solve.lower(A, sds(nd * M)).compile()
+    # interior and boundary parts: x_blk of one chip fits VMEM
+    assert _dia_paths(s)["streamed"] == 0 < _dia_paths(s)["resident"]
     text = compiled.as_text()
     assert _custom_calls(compiled) >= 1
     assert "collective-permute" in text
