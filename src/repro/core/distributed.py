@@ -312,10 +312,9 @@ def dist_spmv_phase(A: DistSparseMatrix, x, mesh: Mesh, phase: str = "full",
     Timing the phases independently and comparing ``t_local + t_exchange``
     against ``t_full`` measures how much of the exchange XLA's scheduler
     actually hid behind local compute (``hidden = local + exchange -
-    full``); the per-shard-count sweep in ``benchmarks/bench_obs.py`` uses
-    this to localize where the ghost-mode p8 overlap is lost. The
-    ``interior``/``boundary`` phases further attribute the local side of a
-    split matrix: the interior term is the overlap window's width.
+    full``). The ``interior``/``boundary`` phases further attribute the
+    local side of a split matrix: the interior term is the overlap
+    window's width.
     """
     if phase == "full":
         return dist_spmv(A, x, mesh, backend=backend, cfg=cfg)

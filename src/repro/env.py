@@ -17,14 +17,14 @@ flags can abort process startup. This module therefore
     unless ``JAX_COMPILATION_CACHE_DIR`` already names a directory (then
     that one is used, and no other is set).
 
-Entry points (``benchmarks/run.py``, the bench subprocess scripts,
-``examples/hpcg_solve.py``, CI) call :func:`apply` first thing::
+Entry points (``examples/hpcg_solve.py``, ``bench/run.py``,
+``repro.launch.serve``) call :func:`apply` first thing::
 
     from repro import env
     env.apply(host_devices=8)      # CPU SPMD: 8 forced host devices
     import jax                     # now initializes with the flags set
 
-:func:`describe` reports what was applied for the BENCH_*.json meta.
+:func:`describe` reports what was applied.
 """
 from __future__ import annotations
 
@@ -124,8 +124,8 @@ def apply(backend: Optional[str] = None,
 
 
 def describe() -> Dict[str, object]:
-    """What :func:`apply` last did (for BENCH meta provenance); reads the
-    live environment when apply was never called in this process."""
+    """What :func:`apply` last did; reads the live environment when apply
+    was never called in this process."""
     if _applied is not None:
         return dict(_applied)
     return {"backend": resolve_backend(), "host_devices": None,

@@ -16,8 +16,7 @@ For every (architecture x input-shape x mesh) cell:
      lax.scan body ONCE regardless of trip count (verified empirically), so
      the corrected cost is  c0 + L*(c1 - c0)  with no scans left inside c1.
 
-Results land in results/dryrun/<arch>__<shape>__<mesh>.json, consumed by
-benchmarks/roofline.py and EXPERIMENTS.md.
+Results land in results/dryrun/<arch>__<shape>__<mesh>.json.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch stablelm_1_6b \

@@ -1,4 +1,4 @@
-"""repro.obs — the perf flight recorder: tracing, metrics, decisions.
+"""repro.obs — tracing, metrics and decision records.
 
 Small, zero-heavy-dep pieces:
 
@@ -17,21 +17,13 @@ Small, zero-heavy-dep pieces:
   serving requests), gated by ``REPRO_LEDGER`` (on by default).
 * :mod:`repro.obs.explain` — replays the ledger into a human-readable
   decision trail. CLI: ``python -m repro.obs.explain``.
-* :mod:`repro.obs.regress` — bench-trajectory store + noise-aware
-  baseline regression gate. CLI: ``python -m repro.obs.regress``.
 * :mod:`repro.obs.report`  — per-phase attribution tables
   (select/plan/convert/kernel/solver/build) from a live or exported
-  trace, plus the distributed exchange-overlap table from
-  ``BENCH_obs.json``. CLI: ``python -m repro.obs.report``.
-
-:func:`repro.obs.provenance.env_info` records run provenance (jax
-version, backend, devices, git rev) in every ``BENCH_*.json``.
+  trace. CLI: ``python -m repro.obs.report``.
 """
 from repro.obs import ledger
 from repro.obs import metrics
 from repro.obs import trace
-from repro.obs.provenance import env_info
 from repro.obs.trace import event, span, tracing
 
-__all__ = ["ledger", "metrics", "trace", "span", "event", "tracing",
-           "env_info"]
+__all__ = ["ledger", "metrics", "trace", "span", "event", "tracing"]

@@ -109,7 +109,7 @@ def clear() -> None:
 
 def dump_json(path: str) -> str:
     """Write the ring as a JSON document ``{"records": [...], "dropped",
-    "capacity"}`` — the CI artifact ``repro.obs.explain`` replays."""
+    "capacity"}`` — the file ``repro.obs.explain`` replays."""
     doc = {"records": records(), "dropped": dropped(), "capacity": CAPACITY}
     d = os.path.dirname(path)
     if d:

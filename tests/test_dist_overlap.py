@@ -31,12 +31,12 @@ from repro.obs import metrics
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
-def _run_subprocess(body: str, env=None):
+def _run_subprocess(body: str, env=None, ndev: int = 8):
     script = textwrap.dedent("""
         import sys
         sys.path.insert(0, %r)
         from repro import env
-        env.apply(host_devices=8)
+        env.apply(host_devices=%d)
         import os
         import numpy as np
         import jax, jax.numpy as jnp
@@ -45,7 +45,7 @@ def _run_subprocess(body: str, env=None):
                                             dist_spmv, dist_spmv_phase,
                                             distribute_vector)
         from repro.launch.mesh import make_mesh
-    """ % os.path.abspath(SRC)) + textwrap.dedent(body)
+    """ % (os.path.abspath(SRC), ndev)) + textwrap.dedent(body)
     full_env = dict(os.environ, **(env or {}))
     res = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, timeout=600, env=full_env)
@@ -313,13 +313,12 @@ def test_plan_cache_restart_skips_planning(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_dist_split_spmv_parity_8shards():
-    """Split vs unsplit vs dense oracle, plus the phase decomposition:
-    interior + boundary == local, and the production result is identical
-    either way."""
-    body = """
-    mesh = make_mesh((8,), ("rows",))
-    prob = hpcg.generate_problem(4, 4, 8)
+# Split vs unsplit vs dense oracle, plus the phase decomposition:
+# interior + boundary == local, and the production result is identical
+# either way.
+_SPLIT_PARITY = """
+    mesh = make_mesh((%(ndev)d,), ("rows",))
+    prob = hpcg.generate_problem(*%(grid)r)
     n = prob.shape[0]
     D = np.zeros((n, n))
     np.add.at(D, (prob.row, prob.col), prob.val)
@@ -354,7 +353,17 @@ def test_dist_split_spmv_parity_8shards():
         raise AssertionError("interior phase on unsplit matrix must raise")
     print("OK")
     """
+
+
+def test_dist_split_spmv_parity_8shards():
+    body = _SPLIT_PARITY % {"ndev": 8, "grid": (4, 4, 8)}
     assert "OK" in _run_subprocess(body)
+
+
+def test_dist_split_spmv_parity_16shards():
+    """One z-plane per shard, so every row reaches a neighbouring shard."""
+    body = _SPLIT_PARITY % {"ndev": 16, "grid": (8, 8, 16)}
+    assert "OK" in _run_subprocess(body, ndev=16)
 
 
 def test_dist_split_multiformat_and_boundary_activate_8shards():

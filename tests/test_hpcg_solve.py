@@ -1,9 +1,15 @@
 """The HPCG example's entry point, called in-process as chip_smoke.py
-calls it: ``main(argv)`` returns the run record, not just an exit code."""
+calls it: ``main(argv)`` returns the run record, not just an exit code.
+Its CLI runs on 8 forced host devices in a subprocess (the device count
+is fixed when jax starts, and conftest keeps this session at one)."""
 import importlib.util
 import os
+import subprocess
+import sys
 
 import pytest
+
+from repro.obs import report
 
 EXAMPLE = os.path.join(os.path.dirname(__file__), "..", "examples",
                        "hpcg_solve.py")
@@ -60,3 +66,50 @@ def test_profile_holds_the_solve_and_its_layers(hpcg_solve, tmp_path):
     with open(tmp_path / "solve.hlo.txt") as f:
         table = scopes.instruction_scopes(f.read())
     assert {"solver.spmv", "solver.vector"} <= set(table.values())
+
+
+def _run_cli(args, cwd, **env):
+    """``HPCG_DEVICES=8 python examples/hpcg_solve.py ARGS`` in ``cwd``,
+    with its selection cache there too; returns its stdout."""
+    full_env = dict(os.environ, HPCG_DEVICES="8",
+                    REPRO_TUNING_CACHE=str(cwd / "selections.json"), **env)
+    res = subprocess.run([sys.executable, os.path.abspath(EXAMPLE)] + args,
+                         capture_output=True, text=True, timeout=600,
+                         env=full_env, cwd=cwd)
+    assert res.returncode == 0, f"stdout:\n{res.stdout}\nstderr:\n{res.stderr}"
+    return res.stdout
+
+
+@pytest.fixture(scope="module")
+def mg_traced(tmp_path_factory):
+    """MG-PCG with per-level selection on 8 host devices, under
+    ``REPRO_TRACE=full``: its stdout and the directory it ran in."""
+    cwd = tmp_path_factory.mktemp("mg")
+    out = _run_cli(["--grid", "16", "16", "16", "--mode", "multiformat",
+                    "--tune", "cached", "--precond", "mg", "--tol", "1e-8",
+                    "--verbose"], cwd, REPRO_TRACE="full")
+    return out, cwd
+
+
+def test_cli_cg_multiformat_cached_8_devices(tmp_path):
+    out = _run_cli(["--grid", "8", "8", "16", "--mode", "multiformat",
+                    "--tune", "cached"], tmp_path)
+    assert "-> PASS" in out, out
+    assert "per-shard remote formats:" in out, out
+
+
+def test_cli_mg_pcg_selects_per_level_8_devices(mg_traced):
+    out, _ = mg_traced
+    assert "-> PASS" in out, out
+    assert "  level 0 " in out and "  level 1 " in out, out
+
+
+def test_cli_mg_pcg_trace_renders_by_phase(mg_traced, capsys):
+    """The exported ``trace.json`` renders a row for each host phase the
+    MG-PCG build and solve pass through."""
+    out, cwd = mg_traced
+    assert "trace exported: trace.json" in out, out
+    assert report.main([str(cwd / "trace.json")]) == 0
+    rows = {line.split()[0] for line in capsys.readouterr().out.splitlines()
+            if line.strip()}
+    assert {"build", "plan", "convert", "select", "solver"} <= rows, rows

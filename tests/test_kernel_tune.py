@@ -313,3 +313,42 @@ def test_cached_policy_width_buckets_store_distinct_decisions(tmp_path,
                                                         ncols=256)
     assert warm_n.mode == "cached" and warm_n.backend == "pallas"
     assert warm_w.mode == "cached" and warm_w.backend is None
+
+
+# ---------------------------------------------------------------------------
+# the CLI's smoke tune (``python -m repro.tuning.kernel_tune --smoke``)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def smoke_cache(tmp_path_factory):
+    """The cache file ``--smoke --cache PATH`` leaves behind."""
+    path = str(tmp_path_factory.mktemp("ktune") / "smoke_kernels.json")
+    kt.main(["--smoke", "--cache", path])
+    return path
+
+
+def test_smoke_cache_roundtrips_with_sell_geometry(smoke_cache):
+    """Every record reads back through a fresh handle, and the tuned SELL
+    record names the container geometry (c, sigma) it was measured on."""
+    with open(smoke_cache) as f:
+        raw = json.load(f)
+    fresh = SelectionCache(smoke_cache)
+    assert raw and all(fresh.get_raw(k) == v for k, v in raw.items())
+    sell = {k: kt.KernelRecord.from_json(v) for k, v in raw.items()
+            if k.startswith("kernel:") and "|SELL|" in k}
+    assert sell, sorted(raw)
+    for k, rec in sell.items():
+        assert "c" in rec.cfg and "sigma" in rec.cfg, (k, rec.cfg)
+
+
+def test_smoke_cache_auto_never_routes_a_loser(smoke_cache, monkeypatch):
+    """With the smoke tune's cache as the process default, ``auto`` takes
+    Pallas exactly where the measured record beat the reference path."""
+    monkeypatch.setenv(CACHE_PATH_ENV, smoke_cache)
+    fresh = SelectionCache(smoke_cache)
+    for A in kt._suite(smoke=True):
+        rec = kt.best_config(A, cache=fresh)
+        assert rec is not None, type(A).__name__
+        want = "pallas" if rec.speedup >= 1.0 else "ref"
+        assert core_ops.resolve_backend("auto", A) == want, rec

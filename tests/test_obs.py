@@ -1,5 +1,7 @@
-"""repro.obs: tracer, metrics, ledger, report, provenance, solver history."""
+"""repro.obs: tracer, metrics, ledger, explain, report, solver history."""
 import collections
+import contextlib
+import io
 import json
 import os
 import sys
@@ -11,14 +13,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import Format, hpcg
+from repro.core import Format, coo_from_arrays, hpcg
 from repro.core.convert import convert, planned_pulls_scope
 from repro.core.ops import spmv
 from repro.core.solvers import cg, cg_fixed_iters, pcg
 from repro.launch.mesh import make_mesh
 from repro.obs import explain, ledger, metrics, trace
 from repro.obs import report
-from repro.obs.provenance import env_info
 
 
 @pytest.fixture(autouse=True)
@@ -421,13 +422,24 @@ def test_policy_select_emits_explainable_records(tmp_path, _ledger_on):
         assert "CART path" in text and "leaf[" in text
 
 
+def _powerlaw_coo(seed, n, shape_a=1.3, scale=4.0):
+    """Power-law row lengths (pareto counts): the irregular-row family."""
+    rng = np.random.default_rng(seed)
+    counts = np.minimum(1 + (rng.pareto(shape_a, n) * scale).astype(np.int64),
+                        n)
+    rows = np.repeat(np.arange(n, dtype=np.int64), counts)
+    cols = np.concatenate([rng.choice(n, k, replace=False) for k in counts])
+    vals = rng.standard_normal(len(rows)).astype(np.float32)
+    vals = np.where(np.abs(vals) < 1e-3, 1e-3, vals)
+    return coo_from_arrays(rows, cols, vals, (n, n))
+
+
 def test_plan_for_records_sell_geometry_source(tmp_path, _ledger_on):
-    from benchmarks.bench_formats import powerlaw_coo
     from repro.tuning import kernel_tune
     from repro.tuning.cache import SelectionCache
     from repro.tuning.policy import FormatPolicy
 
-    C = powerlaw_coo(3, 512)
+    C = _powerlaw_coo(3, 512)
     cache = SelectionCache(str(tmp_path / "k.json"))
     A = convert(C, Format.SELL)
     kernel_tune.tune_kernel(A, cache=cache,
@@ -474,6 +486,35 @@ def test_explain_render_kernel_record_with_sell_geometry(_ledger_on):
     assert "2.50x" in text
 
 
+@pytest.fixture(scope="module")
+def explain_demo(tmp_path_factory):
+    """``python -m repro.obs.explain --family powerlaw --dump PATH``: the
+    demo's printed decision trail and the ledger dump it wrote."""
+    dump = str(tmp_path_factory.mktemp("explain") / "ledger_dump.json")
+    was = ledger.enabled()
+    out = io.StringIO()
+    try:
+        with contextlib.redirect_stdout(out):
+            assert explain.main(["--family", "powerlaw", "--dump", dump]) == 0
+    finally:
+        ledger.clear()
+        ledger.set_enabled(was)
+    return out.getvalue(), dump
+
+
+def test_explain_demo_prints_the_decision_trail(explain_demo):
+    text, dump = explain_demo
+    assert "CART path" in text and "format.select" in text
+    assert "veto:" in text or "kernel record:" in text
+    assert ledger.load_json(dump)["records"]
+
+
+def test_explain_replays_its_dump(explain_demo, capsys):
+    _, dump = explain_demo
+    assert explain.main([dump, "--quiet"]) == 0
+    assert "format.select" in capsys.readouterr().out
+
+
 # ---------------------------------------------------------------------------
 # Report
 # ---------------------------------------------------------------------------
@@ -496,20 +537,6 @@ def test_attribution_self_time():
     assert "build" in report.render_attribution(list(rows.values()))
 
 
-def test_overlap_rows_from_bench_doc():
-    doc = {"rows": [
-        {"name": "obs_overlap_ghost_p4", "us_per_call": 120.0,
-         "derived": "local_us=100;exch_us=60;hidden_us=40;hidden_frac=0.667"},
-        {"name": "obs_overlap_ghost_p8", "us_per_call": 180.0,
-         "derived": "local_us=100;exch_us=80;hidden_us=0;hidden_frac=0.0"},
-        {"name": "scaling_spmv_ghost_p8", "us_per_call": 1.0, "derived": ""},
-    ]}
-    rows = report.overlap_rows(doc)
-    assert [r["p"] for r in rows] == [4, 8]
-    text = report.render_overlap(rows)
-    assert "hidden" in text and "ghost" in text
-
-
 def test_report_cli_renders(tmp_path, capsys):
     with trace.tracing("full"):
         with trace.span("solver.solve"):
@@ -523,16 +550,8 @@ def test_report_cli_renders(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
-# Provenance + solver history
+# Solver history
 # ---------------------------------------------------------------------------
-
-
-def test_env_info_shape():
-    info = env_info()
-    assert info["jax_version"] == jax.__version__
-    assert info["backend"] == jax.default_backend()
-    assert info["device_count"] >= 1
-    json.dumps(info)
 
 
 def test_cg_history_fixed_size_and_monotone_tail():

@@ -98,9 +98,10 @@ def test_prefill_by_decode_fallback_families():
 
 @pytest.mark.slow
 def test_serve_smoke_subprocess():
-    """The CI entry point: every request completes, output lists printed.
-    Run in a subprocess so serve's env.apply() cannot touch this session's
-    XLA flags (conftest asserts the device-count override never leaks)."""
+    """The CLI: every request completes, output lists printed, and the
+    engine takes the batched prefill, not the per-token fallback. Run in
+    a subprocess so serve's env.apply() cannot touch this session's XLA
+    flags (conftest asserts the device-count override never leaks)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     n = 6
@@ -112,6 +113,7 @@ def test_serve_smoke_subprocess():
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert out.returncode == 0, out.stderr[-2000:]
     assert f"served {n} requests, {n * 4} tokens" in out.stdout, out.stdout
+    assert "batched prefills" in out.stdout, out.stdout
     for rid in range(n):
         assert f"req {rid}:" in out.stdout, out.stdout
 
