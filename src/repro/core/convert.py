@@ -130,7 +130,7 @@ def dia_to_coo(A: DIA) -> COO:
     cols = i + offs
     valid = (cols >= 0) & (cols < n)
     rows = jnp.broadcast_to(i, (nd, m))
-    data = jnp.where(valid, A.data, 0)
+    data = jnp.where(valid, A.table, 0)
     rows = jnp.where(valid, rows, 0)
     cols = jnp.where(valid, cols, 0)
     return COO(rows.reshape(-1), cols.reshape(-1), data.reshape(-1), A.shape, A.nnz)

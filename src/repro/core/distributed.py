@@ -40,7 +40,14 @@ Architecture (the PR-2 plan/execute split, applied end-to-end):
 
 Containers are *stacked*: every array gains a leading P axis which is
 sharded over the mesh partition axes; inside ``shard_map`` each shard sees
-its own slab (leading dim 1) and unstacks it.
+its own slab (leading dim 1) and unstacks it. A uniform-mode DIA or COO
+part is *row-sharded* instead: one container over every shard's rows,
+sharded along its row axis, so each shard sees exactly its own container
+with no axis of length 1 to strip. A DIA part's table is lane-tiled,
+``(ndiag, P * ceil(mp/128), 128)`` sharded along the row-tile axis, its
+offsets ``(P * ndiag,)``; a COO part's arrays are ``(P * cap,)``. A shard
+then hands the Pallas DIA kernel its table as stored, with no copy or
+relayout of it in the solve.
 """
 from __future__ import annotations
 
@@ -57,7 +64,7 @@ from repro.core.convert import (SwitchPlan, _planned_pull,
                                 convert_execute_batch, plan_switch_batch)
 from repro.core import ops as _ops
 from repro.core.dynamic import SwitchDynamicMatrix
-from repro.core.formats import COO, Format
+from repro.core.formats import COO, DIA, Format, tile_dia_table
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 
@@ -79,19 +86,51 @@ def leading_axis_spec(axis, ndim: int) -> PartitionSpec:
     return PartitionSpec(axis, *(None,) * (ndim - 1))
 
 
-def stack_parts(parts: Sequence):
-    """Stack P same-structure containers into one with a leading P axis."""
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *parts)
+def _row_sharded(part) -> bool:
+    """Whether ``part`` is a row-sharded DIA or COO container, told from a
+    stacked one by its arrays' rank: row-sharded offsets and COO arrays
+    are 1-D, stacked ones carry the leading shard axis."""
+    if isinstance(part, DIA):
+        return part.offsets.ndim == 1
+    return isinstance(part, COO) and part.row.ndim == 1
+
+
+def _row_shard(part):
+    """A stacked uniform-mode part as it is stored row-sharded: a DIA
+    table lane-tiled and laid shard after shard along its row-tile axis,
+    COO arrays end to end. Other containers stay stacked. Runs once, at
+    build; for P = 1 each step is a reshape."""
+    if isinstance(part, DIA):
+        tiles = tile_dia_table(part.data)              # (P, nd, Mt, 128)
+        nshards, nd, mt, lanes = tiles.shape
+        data = jnp.moveaxis(tiles, 0, 1).reshape(nd, nshards * mt, lanes)
+        return DIA(part.offsets.reshape(-1), data, part.shape, part.nnz)
+    if isinstance(part, COO):
+        return jax.tree.map(lambda a: a.reshape(-1), part)
+    return part
 
 
 def _unstack(part):
-    """Inside shard_map: strip the leading (length-1) shard axis."""
+    """Inside shard_map: a shard's own container. A stacked one has its
+    leading (length-1) shard axis stripped; a row-sharded one is already
+    the shard's container."""
+    if _row_sharded(part):
+        return part
     return jax.tree.map(lambda a: a[0], part)
 
 
 def _part_spec(t, axis: AxisNames):
-    """Stacked-container PartitionSpec tree: leading shard axis on ``axis``."""
-    return jax.tree.map(lambda a: leading_axis_spec(axis, a.ndim), t)
+    """PartitionSpec tree of shard containers on ``axis``: the leading
+    shard axis of a stacked container, the row axis of a row-sharded one
+    (axis 1 of a lane-tiled DIA table)."""
+    def spec(part):
+        if _row_sharded(part):
+            return jax.tree.map(
+                lambda a: (PartitionSpec(axis) if a.ndim == 1
+                           else PartitionSpec(None, axis, None)), part)
+        return jax.tree.map(lambda a: leading_axis_spec(axis, a.ndim), part)
+
+    return jax.tree.map(spec, t, is_leaf=lambda n: isinstance(n, (COO, DIA)))
 
 
 # ---------------------------------------------------------------------------
@@ -103,8 +142,9 @@ def _part_spec(t, axis: AxisNames):
 class DistSparseMatrix:
     """Row-partitioned sparse matrix with local/remote split per shard.
 
-    ``local``/``remote`` are stacked containers (or stacked
-    SwitchDynamicMatrix for Multi-Format). ``halo_mode`` is ``"neighbor"``
+    ``local``/``remote`` are row-sharded DIA or COO containers, or
+    stacked ones of the other formats (stacked SwitchDynamicMatrix for
+    Multi-Format); see the module docstring. ``halo_mode`` is ``"neighbor"``
     (remote columns renumbered into a [prev_tail | next_head] halo of width
     ``hw`` per side) or ``"gather"`` (remote columns are global ids).
     ``remote_empty`` marks a statically block-diagonal partition: the
@@ -935,13 +975,13 @@ def build_dist_matrix(row, col, val, shape, mesh: Mesh, axis: AxisNames,
 
     boundary = None
     if mode == "uniform":
-        local = convert_execute_batch(
-            lcoos, plan_switch_batch(lcoos, Format(local_format)))
+        local = _row_shard(convert_execute_batch(
+            lcoos, plan_switch_batch(lcoos, Format(local_format))))
         if bcoos is not None:
-            boundary = convert_execute_batch(
-                bcoos, plan_switch_batch(bcoos, Format(local_format)))
-        remote = convert_execute_batch(
-            rcoos, plan_switch_batch(rcoos, Format(remote_format)))
+            boundary = _row_shard(convert_execute_batch(
+                bcoos, plan_switch_batch(bcoos, Format(local_format))))
+        remote = _row_shard(convert_execute_batch(
+            rcoos, plan_switch_batch(rcoos, Format(remote_format))))
     elif mode == "multiformat":
         # per-shard selection, paper §V-E, via the unified FormatPolicy
         from repro.tuning.policy import FormatPolicy
@@ -1026,7 +1066,7 @@ def _count_parts(parts: dict) -> dict:
 
 
 def _shard_containers(A: DistSparseMatrix, mesh: Mesh) -> DistSparseMatrix:
-    """Place stacked shard arrays with their leading axis on the mesh."""
+    """Place the parts' arrays on the mesh by :func:`_part_spec`."""
     axis = A.axis
 
     def put(t):
@@ -1035,8 +1075,8 @@ def _shard_containers(A: DistSparseMatrix, mesh: Mesh) -> DistSparseMatrix:
         # backends, which must not trip a build-time transfer guard.
         with jax.transfer_guard("allow"):
             return jax.tree.map(
-                lambda a: jax.device_put(
-                    a, NamedSharding(mesh, leading_axis_spec(axis, a.ndim))), t)
+                lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec)),
+                t, _part_spec(t, axis))
 
     return A._replace_parts(put(A.local), put(A.remote),
                             boundary=put(A.boundary) if A.split else None)

@@ -113,10 +113,16 @@ class DIA:
     ``data[d, i]`` holds A[i, i + offsets[d]] (cusp convention, padded with
     zeros where the diagonal leaves the matrix). Rectangular matrices are
     supported: offsets range over [-(M-1), N-1].
+
+    ``data`` is the ``(ndiag, M)`` table, or the same table lane-tiled,
+    ``(ndiag, ceil(M / 128), 128)`` with A[i, i + offsets[d]] at
+    ``data[d, i // 128, i % 128]`` and zeros in the rows past M: the
+    layout the Pallas DIA kernel reads, which a distributed part stores
+    (``repro.core.distributed``). :attr:`table` is the 2-D view of either.
     """
 
     offsets: jax.Array  # (ndiag,) int32 diagonal offsets (k = col - row)
-    data: jax.Array  # (ndiag, M) values
+    data: jax.Array  # (ndiag, M) or lane-tiled (ndiag, ceil(M/128), 128)
     shape: Tuple[int, int] = static_field()
     nnz: int = static_field()
 
@@ -129,6 +135,28 @@ class DIA:
     @property
     def dtype(self):
         return self.data.dtype
+
+    @property
+    def tiled(self) -> bool:
+        """True when ``data`` is lane-tiled, ``(ndiag, ceil(M/128), 128)``."""
+        return self.data.ndim == 3
+
+    @property
+    def table(self) -> jax.Array:
+        """``data`` as the ``(ndiag, M)`` table, whatever its layout."""
+        if not self.tiled:
+            return self.data
+        return self.data.reshape(self.ndiag, -1)[:, :self.shape[0]]
+
+
+def tile_dia_table(data: jax.Array) -> jax.Array:
+    """An ``(..., M)`` DIA table lane-tiled: ``(..., ceil(M/128), 128)``,
+    the rows past M zero."""
+    m = data.shape[-1]
+    pad = -m % 128
+    if pad:
+        data = jnp.pad(data, [(0, 0)] * (data.ndim - 1) + [(0, pad)])
+    return data.reshape(*data.shape[:-1], (m + pad) // 128, 128)
 
 
 @_register
@@ -366,7 +394,7 @@ def to_dense_np(A) -> np.ndarray:
             sl = slice(indptr[i], indptr[i + 1])
             np.add.at(out, (np.full(indptr[i + 1] - indptr[i], i), idx[sl]), v[sl])
     elif isinstance(A, DIA):
-        offs, d = np.asarray(A.offsets), np.asarray(A.data)
+        offs, d = np.asarray(A.offsets), np.asarray(A.table)
         for k in range(d.shape[0]):
             off = int(offs[k])
             i = np.arange(max(0, -off), min(m, n - off))

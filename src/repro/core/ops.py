@@ -13,6 +13,7 @@ block-sparse / MoE integration) and the dense-vector algorithms used by CG
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -190,10 +191,11 @@ def _spmv_dia(A: DIA, x):
         w = jax.lax.dynamic_slice(xp, (off + m,), (m,))
         return acc + drow * w, None
 
+    table = A.table
     acc0 = varying_like(jnp.zeros((m,), jnp.result_type(A.dtype, x.dtype)),
-                        x, A.data)
+                        x, table)
     acc, _ = jax.lax.scan(one_diag, acc0,
-                          (A.offsets.astype(jnp.int32), A.data),
+                          (A.offsets.astype(jnp.int32), table),
                           unroll=min(A.ndiag, _DIA_UNROLL_MAX))
     return acc
 
@@ -315,7 +317,7 @@ def _spmm_dia(A: DIA, B):
     cols = i + A.offsets[:, None].astype(jnp.int32)
     valid = (cols >= 0) & (cols < n)
     bv = jnp.take(B, jnp.clip(cols, 0, n - 1), axis=0, mode="clip")  # (nd, M, K)
-    return jnp.sum(jnp.where(valid[..., None], A.data[..., None] * bv, 0), axis=0)
+    return jnp.sum(jnp.where(valid[..., None], A.table[..., None] * bv, 0), axis=0)
 
 
 def _spmm_ell(A: ELL, B):
@@ -427,7 +429,7 @@ def extract_diagonal(A):
     if isinstance(A, DIA):
         slot = jnp.argmax(A.offsets == 0)
         has = jnp.any(A.offsets == 0)
-        return jnp.where(has, A.data[slot, :d], 0)
+        return jnp.where(has, A.table[slot, :d], 0)
     if isinstance(A, ELL):
         i = jnp.arange(A.shape[0], dtype=jnp.int32)[:, None]
         on = A.cols == i
@@ -454,8 +456,9 @@ def update_diagonal(A, new_diag):
         return CSR(A.indptr, A.indices, jnp.where(on, jnp.take(new_diag, rows, mode="clip"), A.data), A.shape, A.nnz)
     if isinstance(A, DIA):
         slot = jnp.argmax(A.offsets == 0)
-        row = jnp.zeros((A.data.shape[1],), A.dtype).at[:new_diag.shape[0]].set(new_diag.astype(A.dtype))
-        return DIA(A.offsets, A.data.at[slot].set(row), A.shape, A.nnz)
+        rows = A.data.shape[1:]  # (M,), or (M/128, 128) lane-tiled
+        row = jnp.zeros((math.prod(rows),), A.dtype).at[:new_diag.shape[0]].set(new_diag.astype(A.dtype))
+        return DIA(A.offsets, A.data.at[slot].set(row.reshape(rows)), A.shape, A.nnz)
     if isinstance(A, ELL):
         i = jnp.arange(A.shape[0], dtype=jnp.int32)[:, None]
         on = A.cols == i

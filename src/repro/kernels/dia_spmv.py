@@ -7,8 +7,9 @@ multiply-add — pure VPU work, zero gathers. Vectors live in the lane-dense
 block with ``T = tm / 128``.
 
 Blocking strategy:
-  * grid over row tiles; the diagonal table ``data[ndiag, M]`` streams
-    through VMEM one ``(ndiag, T, 128)`` block per grid step;
+  * grid over row tiles; the lane-tiled diagonal table
+    ``data[ndiag, M / 128, 128]`` streams through VMEM one
+    ``(ndiag, T, 128)`` block per grid step;
   * each (tile, diagonal) reads the 8-row-aligned ``(T + 16, 128)`` window
     that covers ``x[row0 + off : row0 + off + tm]`` from one of two homes,
     chosen by the caller from the padded ``x``'s size:
@@ -36,12 +37,14 @@ VMEM per step: 2 * ndiag * tm * itemsize (data, double-buffered) +
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.formats import tile_dia_table
 from repro.core.ops import vma
 
 LANES = 128
@@ -125,28 +128,34 @@ def _dia_kernel(offsets_ref, data_ref, x_ref, y_ref, *stream,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n", "tm", "x_resident", "interpret"))
+                   static_argnames=("n", "m", "tm", "x_resident", "interpret"))
 def dia_spmv(offsets: jax.Array, data: jax.Array, x: jax.Array, n: int,
-             tm: int = 8192, x_resident: bool = False,
-             interpret: bool = False) -> jax.Array:
-    """y = A @ x for DIA A given as (offsets[ndiag], data[ndiag, M]).
+             m: Optional[int] = None, tm: int = 8192,
+             x_resident: bool = False, interpret: bool = False) -> jax.Array:
+    """y = A @ x for DIA A given as (offsets[ndiag], data[ndiag, M]), or
+    with ``data`` lane-tiled, ``[ndiag, ceil(M/128), 128]``.
 
     ``x`` has length ``n`` (rectangular matrices supported). ``data`` rows
     follow the cusp convention data[d, i] = A[i, i + offsets[d]]; entries
-    whose column falls outside ``[0, n)`` contribute nothing. ``tm`` must
-    be a multiple of :func:`row_unit` of the data dtype. ``x_resident``
-    keeps the whole padded ``x`` (``4 * x_pad_len(n, tm)`` bytes) in VMEM;
-    otherwise each window is a DMA from HBM.
+    whose column falls outside ``[0, n)`` contribute nothing. A lane-tiled
+    table is the kernel's own operand and goes in as it is; a 2-D one is
+    padded to whole lanes and reshaped to it first. ``m`` is M (default:
+    the table's rows, padding included). ``tm`` must be a multiple of
+    :func:`row_unit` of the data dtype. ``x_resident`` keeps the whole
+    padded ``x`` (``4 * x_pad_len(n, tm)`` bytes) in VMEM; otherwise each
+    window is a DMA from HBM.
     """
     unit = row_unit(data.dtype)
     if tm <= 0 or tm % unit:
         raise ValueError(f"DIA kernel row tile tm={tm} is not a positive "
                          f"multiple of {unit} rows for "
                          f"{jnp.dtype(data.dtype).name} data")
-    ndiag, m = data.shape
-    m128 = -(-m // LANES) * LANES
-    if m128 != m:
-        data = jnp.pad(data, ((0, 0), (0, m128 - m)))
+    ndiag = data.shape[0]
+    if data.ndim == 2:
+        m = data.shape[1] if m is None else m
+        data = tile_dia_table(data)
+    m128 = data.shape[1] * LANES
+    m = m128 if m is None else m
     t = tm // LANES
     lpad = tm
     total = x_pad_len(n, tm)
@@ -175,6 +184,5 @@ def dia_spmv(offsets: jax.Array, data: jax.Array, x: jax.Array, n: int,
         out_shape=jax.ShapeDtypeStruct((m128 // LANES, LANES), jnp.float32,
                                        vma=vma(offsets, data, x)),
         interpret=interpret,
-    )(offsets.astype(jnp.int32), data.reshape(ndiag, m128 // LANES, LANES),
-      x_pad.reshape(total // LANES, LANES))
+    )(offsets.astype(jnp.int32), data, x_pad.reshape(total // LANES, LANES))
     return y.reshape(m128)[:m].astype(x.dtype)

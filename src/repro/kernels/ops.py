@@ -214,9 +214,11 @@ def dia_spmv(A: DIA, x: jax.Array, tm: Optional[int] = None,
     """DIA SpMV via the Pallas kernel, ``x`` resident in VMEM when its
     padded copy fits :data:`X_VMEM_BUDGET`, else streamed from HBM in
     per-tile windows; counts each traced call's path
-    (``kernel.dia.x_resident`` / ``kernel.dia.x_streamed``). Raises
-    ``ValueError`` when the (ndiag, tm) diagonal tiles exceed
-    :data:`DIA_VMEM_BUDGET`."""
+    (``kernel.dia.x_resident`` / ``kernel.dia.x_streamed``) and how its
+    table arrived: lane-tiled, the kernel's operand as stored
+    (``kernel.dia.table_tiled``), or 2-D and relaid out by the call
+    (``kernel.dia.table_relaid``). Raises ``ValueError`` when the
+    (ndiag, tm) diagonal tiles exceed :data:`DIA_VMEM_BUDGET`."""
     cfg = resolve_config(A, cfg)
     tm = int(_pick(tm, cfg, "tm", A))
     need = 2 * A.ndiag * tm * A.dtype.itemsize
@@ -230,8 +232,10 @@ def dia_spmv(A: DIA, x: jax.Array, tm: Optional[int] = None,
     resident = 4 * _dia.x_pad_len(n, tm) <= X_VMEM_BUDGET
     _metrics.inc("kernel.dia.x_resident" if resident
                  else "kernel.dia.x_streamed")
-    return _dia.dia_spmv(A.offsets, A.data, x, n, tm=tm, x_resident=resident,
-                         interpret=_interpret(A, x))
+    _metrics.inc("kernel.dia.table_tiled" if A.tiled
+                 else "kernel.dia.table_relaid")
+    return _dia.dia_spmv(A.offsets, A.data, x, n, m=A.shape[0], tm=tm,
+                         x_resident=resident, interpret=_interpret(A, x))
 
 
 def ell_spmv(A: ELL, x: jax.Array, tm: Optional[int] = None,

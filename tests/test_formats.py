@@ -72,6 +72,40 @@ def test_dia_banded_exact():
     np.testing.assert_allclose(to_dense_np(Ad), to_dense_np(A))
 
 
+@pytest.mark.parametrize("shape", [(256, 256), (200, 200), (150, 90)])
+def test_dia_tiled_table_ref_path_matches_2d(shape):
+    """A lane-tiled DIA table (rows padded to whole lanes when M % 128 != 0)
+    reads through the same reference path as its 2-D form: SpMV, SpMM,
+    diagonal extract and update, conversion out of DIA."""
+    from repro.core.formats import tile_dia_table
+
+    A = _rand(13, shape, density=0.1)
+    D = to_dense_np(A)
+    d = min(shape)
+    D[np.arange(d), np.arange(d)] = 5.0
+    A2 = convert(coo_from_dense_np(D), Format.DIA)
+    A3 = DIA(A2.offsets, tile_dia_table(A2.data), A2.shape, A2.nnz)
+    assert A3.tiled and not A2.tiled
+    assert A3.data.shape == (A2.ndiag, -(-shape[0] // 128), 128)
+    np.testing.assert_array_equal(np.asarray(A3.table), np.asarray(A2.data))
+    x = jnp.asarray(np.random.default_rng(14).standard_normal(shape[1]),
+                    jnp.float32)
+    B = jnp.asarray(np.random.default_rng(15).standard_normal((shape[1], 3)),
+                    jnp.float32)
+    for op, arg in ((spmv, x), (spmm, B)):
+        np.testing.assert_array_equal(np.asarray(op(A3, arg)),
+                                      np.asarray(op(A2, arg)))
+    np.testing.assert_array_equal(np.asarray(extract_diagonal(A3)),
+                                  np.asarray(extract_diagonal(A2)))
+    U2 = update_diagonal(A2, jnp.full((d,), 7.0))
+    U3 = update_diagonal(A3, jnp.full((d,), 7.0))
+    assert U3.data.shape == A3.data.shape
+    np.testing.assert_array_equal(np.asarray(U3.table), np.asarray(U2.data))
+    np.testing.assert_array_equal(to_dense_np(A3), to_dense_np(A2))
+    for fmt in (Format.COO, Format.CSR, Format.ELL):
+        np.testing.assert_array_equal(to_dense_np(convert(A3, fmt)), D)
+
+
 def test_capacity_padding_is_inert():
     A = random_coo(8, (32, 32), density=0.1, capacity=500)
     D = to_dense_np(A)

@@ -5,8 +5,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import (Format, banded_coo, coo_from_dense_np, convert,
+from repro.core import (DIA, Format, banded_coo, coo_from_dense_np, convert,
                         random_coo, to_dense_np)
+from repro.core.formats import tile_dia_table
 from repro.kernels import ops as kops
 from repro.kernels.ref import (bsr_spmm_ref, csr_spmv_ref, dia_spmv_ref,
                                ell_spmv_ref)
@@ -69,6 +70,29 @@ def test_dia_kernel_x_paths_agree_bitwise(shape, offsets, tm, monkeypatch):
         monkeypatch.setattr(kops, "X_VMEM_BUDGET", budget)
         ys[path] = np.asarray(kops.dia_spmv(A, x, tm=tm))
     np.testing.assert_array_equal(ys["resident"], ys["streamed"])
+
+
+@pytest.mark.parametrize("shape,offsets", [
+    ((1000, 1000), [-96, -32, -1, 0, 1, 32, 96]),
+    ((513, 513), [-5, 0, 5]),            # rows padded to whole lanes
+    ((128, 200), [0, 64, 150]),
+])
+def test_dia_kernel_tiled_table_bitwise(shape, offsets):
+    """A lane-tiled table goes into the kernel as stored, a 2-D one is
+    relaid out to it first: the same ``y``, bit for bit, and each call
+    counted by how its table arrived."""
+    A = convert(banded_coo(shape, offsets), Format.DIA)
+    T = DIA(A.offsets, tile_dia_table(A.data), A.shape, A.nnz)
+    x = jnp.asarray(RNG.standard_normal(shape[1]).astype(np.float32))
+    with metrics.scope() as s:
+        y2 = np.asarray(kops.dia_spmv(A, x))
+        assert (s.delta("kernel.dia.table_relaid"),
+                s.delta("kernel.dia.table_tiled")) == (1, 0)
+        y3 = np.asarray(kops.dia_spmv(T, x))
+        assert (s.delta("kernel.dia.table_relaid"),
+                s.delta("kernel.dia.table_tiled")) == (1, 1)
+    assert y3.shape == (shape[0],)
+    np.testing.assert_array_equal(y3, y2)
 
 
 @pytest.mark.parametrize("tm", [1024, 2048, 4096])
